@@ -38,27 +38,11 @@ def get_used_memory_mb() -> int:
         return 0
 
 
-def get_tpu_stats() -> list:
-    """Best-effort TPU device stats via jax; empty off-device."""
-    try:
-        import jax
-
-        stats = []
-        for i, dev in enumerate(jax.local_devices()):
-            mem = getattr(dev, "memory_stats", None)
-            entry = {"index": i}
-            if callable(mem):
-                m = mem() or {}
-                entry["memory_used_gb"] = m.get("bytes_in_use", 0) / 1e9
-                entry["memory_total_gb"] = m.get("bytes_limit", 0) / 1e9
-            stats.append(entry)
-        return stats
-    except Exception:  # noqa: BLE001
-        return []
-
-
 class ResourceMonitor:
-    """Periodically reports host CPU/mem (+ TPU stats) to the master."""
+    """Periodically reports host CPU/mem to the master. Device memory
+    is not read here: asking JAX for it would take the chip from the
+    worker (one process per chip) — the worker that holds the chip
+    publishes ``device.hbm.*`` gauges itself (Trainer)."""
 
     # Stats are best-effort, but a healed partition must bring them
     # back: the guard is a circuit breaker (many misses to trip, then
@@ -78,7 +62,6 @@ class ResourceMonitor:
             max_consecutive_failures=self._MAX_MISSES,
             cooldown=self._COOLDOWN,
         )
-        self.report_tpu = False
 
     def start(self):
         self._thread = threading.Thread(
@@ -96,7 +79,6 @@ class ResourceMonitor:
                     lambda: self._client.report_used_resource(
                         get_process_cpu_percent(),
                         get_used_memory_mb(),
-                        get_tpu_stats() if self.report_tpu else [],
                     )
                 )
             except Exception:  # noqa: BLE001

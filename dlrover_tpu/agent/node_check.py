@@ -4,12 +4,14 @@ Equivalent capability: reference dlrover/trainer/torch/node_check/
 nvidia_gpu.py:26 (matmul rounds + 10x allgather of 2^24 floats, elapsed
 time written to a per-rank file; MOCK_ERR_RANK fault injection
 utils.py:50). TPU-native redesign: the probe runs a bf16 matmul loop on
-every local TPU device (MXU exercise) and a psum+all_gather over all
-local devices via pmap (ICI exercise); multi-host probes run the same
-program under jax.distributed so the collectives cross hosts. The agent
-times the run and reports (normal, elapsed) to the master, whose pairing
-logic (master/rendezvous.py NetworkCheckRendezvousManager) isolates the
-faulty node.
+every local TPU device (MXU exercise) and a psum over all local devices
+via a jitted shard_map (ICI exercise); multi-host probes run the same
+program under jax.distributed so the collectives cross hosts. The
+payload initialises a JAX backend, so the agent — which must leave the
+chip to its workers — runs it as a child process that exits before any
+worker is spawned (:func:`run_node_check_child`) and reports (normal,
+elapsed) to the master, whose pairing logic (master/rendezvous.py
+NetworkCheckRendezvousManager) isolates the faulty node.
 """
 
 from __future__ import annotations
@@ -22,8 +24,6 @@ from dlrover_tpu.common.constants import NodeEnv
 from dlrover_tpu.common.log import get_logger
 
 logger = get_logger(__name__)
-
-CHECK_TIME_DIR = "/tmp/dlrover_tpu/node_check"
 
 MATMUL_SIZE = 1024
 MATMUL_ROUNDS = 10
@@ -38,14 +38,13 @@ def _mock_error() -> bool:
     return mock_rank != "" and mock_rank == node_rank
 
 
-def matmul_probe(devices=None) -> float:
+def matmul_probe() -> float:
     """Time a bf16 matmul loop on each local device (MXU health)."""
     import jax
     import jax.numpy as jnp
 
-    devices = devices or jax.local_devices()
     start = time.time()
-    for dev in devices:
+    for dev in jax.local_devices():
         x = jax.device_put(
             jnp.ones((MATMUL_SIZE, MATMUL_SIZE), dtype=jnp.bfloat16), dev
         )
@@ -55,24 +54,12 @@ def matmul_probe(devices=None) -> float:
     return time.time() - start
 
 
-def collective_probe(devices=None) -> float:
-    """Time psum + all_gather across local devices (ICI health); with a
+def collective_probe() -> float:
+    """Time psum rounds across local devices (ICI health); with a
     multi-process jax.distributed setup the same collectives span DCN."""
-    import jax
-    import jax.numpy as jnp
+    from dlrover_tpu.agent.probe import local_psum
 
-    devices = devices or jax.local_devices()
-    n = len(devices)
-    if n == 0:
-        raise RuntimeError("no devices to probe")
-    shape = (n, COLLECTIVE_ELEMS // max(n, 1))
-    x = jnp.ones(shape, dtype=jnp.float32)
-
-    probe = jax.pmap(
-        lambda v: jax.lax.psum(v, axis_name="d"),
-        axis_name="d",
-        devices=devices,
-    )
+    x, probe = local_psum(COLLECTIVE_ELEMS)
     start = time.time()
     for _ in range(COLLECTIVE_ROUNDS):
         out = probe(x)
@@ -80,25 +67,10 @@ def collective_probe(devices=None) -> float:
     return time.time() - start
 
 
-def write_time_to_file(elapsed: float, normal: bool, local_rank: int = 0):
-    os.makedirs(CHECK_TIME_DIR, exist_ok=True)
-    path = os.path.join(CHECK_TIME_DIR, f"{local_rank}.json")
-    with open(path, "w") as f:
-        json.dump(
-            {"elapsed": elapsed, "normal": normal, "ts": time.time()}, f
-        )
-
-
-def read_time_from_file(local_rank: int = 0):
-    path = os.path.join(CHECK_TIME_DIR, f"{local_rank}.json")
-    if not os.path.exists(path):
-        return None
-    with open(path) as f:
-        return json.load(f)
-
-
-def run_node_check(local_rank: int = 0) -> tuple[bool, float]:
-    """The payload the agent executes (in-process or as a subprocess).
+def run_node_check() -> tuple[bool, float]:
+    """The payload (the agent runs it through
+    :func:`run_node_check_child`). A process that finds no accelerator
+    without ``JAX_PLATFORMS=cpu`` fails the check.
 
     Returns (normal, elapsed_seconds)."""
     start = time.time()
@@ -106,26 +78,38 @@ def run_node_check(local_rank: int = 0) -> tuple[bool, float]:
     try:
         if _mock_error():
             raise RuntimeError("mock node failure injected via MOCK_ERR_RANK")
-        import jax
+        from dlrover_tpu.common.backend import require_backend
 
-        devices = jax.local_devices()
-        if not devices:
-            raise RuntimeError("no local devices enumerated")
-        matmul_probe(devices)
-        collective_probe(devices)
+        require_backend()
+        matmul_probe()
+        collective_probe()
     except Exception as e:  # noqa: BLE001
         logger.error("node check failed: %s", e)
         normal = False
-    elapsed = time.time() - start
-    write_time_to_file(elapsed, normal, local_rank)
-    return normal, elapsed
+    return normal, time.time() - start
+
+
+def run_node_check_child() -> tuple[bool, float]:
+    """:func:`run_node_check` in a child process that has exited — and
+    released the chip — before this returns. A child that dies without
+    a verdict fails the check."""
+    from dlrover_tpu.agent.probe import run_json_child
+
+    start = time.time()
+    rc, verdict, tail = run_json_child("dlrover_tpu.agent.node_check")
+    if not isinstance(verdict, dict) or "normal" not in verdict:
+        logger.error(
+            "node check child exited %s without a verdict: %s", rc, tail
+        )
+        return False, time.time() - start
+    return bool(verdict["normal"]), float(verdict["elapsed"])
 
 
 def main():
-    normal, elapsed = run_node_check(
-        int(os.environ.get(NodeEnv.LOCAL_RANK, "0"))
-    )
+    normal, elapsed = run_node_check()
     logger.info("node check: normal=%s elapsed=%.2fs", normal, elapsed)
+    # the last stdout line is the verdict (run_json_child's contract)
+    print(json.dumps({"normal": normal, "elapsed": elapsed}), flush=True)
     raise SystemExit(0 if normal else 1)
 
 

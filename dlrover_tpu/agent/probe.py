@@ -4,23 +4,33 @@ Equivalent capability: the reference admits a node only after a
 ``NetworkCheckElasticAgent`` runs a matmul + repeated-allgather payload
 and kills hosts that fail it (node_check/nvidia_gpu.py); our
 node_check.py reproduces the pass/fail half for dedicated probe rounds.
-This module is the *graded* half: three timed legs run by the agent
-BEFORE ``rdzv.join``, with per-leg milliseconds shipped in
+This module is the *graded* half: three timed legs run BEFORE
+``rdzv.join``, with per-leg milliseconds shipped in
 ``JoinRendezvousRequest.probe_report`` so the master's health gate
 (master/health.py) can judge the host against the fleet median AND its
 own persisted fingerprint — pass / quarantine / refuse instead of the
 binary normal flag.
 
-Legs (TPU; CPU smoke-arm stand-ins in parentheses):
+Where the legs run (one process per chip): the device legs initialise a
+JAX backend, and a process that has done so owns the chip. The agent
+must hand that chip to its workers, so it never runs a leg itself: the
+join-time probe is :func:`run_probe_child` — ``python -m
+dlrover_tpu.agent.probe`` as a short-lived child that exits before any
+worker is spawned — and the in-band re-probe runs inside the worker
+that holds the chip, at a step boundary (``Trainer._maybe_reprobe``).
+
+Legs (TPU; host stand-ins, taken only under ``JAX_PLATFORMS=cpu``, in
+parentheses):
 
 - ``hbm``        — HBM-bandwidth microbench: on-device array copy
                    rounds (host memcpy over a scaled buffer).
 - ``matmul``     — an MXU matmul round per local device (numpy matmul
                    — a jitted jax matmul on CPU would time XLA
                    compilation, not the hardware).
-- ``collective`` — N ICI psum rounds over the local mesh via pmap
-                   (loopback-socket round trips: the only in-process
-                   stand-in that still exercises a real network stack).
+- ``collective`` — N ICI psum rounds over the local mesh via a
+                   jitted shard_map (loopback-socket round trips: the
+                   only stand-in that still exercises a real network
+                   stack).
 
 Every leg opens its timed window with ``chaos_point("probe.degrade",
 leg=..., rank=...)`` — the ``degrade`` action (common/chaos.py) injects
@@ -33,10 +43,14 @@ probe reports an error and the gate refuses it, mirroring node_check.
 
 from __future__ import annotations
 
+import json
 import os
 import socket
+import subprocess
+import sys
 import time
 
+from dlrover_tpu.common import backend as backend_mod
 from dlrover_tpu.common.chaos import chaos_point
 from dlrover_tpu.common.constants import NodeEnv
 from dlrover_tpu.common.log import get_logger
@@ -90,18 +104,17 @@ def _mock_error() -> bool:
 
 
 def _device_backend() -> str:
-    """Accelerator backend name, or '' for the host stand-in path.
-    Import failures gate to the stand-ins instead of erroring: the
-    probe must run on smoke arms with no jax at all."""
-    try:
-        import jax
+    """Accelerator backend name, or '' for the host stand-in legs.
 
-        backend = jax.default_backend()
-        if backend != "cpu" and jax.local_devices():
-            return backend
-    except Exception:  # noqa: BLE001 - no jax / no devices -> host arm
-        pass
-    return ""
+    The stand-ins are for a CPU the caller pinned (tests, CPU
+    harnesses) and never touch JAX. Anywhere else the legs must reach
+    the accelerator: a backend that fails to initialise, or JAX's
+    silent CPU fallback, raises — the report then carries the error and
+    the gate refuses the host instead of grading host memcpy timings as
+    the health of a TPU."""
+    if backend_mod.cpu_pinned():
+        return ""
+    return backend_mod.require_backend()
 
 
 # ---------------------------------------------------------------- legs
@@ -156,7 +169,11 @@ def matmul_probe(rank: int, device: bool) -> float:
             )
             for dev in jax.local_devices()
         ]
-        (jnp.matmul(xs[0], xs[0]) / MATMUL_SIZE).block_until_ready()
+        # EVERY device: each one loads its own copy of the program, and
+        # warming only the first left that inside the window (930 ms on
+        # four real chips against 3 ms on one)
+        for x in xs:
+            (jnp.matmul(x, x) / MATMUL_SIZE).block_until_ready()
         t0 = time.perf_counter()
         chaos_point("probe.degrade", leg="matmul", rank=rank)
         for x in xs:
@@ -179,21 +196,10 @@ def collective_probe(rank: int, device: bool) -> float:
     """ICI leg: psum rounds over the local mesh (loopback-socket round
     trips on the smoke arm — the one stand-in that still pushes bytes
     through a real network stack). Returns elapsed milliseconds.
-    Setup and a warmup round run outside the timed window (pmap
-    compilation / socket handshake are not the hardware under test)."""
+    Setup and a warmup round run outside the timed window
+    (compilation / socket handshake are not the hardware under test)."""
     if device:
-        import jax
-        import jax.numpy as jnp
-
-        devices = jax.local_devices()
-        n = len(devices)
-        shape = (n, max(COLLECTIVE_BYTES // 4 // max(n, 1), 1))
-        x = jnp.ones(shape, dtype=jnp.float32)
-        probe = jax.pmap(
-            lambda v: jax.lax.psum(v, axis_name="d"),
-            axis_name="d",
-            devices=devices,
-        )
+        x, probe = local_psum(COLLECTIVE_BYTES // 4)
         probe(x).block_until_ready()  # warmup (compile)
         t0 = time.perf_counter()
         chaos_point("probe.degrade", leg="collective", rank=rank)
@@ -213,6 +219,29 @@ def collective_probe(rank: int, device: bool) -> float:
         sender.close()
         conn.close()
         server.close()
+
+
+def local_psum(elems: int):
+    """``(x, fn)``: a float32 array of about ``elems`` elements, one
+    row per local device, and the jitted all-reduce over those devices
+    (shared with node_check's ICI leg)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    devices = jax.local_devices()
+    n = len(devices)
+    mesh = Mesh(np.array(devices), ("d",))
+    x = jax.device_put(
+        jnp.ones((n, max(elems // n, 1)), jnp.float32),
+        NamedSharding(mesh, P("d")),
+    )
+    fn = jax.jit(jax.shard_map(
+        lambda v: jax.lax.psum(v, "d"),
+        mesh=mesh, in_specs=P("d"), out_specs=P("d"),
+    ))
+    return x, fn
 
 
 def _loopback_pair():
@@ -262,7 +291,7 @@ def run_probe(node_rank: int | None = None) -> dict:
     binary fail. Never raises."""
     rank = _node_rank() if node_rank is None else int(node_rank)
     t0 = time.perf_counter()
-    backend = _device_backend()
+    backend = ""
     legs: dict[str, float] = {}
     error = ""
     try:
@@ -270,6 +299,7 @@ def run_probe(node_rank: int | None = None) -> dict:
             raise RuntimeError(
                 "mock probe failure injected via MOCK_ERR_RANK"
             )
+        backend = _device_backend()
         device = bool(backend)
         legs["hbm"] = round(hbm_probe(rank, device), 3)
         legs["matmul"] = round(matmul_probe(rank, device), 3)
@@ -283,7 +313,7 @@ def run_probe(node_rank: int | None = None) -> dict:
         "legs": legs,
         "elapsed_s": round(elapsed, 4),
         "host": rank,
-        "backend": backend or "host",
+        "backend": backend or ("unknown" if error else "host"),
         "error": error,
         "t": time.time(),
     }
@@ -296,8 +326,84 @@ def run_probe(node_rank: int | None = None) -> dict:
     return report
 
 
+# a join-time child pays process start, backend initialisation and
+# three small compiles before its first timed window
+CHILD_TIMEOUT_S = 300.0
+
+
+def run_json_child(
+    module: str, args: tuple = (), timeout: float = CHILD_TIMEOUT_S
+):
+    """Run ``python -m <module>`` to its end and return ``(rc, payload,
+    tail)``: the JSON object on its last stdout line (None when there
+    is none) and the end of its stderr. The agent's seam to payloads
+    that initialise a JAX backend: by the time this returns the child
+    has exited and released whatever chip it took."""
+    import dlrover_tpu
+
+    env = dict(os.environ)
+    pkg_root = os.path.dirname(
+        os.path.dirname(os.path.abspath(dlrover_tpu.__file__))
+    )
+    env["PYTHONPATH"] = os.pathsep.join(
+        [pkg_root]
+        + [p for p in env.get("PYTHONPATH", "").split(os.pathsep) if p]
+    )
+    # spawn seam (dlint DL003): agent.spawn covers workers; this is the
+    # payload-child counterpart
+    chaos_point("probe.spawn", module=module)
+    try:
+        proc = subprocess.run(  # noqa: S603
+            [sys.executable, "-m", module, *args],
+            env=env, capture_output=True, text=True, timeout=timeout,
+        )
+    except subprocess.TimeoutExpired as e:
+        tail = (e.stderr or b"")[-2000:]
+        if isinstance(tail, bytes):
+            tail = tail.decode(errors="replace")
+        return 124, None, f"timed out after {timeout:.0f}s: {tail}"
+    try:
+        payload = json.loads(proc.stdout.strip().splitlines()[-1])
+    except (IndexError, ValueError):
+        payload = None
+    return proc.returncode, payload, proc.stderr[-2000:]
+
+
+def run_probe_child(node_rank: int | None = None) -> dict:
+    """The join-time probe as the agent runs it: :func:`run_probe` in a
+    child process that has exited before this returns, so the chip it
+    measured is free for the workers. A child that dies without a
+    report becomes an error report — the gate refuses the host, exactly
+    like a leg failure. Never raises."""
+    rank = _node_rank() if node_rank is None else int(node_rank)
+    t0 = time.perf_counter()
+    rc, report, tail = run_json_child(
+        "dlrover_tpu.agent.probe", ("--node-rank", str(rank))
+    )
+    if not isinstance(report, dict) or "legs" not in report:
+        logger.error(
+            "hardware probe child exited %s without a report: %s", rc, tail
+        )
+        report = {
+            "legs": {},
+            "host": rank,
+            "backend": "unknown",
+            "error": f"probe child exited {rc}: {tail[-300:]}",
+            "t": time.time(),
+        }
+    # the join pays the child's whole life, not just its legs
+    report["elapsed_s"] = round(time.perf_counter() - t0, 4)
+    logger.info(
+        "hardware probe (child): %s backend=%s (%.0f ms total)%s",
+        {k: f"{v:.1f}ms" for k, v in report["legs"].items()},
+        report["backend"], report["elapsed_s"] * 1000,
+        f" ERROR={report['error']}" if report["error"] else "",
+    )
+    return report
+
+
 class ProbeScheduler:
-    """Cadence governor for the agent's in-band re-probe, mirroring the
+    """Cadence governor for the in-band re-probe, mirroring the
     device-time sampler's window governor: ``interval`` is the FLOOR,
     and each probe's measured cost stretches the next gap until the
     steady-state overhead stays under ``overhead_pct`` of the wait — an
@@ -338,9 +444,10 @@ class ProbeScheduler:
         now = time.time() if now is None else now
         return now >= self._next_t
 
-    def run(self, node_rank: int | None = None) -> dict:
-        """Run the re-probe now and re-arm from its measured cost."""
-        report = run_probe(node_rank)
+    def run(self, node_rank: int | None = None, probe_fn=None) -> dict:
+        """Probe now (in this process, or through ``probe_fn``) and
+        re-arm from the measured cost."""
+        report = (probe_fn or run_probe)(node_rank)
         self.seed(report)
         return report
 
@@ -356,17 +463,25 @@ _SCHEDULER: ProbeScheduler | None = None
 
 
 def default_scheduler() -> ProbeScheduler:
-    """The process-wide scheduler: the rendezvous handlers (elastic
-    training AND network check) and the monitor loop share one cache,
-    so back-to-back joins don't each re-pay the probe."""
+    """The process-wide scheduler: in the agent the rendezvous handlers
+    (elastic training AND network check) share one cache, so
+    back-to-back joins don't each re-pay the probe; in a worker it
+    paces the in-band re-probe."""
     global _SCHEDULER
     if _SCHEDULER is None:
         _SCHEDULER = ProbeScheduler()
     return _SCHEDULER
 
 
-def main():
-    report = run_probe()
+def main(argv=None):
+    import argparse
+
+    parser = argparse.ArgumentParser(prog="dlrover_tpu.agent.probe")
+    parser.add_argument("--node-rank", type=int, default=None)
+    args = parser.parse_args(argv)
+    report = run_probe(args.node_rank)
+    # the last stdout line is the report (run_json_child's contract)
+    print(json.dumps(report), flush=True)
     raise SystemExit(0 if not report["error"] else 1)
 
 

@@ -26,6 +26,7 @@ import time
 
 from dlrover_tpu.agent.master_client import MasterClient
 from dlrover_tpu.common import flight, telemetry, tracing
+from dlrover_tpu.common.backend import compile_cache_env
 from dlrover_tpu.common.chaos import chaos_point
 from dlrover_tpu.agent.monitor import (
     HeartbeatReporter,
@@ -45,25 +46,6 @@ from dlrover_tpu.common.constants import (
 from dlrover_tpu.common.log import get_logger
 
 logger = get_logger(__name__)
-
-
-def apply_compilation_cache_env(cache_dir: str, env: dict) -> dict:
-    """Point a worker env at the persistent XLA compilation cache.
-
-    User-provided values win; the thresholds drop to "cache everything"
-    so a restarted worker replays every program from cache instead of
-    recompiling (the recompile-after-membership-change cost is the
-    goodput sink the cache exists to remove)."""
-    if cache_dir:
-        os.makedirs(cache_dir, exist_ok=True)
-        env.setdefault("JAX_COMPILATION_CACHE_DIR", cache_dir)
-        env.setdefault(
-            "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0.0"
-        )
-        env.setdefault(
-            "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "-1"
-        )
-    return env
 
 
 @dataclasses.dataclass
@@ -90,11 +72,6 @@ class ElasticLaunchConfig:
     accelerator: str = "tpu"
     log_dir: str | None = None
     run_id: str = "dlrover-tpu"
-    # persistent XLA compilation cache shared across worker restarts:
-    # elastic membership changes restart worker processes with a new
-    # mesh, and the recompile must be a cache hit or it eats the goodput
-    # the flash checkpoint bought (SURVEY hard-parts list). "" disables.
-    compilation_cache_dir: str = "/tmp/dlrover_tpu/compile_cache"
     # Prometheus /metrics endpoint on the agent (reference xpu_timer
     # brpc/Prometheus export): -1 = disabled (default: an HTTP listener
     # is opt-in), 0 = ephemeral port, >0 = fixed port
@@ -115,17 +92,11 @@ class ElasticLaunchConfig:
     reshape_ack_timeout: float = 60.0
 
     def auto_configure_params(self):
-        """--auto-config: infer process count from visible devices."""
-        if not self.auto_config:
-            return
-        try:
-            import jax
-
-            # One JAX process per host drives all local TPU chips.
+        """--auto-config: one JAX process per host drives all of its
+        local chips. Decided without asking JAX — enumerating devices
+        here would take the chip away from that worker."""
+        if self.auto_config:
             self.nproc_per_node = 1
-            _ = jax.local_devices()
-        except Exception:  # noqa: BLE001
-            self.nproc_per_node = max(self.nproc_per_node, 1)
 
 
 class WorkerSpec:
@@ -166,9 +137,9 @@ class MasterRendezvousHandler:
         self._client = client
         self._local_world_size = local_world_size
         self._timeout = timeout
-        # hardware-probe cadence cache (agent/probe.py): joins ship the
-        # freshest per-leg timings; the process-wide default means a
-        # net-check round and the training join share one probe
+        # hardware-probe cache (agent/probe.py): joins ship the last
+        # report; the process-wide default means a net-check round and
+        # the training join share one probe child
         from dlrover_tpu.agent.probe import default_scheduler
 
         self._probe = (
@@ -210,14 +181,19 @@ class MasterRendezvousHandler:
 
     def _probe_report(self, fresh: bool = False) -> dict:
         """The hardware probe report to ship with a join: the cached
-        sample while it is fresh, a re-run when the gate demanded one
-        (``fresh``) or nothing is cached yet. Empty when disabled."""
+        sample, or a new one when the gate demanded it (``fresh``) or
+        nothing is cached yet. Empty when disabled. The legs run in a
+        child process (one process per chip): every caller reaches
+        this with no worker alive — before the first spawn, or after
+        ``_stop_workers`` on the restart path."""
         from dlrover_tpu.agent import probe as hw_probe
 
         if hw_probe.probe_disabled():
             return {}
         if fresh or self._probe.last_report is None:
-            return self._probe.run(self._node_rank)
+            return self._probe.run(
+                self._node_rank, probe_fn=hw_probe.run_probe_child
+            )
         return self._probe.last_report
 
     def _next_rendezvous(self):
@@ -566,9 +542,9 @@ class ElasticTrainingAgent:
             env[NodeEnv.RESTORE_STEP] = str(restore_step)
         else:
             env.pop(NodeEnv.RESTORE_STEP, None)
-        apply_compilation_cache_env(
-            self._config.compilation_cache_dir, env
-        )
+        # restarted workers replay every program from the cache the
+        # first incarnation wrote instead of recompiling
+        compile_cache_env(env)
         return env
 
     def _start_worker_processes(self, rank_offset, total, coordinator):
@@ -816,12 +792,6 @@ class ElasticTrainingAgent:
             # triggers a local flight-recorder dump (the worker's own
             # detector may be the thing that's stuck)
             self._poll_diagnosis()
-            # continuous hardware check: a governed low-cadence
-            # re-probe (floor interval stretched until the probe costs
-            # under its overhead budget) feeding the master's
-            # fingerprint store — sustained degradation becomes a
-            # hw_degraded verdict and a drain, not a mystery slowdown
-            self._maybe_reprobe()
             # announced preemption: the platform (simulated by the
             # ``preempt.notice`` chaos action) says this host dies at a
             # deadline — relay to the brain and, when directed, drain
@@ -846,23 +816,6 @@ class ElasticTrainingAgent:
             if self._heartbeat.action == "restart":
                 self._heartbeat.action = ""
                 self._restart_workers()
-
-    def _maybe_reprobe(self):
-        """In-band hardware re-probe on the shared scheduler's cadence;
-        best-effort shipping to the master's fingerprint store."""
-        from dlrover_tpu.agent import probe as hw_probe
-
-        if hw_probe.probe_disabled():
-            return
-        sched = hw_probe.default_scheduler()
-        if not sched.due():
-            return
-        report = sched.run(self._config.node_rank)
-        try:
-            self._client.report_probe(self._config.node_rank, report)
-        except Exception:  # noqa: BLE001 - the health signal is
-            # advisory; a dropped sample waits for the next window
-            logger.warning("in-band probe report failed", exc_info=True)
 
     def _poll_diagnosis(self):
         """Best-effort: fetch the master's runtime verdicts; when a
@@ -1295,14 +1248,14 @@ class NodeCheckElasticAgent:
         return result
 
     def run(self) -> bool:
-        from dlrover_tpu.agent.node_check import run_node_check
+        from dlrover_tpu.agent import node_check
 
         node_rank = self._config.node_rank
         round_timeout = min(self._config.rdzv_timeout, 90)
         result = None
         for _ in range(self._rounds):
             self._rdzv_handler.next_rendezvous()
-            normal, elapsed = run_node_check()
+            normal, elapsed = node_check.run_node_check_child()
             self._client.report_node_check_result(
                 node_rank, normal, elapsed
             )

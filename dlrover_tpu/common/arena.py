@@ -3,8 +3,8 @@
 Equivalent capability: the reference pins and reuses host staging
 buffers for its D2H/H2D checkpoint legs (atorch's pinned-memory pools)
 so a multi-GB save/restore does not pay page-fault-in on every pass.
-Our cold-vs-warm bench gap (``ckpt_engine_cold_gbps`` 1.31 vs 5.81
-warm, BENCH_r05) is exactly that tax: a fresh buffer's first touch
+The cold-vs-warm bench gap (``ckpt_engine_cold_gbps`` 1.31 vs 5.81
+warm in a pre-PR-1 chip run) was exactly that tax: a fresh buffer's first touch
 faults pages in single-threaded, while a reused one runs at memory
 bandwidth. This arena keeps freed checkpoint buffers alive for the
 process lifetime so repeat saves/restores hit warm pages.

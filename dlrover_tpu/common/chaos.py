@@ -9,7 +9,8 @@ sites** threaded through the control plane (``rpc.send``, ``rpc.recv``,
 ``ipc.request``, ``agent.spawn``, ``ckpt.write``, ``ckpt.manifest``,
 ``ckpt.save``, ``rdzv.join``, ``master.kill``, ``elastic.signal``,
 ``elastic.reshape``, ``preempt.notice``, ``brain.plan``,
-``serve.admit``, ``serve.step``, ``probe.degrade``) consult a
+``serve.admit``, ``serve.step``, ``probe.degrade``, ``probe.spawn``)
+consult a
 seeded schedule
 that can drop or
 delay RPC frames, kill or hang a process at a chosen step, tear a

@@ -8,26 +8,35 @@ parameter per token (fwd 2 + bwd 4) plus the causal-attention
 parameter count does not capture. Models without the attention term
 (recsys, linear probes) use the dense part alone.
 
-Peak FLOP/s defaults to the v5e bf16 peak (197 TFLOP/s) and is
-env-overridable (``DLROVER_TPU_PEAK_FLOPS``) for other generations —
-deliberately conservative for int8-selected arms, whose dots run the
-2x int8 MXU path.
+Peak FLOP/s comes from ONE table keyed by the device kind JAX reports.
+An accelerator that is not in it is an error, never a default: a
+utilization against somebody else's peak is not a measurement. The CPU
+has no row, so a CPU run reports no MFU at all. The bf16 peak is
+deliberately conservative for int8-selected arms, whose dots run the 2x
+int8 MXU path.
 """
 
 from __future__ import annotations
 
-import os
+# bf16 peak FLOP/s of one chip, by ``jax.Device.device_kind``
+PEAK_FLOPS = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s (bf16) per chip
+    "TPU v5 lite": 197e12,
+}
 
-PEAK_FLOPS_ENV = "DLROVER_TPU_PEAK_FLOPS"
-# v5e bf16 peak per chip
-DEFAULT_PEAK_FLOPS = 197e12
 
-
-def peak_flops() -> float:
-    try:
-        return float(os.environ.get(PEAK_FLOPS_ENV, DEFAULT_PEAK_FLOPS))
-    except ValueError:
-        return DEFAULT_PEAK_FLOPS
+def peak_flops(device) -> float | None:
+    """Published peak of ``device`` (a ``jax.Device``). None on the
+    CPU — it has no row, and no MFU is reported there; an accelerator
+    missing from the table raises."""
+    peak = PEAK_FLOPS.get(device.device_kind)
+    if peak is None and device.platform != "cpu":
+        raise ValueError(
+            f"no published peak FLOP/s for device kind "
+            f"{device.device_kind!r}: add it to common/mfu.PEAK_FLOPS "
+            f"with its source"
+        )
+    return peak
 
 
 def transformer_step_flops(
@@ -46,10 +55,8 @@ def transformer_step_flops(
     return flops
 
 
-def mfu(flops_per_step: float, step_seconds: float,
-        peak: float | None = None) -> float:
-    """Fraction of peak the step achieved; 0 when unmeasurable."""
-    peak = peak_flops() if peak is None else peak
+def mfu(flops_per_step: float, step_seconds: float, peak: float) -> float:
+    """Fraction of ``peak`` the step achieved; 0 when unmeasurable."""
     if step_seconds <= 0 or peak <= 0:
         return 0.0
     return flops_per_step / step_seconds / peak

@@ -350,9 +350,7 @@ def _sharded_flash(config: LlamaConfig, qt, kt, vt, layout: str = "bhsd",
     if rope:
         table_spec = logical_to_mesh_axes(("batch", None, None), rules)
         in_specs = in_specs + (table_spec, table_spec)
-    from dlrover_tpu.parallel import get_shard_map
-
-    return get_shard_map()(
+    return jax.shard_map(
         kernel,
         mesh=mesh,
         in_specs=in_specs,
@@ -684,7 +682,7 @@ def llama_loss_fn(config: LlamaConfig):
             # norm_scale path: the final RMSNorm fuses into the chunked
             # custom-VJP CE — no jax.checkpoint, so a remat="none" step
             # carries no checkpoint custom-call (the old norm_fn closure
-            # form kept one at ~25.7 ms/step, BENCH_r05 checkpoint.10)
+            # form kept one, ~25.7 ms/step in a pre-PR-1 chip run)
             loss_sum, valid_sum = fused_linear_cross_entropy(
                 h, params["lm_head"].astype(dtype), labels,
                 n_chunks=config.ce_chunks,

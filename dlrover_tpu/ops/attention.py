@@ -39,8 +39,10 @@ GQA: the kv-head index is derived from the q-head grid index inside the
 BlockSpec index maps — grouped kv is never materialised in the forward;
 the backward produces per-q-head dk/dv and group-sums outside.
 
-On non-TPU backends the same kernels run in Pallas interpret mode, so the
-unit-test suite exercises the real kernel code paths on the CPU mesh.
+Under ``JAX_PLATFORMS=cpu`` the same kernels run in Pallas interpret
+mode, so the unit-test suite exercises the real kernel code paths on the
+CPU mesh. A process that finds no accelerator without that pin fails
+(common/backend.py): interpret mode never stands in for a missing chip.
 """
 
 from __future__ import annotations
@@ -54,6 +56,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from dlrover_tpu.common.backend import use_interpret
 
 NEG_INF = -1e30
 
@@ -165,10 +169,6 @@ def _zero_pad_rows(x, block_idx, block_size, true_len):
     return jnp.where(rows + block_idx * block_size < true_len, x, 0)
 
 
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 # ---------------------------------------------------------------------------
 # fused rope (rotary embedding applied inside the kernels)
 # ---------------------------------------------------------------------------
@@ -217,16 +217,7 @@ def _unrope_tile(g, cos_ref, sin_ref):
 
 
 def _compiler_params(dims):
-    # jax >= 0.8 spells it CompilerParams; 0.4.x TPUCompilerParams
-    cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams", None
-    )
-    if cls is None:
-        return None
-    try:
-        return cls(dimension_semantics=dims)
-    except TypeError:  # older/newer field name differences
-        return None
+    return pltpu.CompilerParams(dimension_semantics=dims)
 
 
 def _col(ref):
@@ -1553,7 +1544,7 @@ def ring_fwd_block(q, k, v, q_start, k_start, sm_scale,
     lse [B, H, Sq, STATS_W] f32).
     """
     if interpret is None:
-        interpret = _use_interpret()
+        interpret = use_interpret()
     g = _RingSetup(q, k, q_start, k_start, block_q, block_k, False)
     return pl.pallas_call(
         functools.partial(
@@ -1588,7 +1579,7 @@ def ring_dq_block(q, k, v, do, lse, delta, q_start, k_start, sm_scale,
     rounding each to the model dtype first would quantize the gradient
     once per tick (the monolithic kernel rounds exactly once)."""
     if interpret is None:
-        interpret = _use_interpret()
+        interpret = use_interpret()
     g = _RingSetup(q, k, q_start, k_start, block_q, block_k, False)
     return pl.pallas_call(
         functools.partial(
@@ -1614,7 +1605,7 @@ def ring_dkv_block(q, k, v, do, lse, delta, q_start, k_start, sm_scale,
     """(dk, dv) contribution of one visiting q block, group-summed for
     GQA (kv shapes), emitted in f32 (see ring_dq_block)."""
     if interpret is None:
-        interpret = _use_interpret()
+        interpret = use_interpret()
     g = _RingSetup(q, k, q_start, k_start, block_q, block_k, True)
     dk_full, dv_full = pl.pallas_call(
         functools.partial(
@@ -1788,7 +1779,7 @@ def flash_attention(
                 f"rope tables must be [B, S, head_dim] {want}, got "
                 f"{tuple(rope_cos.shape)} / {tuple(rope_sin.shape)}")
     if interpret is None:
-        interpret = _use_interpret()
+        interpret = use_interpret()
     return _flash(q, k, v, "bhsd", int(q.shape[1]), int(k.shape[1]),
                   float(sm_scale), bool(causal),
                   int(block_q), int(block_k),
@@ -1841,15 +1832,19 @@ def flash_attention_bshd(
     KVH, Skv = k.shape[2], k.shape[1]
     if H % KVH != 0:
         raise ValueError(f"q heads {H} not divisible by kv {KVH}")
-    if H > 128:
+    if H > 128 or H * hd > 3072:
         # the fused kernels keep per-head softmax stats in columns of a
-        # (block_q, 128) scratch; wider models use the per-head grid
+        # (block_q, 128) scratch, and their VMEM footprint grows with
+        # the H*Dh width while blocks cannot shrink below 128 rows: at
+        # 4096 wide the v5e compiler refuses the fused backward (17.3 MB
+        # of scoped VMEM against its 16 MB limit; 3072 wide compiles).
+        # Wider models use the per-head grid.
         fused = False
     if sm_scale is None:
         sm_scale = hd ** -0.5
     _check_mask_extras(causal, window, prefix_len)
     if interpret is None:
-        interpret = _use_interpret()
+        interpret = use_interpret()
     if not interpret and hd % 128 != 0:
         o = flash_attention(
             q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),

@@ -191,9 +191,9 @@ def fused_linear_cross_entropy(
     this from fused CUDA CE losses.
 
     The recompute used to ride ``jax.checkpoint`` — whose lowering left
-    a ``checkpoint`` custom-call in the compiled step charged at
-    25.7 ms/step on the remat=none headline arm (BENCH_r05 top_ops
-    ``checkpoint.10``, #3 overall). The ``custom_vjp`` form expresses
+    a ``checkpoint`` custom-call in the compiled step (25.7 ms/step on
+    the remat=none headline arm of a pre-PR-1 chip run, its #3 op).
+    The ``custom_vjp`` form expresses
     the identical recompute schedule with zero remat machinery, so a
     remat="none" step is now genuinely checkpoint-free (the bench's
     StepProfiler forbid-ops gate pins it).

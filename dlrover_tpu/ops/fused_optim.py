@@ -38,11 +38,14 @@ import optax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from dlrover_tpu.common.backend import use_interpret
 from dlrover_tpu.ops.quantization import (
     BLOCK,
     LOG_FLOOR,
+    TILE_ROWS,
     _LOG_LEVELS,
-    _use_interpret,
+    row_spec,
+    scale_spec,
 )
 
 __all__ = [
@@ -53,11 +56,6 @@ __all__ = [
     "unflatten_from_blocks",
     "pallas_call_count",
 ]
-
-# rows per grid step: 512 x 256 x 4B = 512 KB per f32 operand — the
-# kernel's ~8 live operands stay well under VMEM
-TILE_ROWS = 512
-
 
 # ---------------------------------------------------------------------------
 # flat block layout
@@ -216,18 +214,10 @@ def _fused_adam8bit_kernel(sc_ref, g_ref, mu_q_ref, mu_s_ref,
     idx = jnp.clip(
         jnp.round((log_rel - _LOG_LO) / _LOG_STEP) + 1, 1, _LOG_LEVELS
     )
-    nu_q_out[:] = jnp.where(rel > 0.0, idx, 0.0).astype(jnp.uint8)
+    # through int32: Mosaic lowers no float32 -> uint8 cast
+    nu_q_out[:] = jnp.where(rel > 0.0, idx, 0.0).astype(
+        jnp.int32).astype(jnp.uint8)
     nu_s_out[:] = vscale.astype(jnp.float32)
-
-
-def _row_spec(tile):
-    return pl.BlockSpec((tile, BLOCK), lambda i: (i, 0),
-                        memory_space=pltpu.VMEM)
-
-
-def _scale_spec(tile):
-    return pl.BlockSpec((tile, 1), lambda i: (i, 0),
-                        memory_space=pltpu.VMEM)
 
 
 def _smem_spec():
@@ -320,7 +310,7 @@ def fused_adamw(
     def update_fn(updates, state, params=None):
         if weight_decay and params is None:
             raise ValueError(optax.base.NO_PARAMS_MSG)
-        ipret = _use_interpret() if interpret is None else interpret
+        ipret = use_interpret() if interpret is None else interpret
         meta = flatten_meta(updates)
         r = meta.total_rows
         tile = min(TILE_ROWS, r)
@@ -350,8 +340,8 @@ def fused_adamw(
                     wd=weight_decay, clip_norm=clip_norm,
                 ),
                 grid=grid,
-                in_specs=[_smem_spec()] + [_row_spec(tile)] * 4,
-                out_specs=(_row_spec(tile),) * 3,
+                in_specs=[_smem_spec()] + [row_spec(tile)] * 4,
+                out_specs=(row_spec(tile),) * 3,
                 out_shape=(
                     fbuf(jnp.float32), fbuf(jnp.float32),
                     fbuf(jnp.float32),
@@ -375,17 +365,17 @@ def fused_adamw(
                 grid=grid,
                 in_specs=[
                     _smem_spec(),
-                    _row_spec(tile),    # g
-                    _row_spec(tile),    # mu_q
-                    _scale_spec(tile),  # mu_scale
-                    _row_spec(tile),    # nu_q
-                    _scale_spec(tile),  # nu_scale
-                    _row_spec(tile),    # p
-                    _row_spec(tile),    # u
+                    row_spec(tile),    # g
+                    row_spec(tile),    # mu_q
+                    scale_spec(tile),  # mu_scale
+                    row_spec(tile),    # nu_q
+                    scale_spec(tile),  # nu_scale
+                    row_spec(tile),    # p
+                    row_spec(tile),    # u
                 ],
                 out_specs=(
-                    _row_spec(tile), _row_spec(tile), _scale_spec(tile),
-                    _row_spec(tile), _scale_spec(tile),
+                    row_spec(tile), row_spec(tile), scale_spec(tile),
+                    row_spec(tile), scale_spec(tile),
                 ),
                 out_shape=(
                     fbuf(jnp.float32),

@@ -7,7 +7,7 @@ atorch/atorch/optimizers/low_bit/. TPU redesign: Pallas VPU kernels doing
 blockwise absmax int8 quantization with stochastic rounding (the unbiased
 rounding the reference gets from its CUDA kernel's RNG); used by the
 8-bit optimizer in dlrover_tpu/optimizers/low_bit.py. Interpret mode
-covers CPU tests.
+covers CPU tests (``JAX_PLATFORMS=cpu`` only — common/backend.py).
 """
 
 from __future__ import annotations
@@ -19,11 +19,24 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from dlrover_tpu.common.backend import use_interpret
+
 BLOCK = 256  # quantization group size (elements)
+# rows per grid step: 512 x 256 x 4B = 512 KB per f32 operand. One
+# whole-array VMEM block is refused by the TPU compiler from a ~32M
+# element leaf on (a 4096x11008 f32 leaf wants 172 MB of VMEM), so every
+# kernel over [rows, BLOCK] buffers walks a grid of row tiles.
+TILE_ROWS = 512
 
 
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def row_spec(tile):
+    return pl.BlockSpec((tile, BLOCK), lambda i: (i, 0),
+                        memory_space=pltpu.VMEM)
+
+
+def scale_spec(tile):
+    return pl.BlockSpec((tile, 1), lambda i: (i, 0),
+                        memory_space=pltpu.VMEM)
 
 
 def _symmetric_scale(absmax):
@@ -66,7 +79,7 @@ def quantize_int8(x, seed: int = 0, stochastic: bool = True,
     Returns (q int8 [rows, BLOCK], scales f32 [rows, 1], orig_shape).
     """
     if interpret is None:
-        interpret = _use_interpret()
+        interpret = use_interpret()
     orig_shape = x.shape
     blocks, _n = _pad_to_blocks(x.reshape(-1))
     rows = blocks.shape[0]
@@ -74,16 +87,14 @@ def quantize_int8(x, seed: int = 0, stochastic: bool = True,
         u = jax.random.uniform(jax.random.key(seed), blocks.shape)
     else:
         u = jnp.zeros(blocks.shape, jnp.float32)
+    # rows are independent (blockwise absmax), so a ragged last tile
+    # only computes garbage rows whose writes are dropped
+    tile = min(TILE_ROWS, rows)
     q, scales = pl.pallas_call(
         functools.partial(_quant_kernel, stochastic=stochastic),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-        ],
-        out_specs=(
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-        ),
+        grid=(pl.cdiv(rows, tile),),
+        in_specs=[row_spec(tile), row_spec(tile)],
+        out_specs=(row_spec(tile), scale_spec(tile)),
         out_shape=(
             jax.ShapeDtypeStruct((rows, BLOCK), jnp.int8),
             jax.ShapeDtypeStruct((rows, 1), jnp.float32),
@@ -147,14 +158,13 @@ def dequantize_pos_log(q, scales, orig_shape, dtype=jnp.float32):
 def dequantize_int8(q, scales, orig_shape, dtype=jnp.float32,
                     interpret: bool | None = None):
     if interpret is None:
-        interpret = _use_interpret()
+        interpret = use_interpret()
+    tile = min(TILE_ROWS, q.shape[0])
     out = pl.pallas_call(
         _dequant_kernel,
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+        grid=(pl.cdiv(q.shape[0], tile),),
+        in_specs=[row_spec(tile), scale_spec(tile)],
+        out_specs=row_spec(tile),
         out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
         interpret=interpret,
     )(q, scales)

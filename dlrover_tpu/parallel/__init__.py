@@ -67,41 +67,3 @@ from dlrover_tpu.parallel.engine import (  # noqa: F401
     estimate_hbm_per_device,
     search_strategy,
 )
-
-
-def get_shard_map():
-    """The framework's single shard_map access point.
-
-    jax >= 0.8 exposes ``jax.shard_map`` (``check_vma`` kwarg) and is
-    used directly. Pre-0.8 builds only have the experimental variant
-    whose equivalent kwarg is ``check_rep`` — returned behind a shim
-    that translates ``check_vma`` so every call site speaks one
-    dialect (the overlapped-collective ring gathers and the CPU-mesh
-    parity tests need shard_map on 0.4.x too)."""
-    import jax
-
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn
-    try:
-        from jax.experimental.shard_map import shard_map as legacy
-    except ImportError as e:  # pragma: no cover - ancient jax
-        raise ImportError(
-            "dlrover_tpu requires a jax with shard_map (>= 0.4)"
-        ) from e
-
-    def shim(f, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        if "axis_names" in kwargs:
-            # jax>=0.8 partial-manual spelling -> the legacy ``auto``
-            # complement (axes NOT named stay automatic)
-            manual = set(kwargs.pop("axis_names"))
-            mesh = kwargs.get("mesh")
-            if mesh is not None:
-                kwargs["auto"] = frozenset(
-                    a for a in mesh.axis_names if a not in manual
-                )
-        return legacy(f, **kwargs)
-
-    return shim

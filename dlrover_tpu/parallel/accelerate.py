@@ -17,6 +17,7 @@ import dataclasses
 import functools
 from typing import Any, Callable, Optional
 
+from dlrover_tpu.common.backend import require_backend
 from dlrover_tpu.common.log import get_logger
 from dlrover_tpu.parallel.mesh import build_mesh, set_mesh
 from dlrover_tpu.parallel.sharding import (
@@ -239,6 +240,11 @@ def auto_accelerate(
     import optax
     from jax.sharding import NamedSharding, PartitionSpec
 
+    if devices is None:
+        # workers that never called init_distributed() still must not
+        # train on JAX's silent CPU fallback (explicit devices are the
+        # caller's word for where to run)
+        require_backend()
     strategy = strategy or Strategy()
     mesh = build_mesh(strategy.mesh, devices=devices)
     set_mesh(mesh)

@@ -142,8 +142,8 @@ def build_mesh(
     """Build a ``jax.sharding.Mesh`` over ``devices`` (default: all).
 
     Uses ``mesh_utils.create_device_mesh`` so that on real TPU slices the
-    logical axes are laid out along the physical ICI torus; falls back to a
-    plain reshape on CPU/virtual platforms.
+    logical axes are laid out along the physical ICI torus; the CPU's
+    virtual devices take a plain reshape (:func:`_device_array`).
 
     When ``config`` carries DCN slice factors (``dcn_data``/``dcn_pipe``/
     ``dcn_fsdp``), builds a hybrid ICI x DCN mesh via
@@ -154,8 +154,6 @@ def build_mesh(
     contiguous chunks) and strides the DCN axes across the groups.
     """
     import jax
-    import numpy as np
-    from jax.experimental import mesh_utils
     from jax.sharding import Mesh
 
     config = config or MeshConfig()
@@ -171,15 +169,24 @@ def build_mesh(
             {a: sizes[a] for a in AXIS_ORDER}, dcn,
         )
         return mesh
-    try:
-        dev_array = mesh_utils.create_device_mesh(
-            shape, devices=devices, allow_split_physical_axes=True
-        )
-    except Exception:  # noqa: BLE001 - virtual/cpu platforms
-        dev_array = np.asarray(devices).reshape(shape)
-    mesh = Mesh(dev_array, AXIS_ORDER)
+    mesh = Mesh(_device_array(shape, devices), AXIS_ORDER)
     logger.info("built mesh %s", {a: sizes[a] for a in AXIS_ORDER})
     return mesh
+
+
+def _device_array(shape, devices):
+    """Devices laid out along the physical ICI torus by ``mesh_utils``.
+    Only the CPU's virtual devices, which have no topology to honour,
+    take a plain reshape: on an accelerator a layout ``mesh_utils``
+    refuses is an error, not a mesh that routes collectives blind."""
+    import numpy as np
+    from jax.experimental import mesh_utils
+
+    if devices[0].platform == "cpu":
+        return np.asarray(devices, dtype=object).reshape(shape)
+    return mesh_utils.create_device_mesh(
+        shape, devices=devices, allow_split_physical_axes=True
+    )
 
 
 def _hybrid_device_array(devices, sizes: dict, dcn: dict):
@@ -236,15 +243,7 @@ def _hybrid_device_array(devices, sizes: dict, dcn: dict):
     # per-slice ICI layout, then stitch: the result axis a has the DCN
     # factor as its *outer* (slowest) stride so crossing a slice boundary
     # means moving along a DCN-tolerant axis only
-    slice_arrays = []
-    for g in groups:
-        try:
-            arr = mesh_utils.create_device_mesh(
-                ici_shape, devices=g, allow_split_physical_axes=True
-            )
-        except Exception:  # noqa: BLE001 - virtual/cpu platforms
-            arr = np.asarray(g, dtype=object).reshape(ici_shape)
-        slice_arrays.append(arr)
+    slice_arrays = [_device_array(ici_shape, g) for g in groups]
     stacked = np.asarray(slice_arrays, dtype=object).reshape(
         dcn_shape + ici_shape
     )
@@ -274,11 +273,7 @@ def set_mesh(mesh) -> None:
 def get_mesh():
     """The active mesh: an enclosing ``with mesh:`` context if present,
     else the process-global one set by :func:`set_mesh`."""
-    try:
-        # jax >= 0.8.2: the public pxla re-export is deprecated
-        from jax._src.mesh import thread_resources
-    except ImportError:  # older jax
-        from jax.interpreters.pxla import thread_resources
+    from jax._src.mesh import thread_resources
 
     env_mesh = thread_resources.env.physical_mesh
     if env_mesh is not None and not env_mesh.empty:

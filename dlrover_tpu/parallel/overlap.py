@@ -149,18 +149,11 @@ def layer_gather_fn(layer_axes, rules=None):
     if mesh.shape.get("pipe", 1) > 1:
         # the pipeline schedule runs stage scans inside its own manual
         # shard_map; sharding constraints from in there would target
-        # the wrong mesh (and pre-0.8 jax cannot even detect it via
-        # get_abstract_mesh) — stages keep the plain schedule
+        # the wrong mesh — stages keep the plain schedule
         return None
-    try:
-        from jax.sharding import get_abstract_mesh
-
-        amesh = get_abstract_mesh()
-        if not amesh.empty and amesh.manual_axes:
-            if _GATHER_AXIS in set(amesh.manual_axes):
-                return None
-    except ImportError:
-        pass
+    amesh = jax.sharding.get_abstract_mesh()
+    if not amesh.empty and _GATHER_AXIS in set(amesh.manual_axes):
+        return None
     n = int(mesh.shape[_GATHER_AXIS])
 
     is_axes_leaf = lambda x: isinstance(x, tuple) or x is None  # noqa: E731
@@ -178,9 +171,6 @@ def layer_gather_fn(layer_axes, rules=None):
 
     if mode == "manual":
         from dlrover_tpu.ops.collectives import ring_all_gather
-        from dlrover_tpu.parallel import get_shard_map
-
-        shard_map = get_shard_map()
 
         def gather_leaf(leaf, plan):
             spec, gathered, dim = plan
@@ -190,7 +180,7 @@ def layer_gather_fn(layer_axes, rules=None):
             def ring(shard):
                 return ring_all_gather(shard, _GATHER_AXIS, n, dim=dim)
 
-            return shard_map(
+            return jax.shard_map(
                 ring, mesh=mesh, in_specs=spec, out_specs=gathered,
                 check_vma=False,
             )(leaf)

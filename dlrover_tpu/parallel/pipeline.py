@@ -39,54 +39,7 @@ logger = get_logger(__name__)
 AXIS = "pipe"
 
 
-def _probe_barrier_ad() -> bool:
-    try:
-        jax.make_jaxpr(jax.grad(
-            lambda x: jax.lax.optimization_barrier(x).sum()
-        ))(jnp.ones((1,)))
-        return True
-    except NotImplementedError:
-        return False
-
-
-@functools.lru_cache(maxsize=1)
-def _barrier_fn():
-    """``jax.lax.optimization_barrier`` — or, on jax builds whose
-    barrier has no differentiation rule (0.4.x), a custom_vjp identity
-    wrapper that barriers the primal and passes cotangents through.
-    The native rule is preferred when present: it also pins the
-    BACKWARD schedule, which the 1F1B memory bound relies on."""
-    if _probe_barrier_ad():
-        return jax.lax.optimization_barrier
-
-    @jax.custom_vjp
-    def barrier(xs):
-        return jax.lax.optimization_barrier(xs)
-
-    def fwd(xs):
-        return jax.lax.optimization_barrier(xs), None
-
-    def bwd(_res, cts):
-        return (cts,)
-
-    barrier.defvjp(fwd, bwd)
-    return barrier
-
-
-def _opt_barrier(xs):
-    return _barrier_fn()(xs)
-
-
-def partial_manual_supported() -> bool:
-    """Whether this jax can compile the pipe schedules' PARTIAL-manual
-    shard_map (manual over ``pipe``, other mesh axes automatic) when a
-    non-pipe axis has extent > 1. jax >= 0.8 can; pre-0.8's SPMD
-    partitioner fatally CHECK-fails on the manual-subgroup shardings
-    the mixed region produces (axis_index -> partition-id is rejected,
-    and in-region collectives trip hlo_sharding_util manual-subgroup
-    CHECKs), so callers on legacy builds must keep the non-pipe mesh
-    extent at 1 alongside an active pipe axis."""
-    return hasattr(jax, "shard_map")
+_opt_barrier = jax.lax.optimization_barrier
 
 
 def pipe_size() -> int:
@@ -237,9 +190,7 @@ def pipeline_apply(
         return outbuf[None], aux_total
 
     n_extra = len(extra_mb)
-    from dlrover_tpu.parallel import get_shard_map
-
-    out_stacked, aux_total = get_shard_map()(
+    out_stacked, aux_total = jax.shard_map(
         schedule,
         mesh=mesh,
         in_specs=(
@@ -343,8 +294,6 @@ def pipeline_loss_1f1b(
     lx_mb = tuple(to_micro(a) for a in last_extras)
 
     from jax.sharding import PartitionSpec as P
-
-    from dlrover_tpu.parallel import get_shard_map
 
     R = 2 * S - 1        # ring-buffer slots: max in-flight stage inputs
     T = M + 2 * (S - 1)  # fwd drains at M+S-2, bwd at M-1+2(S-1)
@@ -549,7 +498,7 @@ def pipeline_loss_1f1b(
             ),
             lp,
         )
-        return get_shard_map()(
+        return jax.shard_map(
             schedule,
             mesh=mesh,
             in_specs=(
@@ -693,8 +642,6 @@ def pipeline_loss_1f1b_interleaved(
     lx_mb = tuple(to_micro(a) for a in last_extras)
 
     from jax.sharding import PartitionSpec as P
-
-    from dlrover_tpu.parallel import get_shard_map
 
     # [8, T, S]: fm fv bm bv rfm rfv rbm rbv
     keys = ("fm", "fv", "bm", "bv", "rfm", "rfv", "rbm", "rbv")
@@ -907,7 +854,7 @@ def pipeline_loss_1f1b_interleaved(
             ),
             lp,
         )
-        return get_shard_map()(
+        return jax.shard_map(
             schedule,
             mesh=mesh,
             in_specs=(

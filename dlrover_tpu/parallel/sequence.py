@@ -34,7 +34,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from dlrover_tpu.ops.attention import NEG_INF, _use_interpret, flash_attention
+from dlrover_tpu.common.backend import use_interpret
+from dlrover_tpu.ops.attention import NEG_INF, flash_attention
 from dlrover_tpu.parallel.mesh import get_mesh
 
 __all__ = [
@@ -246,7 +247,7 @@ def ring_attention(
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     kernel_ok = use_kernel and causal and (
-        _use_interpret() or q.shape[-1] % 128 == 0
+        use_interpret() or q.shape[-1] % 128 == 0
     )
     if kernel_ok and n > 1:
         return _ring_flash(q, k, v, axis_name, n, float(sm_scale),
@@ -363,9 +364,7 @@ def sequence_sharded_attention(
                                causal=causal, sm_scale=sm_scale)
     else:
         raise ValueError(f"unknown sequence-parallel impl {impl!r}")
-    from dlrover_tpu.parallel import get_shard_map
-
-    return get_shard_map()(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False,
     )(q, k, v)

@@ -102,17 +102,7 @@ def shard_logical(x, logical_axes, rules: Optional[LogicalRules] = None):
     # Inside a partial-manual shard_map (e.g. the pipeline schedule) the
     # constraint must target the current *abstract* mesh, with manual
     # axes stripped from the spec (they are per-device there).
-    # Older jax builds (< 0.5) have no get_abstract_mesh — there the
-    # partial-manual case cannot arise either, so constrain on the
-    # concrete mesh directly.
-    from jax.sharding import PartitionSpec
-
-    try:
-        from jax.sharding import get_abstract_mesh
-    except ImportError:
-        return jax.lax.with_sharding_constraint(
-            x, NamedSharding(mesh, spec)
-        )
+    from jax.sharding import PartitionSpec, get_abstract_mesh
 
     amesh = get_abstract_mesh()
     if not amesh.empty and amesh.manual_axes:

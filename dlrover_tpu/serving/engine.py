@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from dlrover_tpu.common.backend import require_backend
 from dlrover_tpu.common.log import get_logger
 from dlrover_tpu.models.llama import (
     LlamaConfig,
@@ -280,6 +281,9 @@ class DecodeEngine:
         capacity: int = 128,
         min_bucket: int = MIN_BUCKET,
     ):
+        # a decode pool that lost the chip must not serve from JAX's
+        # silent CPU fallback
+        require_backend()
         self.config = config
         self.params = params
         self.slots = int(slots)

@@ -12,10 +12,17 @@ def init_distributed():
 
     The TPU analogue of torch's init_process_group bootstrap: the master's
     rendezvous designated a coordinator (rank-0 host); every worker calls
-    jax.distributed.initialize against it. Single-process jobs no-op.
+    jax.distributed.initialize against it. Single-process jobs skip
+    that — but every worker checks here, at start-up, that it got the
+    accelerator: without ``JAX_PLATFORMS=cpu`` a worker that finds none
+    fails now, with one message, instead of training on JAX's silent
+    CPU fallback.
     """
+    from dlrover_tpu.common.backend import require_backend
+
     num = int(os.environ.get(NodeEnv.JAX_NUM_PROCESSES, "1"))
     if num <= 1:
+        require_backend()
         return False
     import jax
 
@@ -26,6 +33,7 @@ def init_distributed():
         num_processes=num,
         process_id=process_id,
     )
+    require_backend()
     return True
 
 

@@ -204,9 +204,7 @@ def _publish_restore_stats(stats: dict):
 def pipelined_device_put(tree, stats: dict | None = None):
     """Host pytree -> device, per-leaf: every leaf's transfer is
     dispatched before any is waited on (async dispatch overlaps the
-    transfers; through a multiplexing link — the remote-tunnel case —
-    the in-flight puts pipeline instead of paying serial RTTs), then
-    one barrier at the end. Emits the ``ckpt.restore.h2d`` interval so
+    transfers), then one barrier at the end. Emits the ``ckpt.restore.h2d`` interval so
     the blocking leg lands in the goodput ledger's checkpoint bucket."""
     import jax
 
@@ -440,9 +438,9 @@ class CheckpointEngine:
         # split the drain into its two real legs for the fill metric:
         # materialise = blocking on the device link (np.asarray waits on
         # the in-flight D2H transfer), fill = the host-side shm memcpy.
-        # ckpt_shm_fill_gbps must describe the LATTER — the old bench
-        # window divided state bytes by the whole drain and so reported
-        # the device link as "shm fill" (the 0.007 GB/s anomaly).
+        # ckpt_shm_fill_gbps must describe the LATTER — dividing state
+        # bytes by the whole drain reports the device link as "shm
+        # fill".
         materialize_s = 0.0
         fill_s = 0.0
 

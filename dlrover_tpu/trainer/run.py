@@ -68,17 +68,6 @@ def parse_args(argv=None):
         help="Prometheus /metrics port on the agent "
              "(-1 = disabled [default], 0 = ephemeral, >0 = fixed)",
     )
-    parser.add_argument(
-        "--compilation-cache-dir",
-        type=str,
-        default=os.environ.get(
-            "DLROVER_TPU_COMPILE_CACHE",
-            "/tmp/dlrover_tpu/compile_cache",
-        ),
-        help="persistent XLA compilation cache shared across worker "
-             "restarts (elastic restarts recompile from cache); "
-             "pass '' to disable",
-    )
     parser.add_argument("training_script", type=str)
     parser.add_argument(
         "training_script_args", nargs=argparse.REMAINDER
@@ -167,7 +156,6 @@ def run(args) -> int:
         rdzv_timeout=args.rdzv_timeout,
         rdzv_elastic_wait=args.rdzv_elastic_wait,
         log_dir=args.log_dir,
-        compilation_cache_dir=args.compilation_cache_dir,
         metrics_port=args.metrics_port,
     )
     script_args = list(args.training_script_args)

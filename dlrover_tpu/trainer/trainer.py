@@ -162,14 +162,25 @@ class Trainer:
         # (re)shape (_refresh_flops re-runs in _adopt_accel), device
         # memory_stats availability probed once on first use
         self._flops_per_token = 0.0
+        self._peak_flops: float | None = None
         self._device_mem_ok: bool | None = None
         self._refresh_flops()
         # step the on-disk pending/latest prestep sidecar was last
         # serialized at (skip-rewrite cache; None = dirty)
         self._prestep_sidecar_step = None
-        # brain cadence adoption: the master client, probed lazily on
-        # the first log boundary (None = unprobed, False = no master)
-        self._cadence_client = None
+        # the master client for the log-boundary advisories (brain
+        # cadence adoption, in-band hardware re-probe), probed lazily
+        # on the first log boundary (None = unprobed, False = no master)
+        self._advisory_client = None
+        # in-band hardware re-probe: this process holds the chip, so
+        # the device legs can only run here (agent/probe.py). Armed a
+        # full interval out — the agent's join-time probe just ran.
+        from dlrover_tpu.agent import probe as hw_probe
+
+        self._reprobe = None
+        if not hw_probe.probe_disabled():
+            self._reprobe = hw_probe.default_scheduler()
+            self._reprobe.seed({"elapsed_s": 0.0})
         self._engine = None
         if args.flash_checkpoint:
             from dlrover_tpu.trainer.flash_checkpoint.engine import (
@@ -267,6 +278,27 @@ class Trainer:
                 tree["data"] = packed
         return tree
 
+    def _release_state(self):
+        """Free the live train state's device buffers and return its
+        abstract twin (shape, dtype, sharding per leaf) — what a
+        restore needs of a state it is about to replace."""
+        import jax
+
+        def abstract(x):
+            return jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=x.sharding
+            )
+
+        is_array = lambda x: isinstance(x, jax.Array)  # noqa: E731
+        twin = jax.tree.map(
+            lambda x: abstract(x) if is_array(x) else x, self.state
+        )
+        for leaf in jax.tree.leaves(self.state):
+            if is_array(leaf):
+                leaf.delete()
+        self.state = self._accel.state = None
+        return twin
+
     def maybe_resume(self) -> int:
         """Restore the newest checkpoint (shm preferred, then storage),
         including dataloader/sampler progress so a restarted job picks
@@ -274,13 +306,22 @@ class Trainer:
         Returns the restored step (0 = fresh)."""
         if self._engine is None:
             return 0
+        tree = self._ckpt_tree()
+        if self._engine.latest_step() >= 0:
+            # restore INTO the state's place, not beside it: the
+            # freshly initialised state is about to be replaced, and
+            # holding it while the restored arrays land doubles the
+            # resident bytes — a state over half the device memory (8 GB
+            # of a 16 GB chip at two Llama-2-7B-width layers) could
+            # never resume. The targets keep shapes and shardings only.
+            tree["train"] = self._release_state()
         # fallback targets: a checkpoint written without the data leaf
         # (oversized loader state) and the pre-wrapper layout (bare
         # train state) must both keep restoring
-        targets = [self._ckpt_tree()]
-        if "data" in targets[0]:
-            targets.append({"train": self.state})
-        targets.append(self.state)
+        targets = [tree]
+        if "data" in tree:
+            targets.append({"train": tree["train"]})
+        targets.append(tree["train"])
         restored = None
         first_err = None
         for tgt in targets:
@@ -294,18 +335,22 @@ class Trainer:
                 break
         if restored is None:
             if first_err is not None:
-                if os.environ.get("DLROVER_TPU_IGNORE_CKPT"):
-                    logger.warning(
-                        "ignoring incompatible checkpoint "
-                        "(DLROVER_TPU_IGNORE_CKPT set): %s", first_err,
-                    )
-                    return 0
-                raise ValueError(
-                    f"existing checkpoint is incompatible with the "
-                    f"current model/optimizer layout: {first_err}. "
-                    f"Delete the checkpoint dir or set "
-                    f"DLROVER_TPU_IGNORE_CKPT=1 to start fresh."
-                ) from first_err
+                if not os.environ.get("DLROVER_TPU_IGNORE_CKPT"):
+                    raise ValueError(
+                        f"existing checkpoint is incompatible with the "
+                        f"current model/optimizer layout: {first_err}. "
+                        f"Delete the checkpoint dir or set "
+                        f"DLROVER_TPU_IGNORE_CKPT=1 to start fresh."
+                    ) from first_err
+                logger.warning(
+                    "ignoring incompatible checkpoint "
+                    "(DLROVER_TPU_IGNORE_CKPT set): %s", first_err,
+                )
+            if self.state is None:
+                # released for a restore that found nothing usable
+                self._adopt_accel(
+                    list(self._accel.mesh.devices.flat), None
+                )
             return 0
         tree, step = restored
         if isinstance(tree, dict) and "train" in tree:
@@ -457,14 +502,17 @@ class Trainer:
                             telemetry.gauge_set(
                                 "train.step.last_s", dur_s
                             )
-                            if tokens and self._flops_per_token > 0:
+                            if (
+                                tokens and self._peak_flops
+                                and self._flops_per_token > 0
+                            ):
                                 from dlrover_tpu.common import mfu
 
                                 telemetry.gauge_set(
                                     "train.mfu",
                                     mfu.mfu(
                                         self._flops_per_token * tokens,
-                                        dur_s,
+                                        dur_s, self._peak_flops,
                                     ),
                                 )
                     self._emit_device_gauges()
@@ -477,6 +525,7 @@ class Trainer:
                         )
                         telemetry.flush()
                         self._maybe_adopt_cadence()
+                        self._maybe_reprobe()
                     write_runtime_metrics(self.global_step)
                     if (
                         self._engine is not None
@@ -536,6 +585,47 @@ class Trainer:
 
     # ------------------------------------------- brain cadence adoption
 
+    def _master(self):
+        """The advisory master client, or None when there is no master
+        (probed once)."""
+        if self._advisory_client is None:
+            try:
+                from dlrover_tpu.agent.master_client import (
+                    build_master_client,
+                )
+
+                self._advisory_client = build_master_client() or False
+            except Exception:  # noqa: BLE001 - env without a master
+                self._advisory_client = False
+        return self._advisory_client or None
+
+    def _maybe_reprobe(self):
+        """Continuous hardware check, in band: a governed low-cadence
+        re-probe (floor interval stretched until the probe costs under
+        its overhead budget) at a step boundary of the process that
+        holds the chip, feeding the master's fingerprint store —
+        sustained degradation becomes a hw_degraded verdict and a
+        drain, not a mystery slowdown. One report per host: local rank
+        0 probes every local device."""
+        from dlrover_tpu.common.constants import NodeEnv
+
+        if (
+            self._reprobe is None
+            or not self._reprobe.due()
+            or os.environ.get(NodeEnv.LOCAL_RANK, "0") != "0"
+        ):
+            return
+        client = self._master()
+        if client is None:
+            return
+        rank = int(os.environ.get(NodeEnv.NODE_RANK, "0"))
+        report = self._reprobe.run(rank)
+        try:
+            client.report_probe(rank, report)
+        except Exception:  # noqa: BLE001 - the health signal is
+            # advisory; a dropped sample waits for the next window
+            logger.warning("in-band probe report failed", exc_info=True)
+
     def _maybe_adopt_cadence(self):
         """Adopt the master brain's goodput-aware checkpoint cadence
         (Young/Daly-tuned ``save_steps``) from the run-config channel.
@@ -546,24 +636,13 @@ class Trainer:
             not self.args.adopt_cadence
             or self._engine is None
             or not self.args.save_steps
-            or self._cadence_client is False
         ):
             return
-        if self._cadence_client is None:
-            try:
-                from dlrover_tpu.agent.master_client import (
-                    build_master_client,
-                )
-
-                self._cadence_client = build_master_client() or False
-            except Exception:  # noqa: BLE001 - env without a master
-                self._cadence_client = False
-            if self._cadence_client is False:
-                return
+        client = self._master()
+        if client is None:
+            return
         try:
-            configs = self._cadence_client.get_elastic_run_config(
-                retries=1
-            )
+            configs = client.get_elastic_run_config(retries=1)
         except (ConnectionError, OSError):
             return
         except Exception:  # noqa: BLE001 - advisory channel
@@ -587,10 +666,18 @@ class Trainer:
     # ------------------------------------------- live MFU / HBM gauges
 
     def _refresh_flops(self):
-        """Model FLOPs per token, computed once per (re)shape — never
-        in the step loop. Explicit ``model_flops_per_token`` wins
-        (transformers pass the exact attention-inclusive value via
-        common/mfu); the fallback is the dense 6 * params estimate."""
+        """Model FLOPs per token and the mesh's peak FLOP/s, computed
+        once per (re)shape — never in the step loop. Explicit
+        ``model_flops_per_token`` wins (transformers pass the exact
+        attention-inclusive value via common/mfu); the fallback is the
+        dense 6 * params estimate."""
+        from dlrover_tpu.common import mfu
+
+        devices = self._accel.mesh.devices
+        # None on the CPU: no peak, so no train.mfu gauge there
+        self._peak_flops = mfu.peak_flops(devices.flat[0])
+        if self._peak_flops:
+            self._peak_flops *= devices.size
         if self.args.model_flops_per_token > 0:
             self._flops_per_token = float(
                 self.args.model_flops_per_token
@@ -629,17 +716,10 @@ class Trainer:
             self._prof.set_context("unfingerprinted", "devices=?")
 
     def _emit_compile_cache_gauges(self):
-        cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR", "")
-        if not cache_dir:
-            try:
-                import jax
+        import jax
 
-                cache_dir = (
-                    jax.config.jax_compilation_cache_dir or ""
-                )
-            except Exception:  # noqa: BLE001 - knob absent in old jax
-                cache_dir = ""
-        if not cache_dir or not os.path.isdir(cache_dir):
+        cache_dir = jax.config.jax_compilation_cache_dir or ""
+        if not os.path.isdir(cache_dir):
             return
         entries = size = 0
         try:

@@ -12,7 +12,6 @@ import optax
 import pytest
 
 
-from tests.conftest import requires_partial_manual
 
 from dlrover_tpu.parallel import (
     MeshConfig,
@@ -139,7 +138,6 @@ class TestAccelerateThirdPartyModel:
             np.random.RandomState(0).randint(0, VOCAB, (batch, 17))
         )
 
-    @requires_partial_manual
     def test_fsdp_tensor_pipe_no_handwritten_axes(self):
         strategy = Strategy(
             mesh=MeshConfig(pipe=2, data=1, fsdp=2, tensor=2),
@@ -169,7 +167,6 @@ class TestAccelerateThirdPartyModel:
         assert all(np.isfinite(losses))
         assert losses[-1] < losses[0]
 
-    @requires_partial_manual
     def test_matches_unsharded_training(self):
         """The derived sharding must not change the math: one dp-only
         step equals one fsdp+tensor+pipe step."""

@@ -205,6 +205,107 @@ class TestNodeCheck:
             client.close()
 
 
+_POISONED = (
+    "import sys; sys.modules['jax'] = None; "  # every `import jax` raises
+    "from {module} import main; sys.exit(main({argv!r}))"
+)
+
+
+class TestAgentStaysOffJax:
+    """One process per chip: a process that initialises a JAX backend
+    owns the chip, so the agent — which must hand it to its workers —
+    and the master may never import jax. The probe and node-check
+    payloads run as child processes that have exited before a worker
+    is spawned."""
+
+    def test_join_network_check_and_monitor_never_import_jax(
+        self, tmp_path
+    ):
+        """A real master and a real tpu-run agent, each in a fresh
+        interpreter where ``import jax`` raises: network check (payload
+        child), join (probe child), worker spawn, monitor ticks with
+        the resource/heartbeat reporters beside them, clean finish."""
+        import subprocess
+
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        env = {
+            **os.environ, "PYTHONPATH": repo, "JAX_PLATFORMS": "cpu",
+            "DLROVER_TPU_SOCKET_DIR": str(tmp_path / "socks"),
+            "ELASTIC_JOB_NAME": f"offjax{os.getpid()}",
+        }
+        env.pop("DLROVER_MASTER_ADDR", None)
+        master = subprocess.Popen(
+            [sys.executable, "-c", _POISONED.format(
+                module="dlrover_tpu.master.main",
+                argv=["--platform", "local", "--node_num", "1",
+                      "--port", "0"],
+            )],
+            env=env, stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
+            text=True,
+        )
+        try:
+            addr = ""
+            for line in master.stdout:
+                if line.startswith("DLROVER_MASTER_ADDR="):
+                    addr = line.strip().partition("=")[2]
+                    break
+            assert addr, "poisoned master never came up"
+            script = tmp_path / "ok.py"
+            # outlive a few 5 s monitor ticks' worth of reporters
+            script.write_text("import time; time.sleep(1.0)\n")
+            agent = subprocess.run(
+                [sys.executable, "-c", _POISONED.format(
+                    module="dlrover_tpu.trainer.run",
+                    argv=["--nnodes", "1", "--network-check",
+                          "--auto-config", "--log-dir",
+                          str(tmp_path / "logs"), str(script)],
+                )],
+                env={**env, "DLROVER_MASTER_ADDR": addr},
+                capture_output=True, text=True, timeout=240,
+            )
+            assert agent.returncode == 0, agent.stderr[-3000:]
+            # both payloads really ran — as children, not in the agent
+            assert "hardware probe (child)" in agent.stderr
+            assert "all workers succeeded" in agent.stderr
+        finally:
+            master.terminate()
+            master.wait(timeout=30)
+
+
+class TestNoSilentCpuFallback:
+    def test_unpinned_worker_without_accelerator_fails_at_start(self):
+        """JAX drops to the CPU with a warning when it cannot take the
+        chip. A worker started WITHOUT ``JAX_PLATFORMS=cpu`` that finds
+        no accelerator must fail at start-up with one message instead
+        of training there in interpret mode."""
+        import subprocess
+
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        env = {**os.environ, "PYTHONPATH": repo}
+        for key in ("JAX_PLATFORMS", "XLA_FLAGS"):
+            env.pop(key, None)
+        worker = subprocess.run(
+            [sys.executable, "-c",
+             "from dlrover_tpu.trainer import init_distributed; "
+             "init_distributed(); print('TRAINING ON THE CPU')"],
+            env=env, capture_output=True, text=True, timeout=240,
+        )
+        if worker.returncode == 0 and "fell back" not in worker.stderr:
+            pytest.skip("an accelerator is attached to this machine")
+        assert worker.returncode != 0
+        assert "TRAINING ON THE CPU" not in worker.stdout
+        assert "no accelerator" in worker.stderr
+        # the same worker, pinned: the CPU is where it was asked to run
+        pinned = subprocess.run(
+            [sys.executable, "-c",
+             "from dlrover_tpu.trainer import init_distributed; "
+             "init_distributed(); print('pinned ok')"],
+            env={**env, "JAX_PLATFORMS": "cpu"},
+            capture_output=True, text=True, timeout=240,
+        )
+        assert pinned.returncode == 0, pinned.stderr[-2000:]
+
+
 class TestRunConfigSharing:
     def test_late_joiner_adopts_rank0_flags(self, local_master):
         """Rank 0 publishes launch flags; a MISCONFIGURED later joiner's

@@ -360,7 +360,8 @@ class TestCadenceController:
         class Stub:
             args = TrainingArgs(save_steps=5)
             _engine = object()
-            _cadence_client = FakeClient()
+            _advisory_client = FakeClient()
+            _master = Trainer._master
 
         stub = Stub()
         Trainer._maybe_adopt_cadence(stub)
@@ -376,14 +377,16 @@ class TestCadenceController:
         class Stub:
             args = TrainingArgs(save_steps=0)  # cadence saving off
             _engine = object()
-            _cadence_client = ExplodingClient()
+            _advisory_client = ExplodingClient()
+            _master = Trainer._master
 
         Trainer._maybe_adopt_cadence(Stub())
 
         class Stub2:
             args = TrainingArgs(save_steps=5, adopt_cadence=False)
             _engine = object()
-            _cadence_client = ExplodingClient()
+            _advisory_client = ExplodingClient()
+            _master = Trainer._master
 
         Trainer._maybe_adopt_cadence(Stub2())
 

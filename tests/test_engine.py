@@ -70,7 +70,7 @@ class TestCandidates:
 
     def test_candidates_never_propose_low_precision(self):
         """auto_accelerate must never hand out a dtype that slows the
-        step (VERDICT r3 #3): fp8/int8 are measured slower than bf16 on
+        step (an earlier review): fp8/int8 are measured slower than bf16 on
         current TPUs, so the generator only emits bfloat16; explicit
         user requests go through a warn-gate in accelerate.py."""
         cands = candidate_strategies(8, small_analysis(), hbm_gb=16.0)
@@ -406,7 +406,7 @@ class TestMeasuredSearch:
 
 class TestHbmAttentionTerm:
     """The activation estimate must charge attention-era residual widths
-    (VERDICT: the old single-tensor-per-layer term green-lit infeasible
+    (an earlier review: the old single-tensor-per-layer term green-lit infeasible
     long-context meshes that burned a dry-run compile each)."""
 
     def _a(self):

@@ -9,7 +9,6 @@ import numpy as np
 import optax
 import pytest
 
-from tests.conftest import requires_partial_manual
 
 
 from dlrover_tpu.models import llama_init, llama_loss_fn
@@ -174,7 +173,6 @@ class TestEndToEndNumerics:
             losses.append(float(m["loss"]))
         return losses
 
-    @requires_partial_manual
     def test_fp8_composes_with_1f1b_pipeline(self):
         """compute_dtype='fp8' and pipe_schedule='1f1b' together: the
         autocast flag is up while the fused schedule traces, so the
@@ -239,8 +237,8 @@ class TestInt8Dot:
         assert rel < 1.0
 
     def test_int8_tracks_bf16_training(self):
-        """Strategy.compute_dtype='int8' loss parity vs bf16 (VERDICT
-        r3 #3: the low-precision knob must not distort training)."""
+        """Strategy.compute_dtype='int8' loss parity vs bf16 (an earlier
+        review: the low-precision knob must not distort training)."""
         helper = TestEndToEndNumerics()
         l8 = helper._run("int8")
         l16 = helper._run("bfloat16")

@@ -305,7 +305,7 @@ class TestLMPPO:
 
 
 class TestMoEDecode:
-    """MoE policies decode through the same KV-cache path (VERDICT item:
+    """MoE policies decode through the same KV-cache path (an earlier review's item:
     rl/generation previously raised NotImplementedError for MoE)."""
 
     def _moe_config(self):

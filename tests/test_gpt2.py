@@ -8,7 +8,6 @@ import numpy as np
 import optax
 import pytest
 
-from tests.conftest import requires_partial_manual
 
 
 from dlrover_tpu.master.elastic_ps import ElasticPsService
@@ -112,7 +111,6 @@ class TestGPT2:
         assert all(np.isfinite(losses))
         assert losses[-1] < losses[0]  # memorizing one batch
 
-    @requires_partial_manual
     def test_pipeline_strategy(self, tiny):
         import dataclasses
 
@@ -149,7 +147,6 @@ class TestGPT2:
         with pytest.raises(ValueError, match="max_seq_len"):
             _gpt2_1f1b_loss(cfg, params, too_long)
 
-    @requires_partial_manual
     def test_1f1b_matches_gpipe_loss(self, tiny):
         import dataclasses
 

@@ -56,7 +56,14 @@ def _seed_fleet(mgr, ranks=(0, 1, 2), ms=100.0, now=0.0):
 
 
 @pytest.fixture
-def disarm():
+def disarm(monkeypatch):
+    # the chaos tests bound the UN-degraded legs at 100 ms: keep the
+    # numpy stand-in matmul too small for a loaded host's BLAS threads
+    # to push it over that by themselves (256-wide took 130-430 ms on
+    # a busy sandbox, sleep or no sleep)
+    from dlrover_tpu.agent import probe
+
+    monkeypatch.setattr(probe, "MATMUL_SIZE", 32)
     yield
     chaos.uninstall()
 

@@ -39,7 +39,6 @@ from dlrover_tpu.parallel import (
     MeshConfig,
     Strategy,
     auto_accelerate,
-    get_shard_map,
 )
 
 
@@ -65,7 +64,7 @@ class TestRingCollectives:
 
         n = 4
         mesh = _mesh(n)
-        sm = get_shard_map()
+        sm = jax.shard_map
         x = jnp.asarray(
             np.random.RandomState(0).randn(8, 12).astype(np.float32)
         )
@@ -93,7 +92,7 @@ class TestRingCollectives:
 
         n = 4
         mesh = _mesh(n)
-        sm = get_shard_map()
+        sm = jax.shard_map
         x = jnp.asarray(
             np.random.RandomState(1).randn(8, 6).astype(np.float32)
         )
@@ -122,7 +121,7 @@ class TestRingCollectives:
 
         n = 4
         mesh = _mesh(n)
-        sm = get_shard_map()
+        sm = jax.shard_map
         rng = np.random.RandomState(2)
         W = jnp.asarray(rng.randn(8, 6).astype(np.float32))
         X = jnp.asarray(rng.randn(8, 8).astype(np.float32))
@@ -153,7 +152,7 @@ class TestRingCollectives:
     def test_reduce_scatter_rejects_indivisible(self):
         with pytest.raises(ValueError, match="not divisible"):
             _mesh(4)
-            sm = get_shard_map()
+            sm = jax.shard_map
             from jax.sharding import PartitionSpec as P
 
             mesh = _mesh(4)

@@ -630,7 +630,7 @@ def _token_problem(vocab=32, dim=4, bs=4, seq=8, n=16):
 
 class TestTrainerMfu:
     def test_live_mfu_agrees_with_bench_formula(
-        self, tmp_path, fresh_telemetry,
+        self, tmp_path, fresh_telemetry, monkeypatch,
     ):
         """Acceptance: per-step ``train.mfu`` must agree with bench's
         offline computation — both call common/mfu on the same FLOPs
@@ -639,6 +639,8 @@ class TestTrainerMfu:
         from dlrover_tpu.common import mfu as mfu_mod
         from dlrover_tpu.trainer.trainer import Trainer, TrainingArgs
 
+        # the CPU has no row in the peak table: the test supplies one
+        monkeypatch.setitem(mfu_mod.PEAK_FLOPS, "cpu", 1e12)
         vocab, dim, bs, seq = 32, 4, 4, 8
         tokens = bs * seq
         params = vocab * dim
@@ -662,7 +664,7 @@ class TestTrainerMfu:
         dur_pts = series["train.step.last_s"]
         assert len(mfu_pts) == 7  # 8 steps minus the compile step
         for mp, dp in zip(mfu_pts, dur_pts):
-            offline = mfu_mod.mfu(flops_step, dp[3])
+            offline = mfu_mod.mfu(flops_step, dp[3], 8e12)
             assert mp[3] == pytest.approx(offline, rel=1e-9)
         # steady-state only: the compile step contributes no sample
         events = [e for e in snap["events"] if e["kind"] == "compile"]
@@ -673,11 +675,12 @@ class TestTrainerMfu:
         assert len(series["ckpt.arena.pooled_bytes"]) == 8
 
     def test_default_flops_estimate_is_dense(
-        self, tmp_path, fresh_telemetry,
+        self, tmp_path, fresh_telemetry, monkeypatch,
     ):
         from dlrover_tpu.common import mfu as mfu_mod
         from dlrover_tpu.trainer.trainer import Trainer, TrainingArgs
 
+        monkeypatch.setitem(mfu_mod.PEAK_FLOPS, "cpu", 1e12)
         loss_fn, init_fn, axes, batches = _token_problem()
         args = TrainingArgs(
             output_dir=str(tmp_path / "out"), max_steps=4, log_steps=0,
@@ -691,16 +694,40 @@ class TestTrainerMfu:
         series = {s["name"]: s["points"] for s in snap["series"]}
         mp, dp = series["train.mfu"][-1], series["train.step.last_s"][-1]
         assert mp[3] == pytest.approx(
-            mfu_mod.mfu(6.0 * 32 * 4 * 32, dp[3]), rel=1e-9
+            mfu_mod.mfu(6.0 * 32 * 4 * 32, dp[3], 8e12), rel=1e-9
         )
 
-    def test_peak_flops_env_override(self, monkeypatch):
+    def test_peak_comes_from_the_device_kind_table(self):
+        """One table keyed by device_kind: the v5e row is the published
+        bf16 peak, the CPU has no peak, an accelerator that is not in
+        the table is an error and never a default."""
+        import types
+
         from dlrover_tpu.common import mfu as mfu_mod
 
-        monkeypatch.setenv(mfu_mod.PEAK_FLOPS_ENV, "1e12")
-        assert mfu_mod.mfu(1e10, 0.01) == pytest.approx(1.0)
-        monkeypatch.setenv(mfu_mod.PEAK_FLOPS_ENV, "garbage")
-        assert mfu_mod.peak_flops() == mfu_mod.DEFAULT_PEAK_FLOPS
+        def dev(platform, kind):
+            return types.SimpleNamespace(platform=platform, device_kind=kind)
+
+        assert mfu_mod.peak_flops(dev("tpu", "TPU v5 lite")) == 197e12
+        assert mfu_mod.peak_flops(dev("cpu", "cpu")) is None
+        with pytest.raises(ValueError, match="TPU v99"):
+            mfu_mod.peak_flops(dev("tpu", "TPU v99"))
+        assert mfu_mod.mfu(1e10, 0.01, 1e12) == pytest.approx(1.0)
+
+    def test_no_mfu_gauge_without_a_peak(self, tmp_path, fresh_telemetry):
+        """A CPU run never writes a number under the device metric's
+        name: no peak, no ``train.mfu`` series."""
+        from dlrover_tpu.trainer.trainer import Trainer, TrainingArgs
+
+        loss_fn, init_fn, axes, batches = _token_problem()
+        args = TrainingArgs(
+            output_dir=str(tmp_path / "out"), max_steps=3, log_steps=0,
+            flash_checkpoint=False,
+        )
+        Trainer(loss_fn, init_fn, axes, args, train_data=batches).train()
+        names = {s["name"] for s in telemetry.snapshot()["series"]}
+        assert "train.step.last_s" in names
+        assert "train.mfu" not in names
 
 
 # -------------------------------------------------------------------------
@@ -711,6 +738,7 @@ class TestTrainerMfu:
 class TestLiveMetricsPlaneEndToEnd:
     def test_smoke_live_plane(
         self, local_master, tmp_path, fresh_telemetry, isolated_ckpt_env,
+        monkeypatch,
     ):
         """The acceptance scenario, in process: a chaos-exercised
         training job ships delta-encoded telemetry to a real master
@@ -722,10 +750,12 @@ class TestLiveMetricsPlaneEndToEnd:
         through re-registration and a simulated failover."""
         from dlrover_tpu.agent.master_client import MasterClient
         from dlrover_tpu.agent.monitor import TelemetryReporter
-        from dlrover_tpu.common import chaos
+        from dlrover_tpu.common import chaos, mfu
         from dlrover_tpu.master.http_plane import MasterHttpPlane
         from dlrover_tpu.trainer.trainer import Trainer, TrainingArgs
 
+        # the CPU has no row in the peak table: the test supplies one
+        monkeypatch.setitem(mfu.PEAK_FLOPS, "cpu", 1e12)
         svc = local_master.servicer
         plane = MasterHttpPlane(svc)
         plane.start()

@@ -236,7 +236,7 @@ class TestKernelStatsExport:
     def test_top_ops_published_and_served(self, tmp_path, monkeypatch):
         """e2e: profile a jitted step window -> publish top-op stats ->
         agent /metrics serves dlrtpu_kernel_self_ms gauges (the online
-        xpu_timer-style per-kernel export, VERDICT r3 #8)."""
+        xpu_timer-style per-kernel export, an earlier review)."""
         import urllib.request
 
         import jax

@@ -159,9 +159,7 @@ def test_softmax_cross_entropy_ignore_index():
 
 def test_vocab_parallel_cross_entropy():
     from jax.sharding import Mesh, PartitionSpec as P
-    from dlrover_tpu.parallel import get_shard_map
-
-    shard_map = get_shard_map()
+    shard_map = jax.shard_map
 
     rng = np.random.RandomState(1)
     vocab, n_shard = 64, 4
@@ -189,6 +187,41 @@ def test_quantize_roundtrip():
     # error bounded by scale/2 per block
     max_scale = float(scales.max())
     assert float(jnp.max(jnp.abs(out - x))) <= max_scale * 0.51
+
+
+@pytest.mark.parametrize(
+    "shape",
+    [(1000,), (1024, 256), (1000, 300)],
+    ids=["one-tile", "two-whole-tiles", "ragged-last-tile"],
+)
+def test_quantize_row_tiles_match_one_block(shape, monkeypatch):
+    """The kernels walk a grid of row tiles (one whole-array VMEM block
+    is refused by the TPU compiler at real leaf sizes): whatever the
+    tiling — a single short tile, whole tiles, a ragged last one — the
+    result is bit for bit what one block over the whole array gives,
+    and that is the plain blockwise absmax quantization."""
+    from dlrover_tpu.ops import quantization
+
+    rng = np.random.RandomState(5)
+    x = jnp.asarray((rng.randn(*shape) * 3).astype(np.float32))
+    q, scales, orig = quantize_int8(x, stochastic=False)
+    out = dequantize_int8(q, scales, orig)
+    monkeypatch.setattr(quantization, "TILE_ROWS", 1 << 30)
+    q1, scales1, _ = quantize_int8(x, stochastic=False)
+    np.testing.assert_array_equal(np.asarray(q), np.asarray(q1))
+    np.testing.assert_array_equal(np.asarray(scales), np.asarray(scales1))
+    np.testing.assert_array_equal(
+        np.asarray(out), np.asarray(dequantize_int8(q1, scales1, orig))
+    )
+    flat = np.asarray(x).reshape(-1)
+    blocks = np.zeros((q.shape[0], quantization.BLOCK), np.float32)
+    blocks.reshape(-1)[: flat.size] = flat
+    absmax = np.abs(blocks).max(axis=-1, keepdims=True)
+    np.testing.assert_allclose(
+        np.asarray(scales), np.where(absmax == 0.0, 1.0, absmax / 127.0),
+        rtol=1e-6,
+    )
+    assert float(jnp.max(jnp.abs(out - x))) <= float(scales.max()) * 0.51
 
 
 def test_quantize_stochastic_unbiased():

@@ -4,8 +4,8 @@ Strategy.remat="none" must mean NONE: the model's own per-layer
 ``jax.checkpoint`` (and the qdot residual ``checkpoint_name`` tags the
 quant-aware policy would consume) must vanish from the traced step —
 before the gate, a leaked checkpoint custom-call charged ~7% of the
-remat=none headline step (BENCH_r05 top_ops ``checkpoint.10``,
-25.7 ms). Intentional non-remat checkpoints — the fused CE's
+remat=none headline step (25.7 ms in a pre-PR-1 chip run).
+Intentional non-remat checkpoints — the fused CE's
 logits-memory chunking — survive the gate untouched.
 """
 

@@ -249,10 +249,16 @@ class TestContinuousBatching:
     def test_eos_evicts_early(self, model):
         config, params = model
         eng = DecodeEngine(config, params, slots=1, capacity=32)
-        p = _prompt(70, 5)
-        # find the greedy continuation, then rerun with its second
-        # token as the EOS id — the request must finish early
-        ref = _greedy_reference(config, params, p, 6)
+        # find a greedy continuation whose first two tokens differ,
+        # then rerun with its second token as the EOS id — the request
+        # must finish early, on the SECOND token (a continuation that
+        # opens with a repeat would hit EOS on the first)
+        for seed in range(70, 90):
+            p = _prompt(seed, 5)
+            ref = _greedy_reference(config, params, p, 6)
+            if ref[0] != ref[1]:
+                break
+        assert ref[0] != ref[1], "no prompt with two distinct first tokens"
         sched = ContinuousBatchingScheduler(eng, rng_seed=7)
         sched.submit(ServeRequest(
             request_id="r0", prompt=p, max_new_tokens=6,

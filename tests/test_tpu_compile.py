@@ -1,0 +1,332 @@
+"""Ask the installed TPU compiler, without a chip.
+
+Interpret mode — what every other kernel test here runs — cannot see
+what Mosaic and XLA:TPU refuse: a block that does not fit VMEM, a cast
+Mosaic does not lower, a program that does not fit HBM. These tests
+AOT-compile the main path's kernels and programs at the published
+Llama-2-7B widths (dim 4096, 32 heads x 128, mlp 11008, vocab 32000,
+sequence 2048) for a *described* v5e (jax.experimental.topologies), so
+each later PR is held to them at no chip time. A compile that passes is
+not a chip run: nothing here executes, and no time or result comes out.
+
+One file on purpose: only one process may hold the TPU library, so the
+topology is described inside a module fixture (never at import, in a
+``skipif`` or in ``parametrize``) and every compile runs in this
+process. The program's CPU branches (``use_interpret``) are steered
+here, in the test: ``interpret=False`` for kernels, a patched
+``require_backend`` for whole programs.
+"""
+
+import dataclasses
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import optax
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
+
+from dlrover_tpu.common import backend
+from dlrover_tpu.models.llama import (
+    PRESETS,
+    llama_init,
+    llama_logical_axes,
+    llama_loss_fn,
+)
+from dlrover_tpu.ops.attention import flash_attention, flash_attention_bshd
+from dlrover_tpu.ops.fused_optim import fused_adamw
+from dlrover_tpu.ops.quantization import (
+    BLOCK,
+    dequantize_int8,
+    quantize_int8,
+)
+from dlrover_tpu.parallel import MeshConfig, Strategy, auto_accelerate
+from dlrover_tpu.parallel.accelerate import TrainState
+from dlrover_tpu.serving.engine import (
+    init_slot_cache,
+    slot_decode,
+    slot_prefill,
+)
+
+SEQ = 2048
+# depth cut to 2 (the layer scan compiles one body whatever the depth)
+CONFIG = dataclasses.replace(
+    PRESETS["llama2-7b"], n_layers=2, max_seq_len=SEQ
+)
+LEAF = (CONFIG.dim, CONFIG.mlp_dim)  # the largest per-layer leaf
+V5E_HBM_BYTES = 16 * 1024 ** 3
+
+
+@pytest.fixture(scope="module")
+def topo():
+    # the compiler would otherwise log under /tmp
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 - no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_persistent_cache():
+    """A compile for a described chip is written to the persistent
+    cache but cannot be read back without one (the next run warns and
+    recompiles): switch the cache off around this file's compiles."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """Whole programs pick their kernels' mode from the backend: make
+    them take the TPU branch (compiled kernels, not interpret mode)."""
+    monkeypatch.setattr(backend, "require_backend", lambda: "tpu")
+
+
+def _shaped(tree, sharding):
+    """Shapes placed on the described device(s): there is no device to
+    hold an array, so every program is lowered from shapes."""
+    if isinstance(sharding, jax.sharding.Sharding):
+        return jax.tree.map(
+            lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype,
+                                           sharding=sharding),
+            tree,
+        )
+    return jax.tree.map(
+        lambda l, s: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=s),
+        tree, sharding,
+    )
+
+
+def _compile(fn, *args, **jit_kw):
+    return jax.jit(fn, **jit_kw).lower(*args).compile()
+
+
+def _kernels(compiled) -> int:
+    return compiled.as_text().count("tpu_custom_call")
+
+
+def _device_bytes(compiled) -> int:
+    m = compiled.memory_analysis()
+    return (
+        m.temp_size_in_bytes + m.argument_size_in_bytes
+        + m.output_size_in_bytes - m.alias_size_in_bytes
+    )
+
+
+def _sum_grad(fn):
+    return jax.value_and_grad(
+        lambda *a: fn(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2)
+    )
+
+
+# ------------------------------------------------------------- kernels
+
+
+@pytest.mark.parametrize("layout", ["bhsd", "fused-rope", "bshd"])
+def test_flash_attention_forward_and_backward(one_chip, layout):
+    b, h, hd = 1, CONFIG.n_heads, CONFIG.head_dim
+    blocks = dict(
+        block_q=CONFIG.attn_block_q, block_k=CONFIG.attn_block_k,
+        interpret=False,
+    )
+    if layout == "bshd":
+        qkv = jax.ShapeDtypeStruct((b, SEQ, h, hd), jnp.bfloat16)
+        fn = functools.partial(flash_attention_bshd, **blocks)
+        args = (qkv,) * 3
+    else:
+        qkv = jax.ShapeDtypeStruct((b, h, SEQ, hd), jnp.bfloat16)
+        args = (qkv,) * 3
+        if layout == "fused-rope":
+            table = jax.ShapeDtypeStruct((b, SEQ, hd), jnp.bfloat16)
+            args += (table, table)
+
+            def fn(q, k, v, cos, sin):
+                return flash_attention(
+                    q, k, v, rope_cos=cos, rope_sin=sin, **blocks
+                )
+        else:
+            fn = functools.partial(flash_attention, **blocks)
+    compiled = _compile(_sum_grad(fn), *_shaped(args, one_chip))
+    # the forward kernel and at least one backward kernel
+    assert _kernels(compiled) >= 2
+
+
+def test_quantize_int8_real_leaf(one_chip):
+    """One whole-array VMEM block was refused here (172 MB against
+    128 MB of VMEM): the kernel walks a grid of row tiles."""
+    x = jax.ShapeDtypeStruct(LEAF, jnp.float32)
+    compiled = _compile(
+        lambda x: quantize_int8(x, interpret=False)[:2],
+        *_shaped((x,), one_chip),
+    )
+    assert _kernels(compiled) == 1
+
+
+def test_dequantize_int8_real_leaf(one_chip):
+    rows = LEAF[0] * LEAF[1] // BLOCK
+    q = jax.ShapeDtypeStruct((rows, BLOCK), jnp.int8)
+    scales = jax.ShapeDtypeStruct((rows, 1), jnp.float32)
+    compiled = _compile(
+        lambda q, s: dequantize_int8(q, s, LEAF, interpret=False),
+        *_shaped((q, scales), one_chip),
+    )
+    assert _kernels(compiled) == 1
+
+
+@pytest.mark.parametrize("bits", [32, 8])
+def test_fused_adamw_real_leaves(one_chip, bits):
+    """bits=8 was refused (Mosaic lowers no float32 -> uint8 cast; the
+    nu re-encode goes through int32)."""
+    tree = {
+        "mlp": jax.ShapeDtypeStruct(LEAF, jnp.float32),
+        "attn": jax.ShapeDtypeStruct(
+            (CONFIG.dim, CONFIG.dim), jnp.float32
+        ),
+    }
+    opt = fused_adamw(
+        1e-3, weight_decay=0.1, clip_norm=1.0, bits=bits, interpret=False
+    )
+    state = jax.eval_shape(opt.init, tree)
+    compiled = _compile(
+        opt.update,
+        *_shaped((tree, state, tree), one_chip),
+    )
+    assert _kernels(compiled) == 1
+
+
+# ------------------------------------------------------------ programs
+
+
+def _abstract_params(dtype=None):
+    params = jax.eval_shape(
+        lambda: llama_init(CONFIG, jax.random.key(0))
+    )
+    if dtype is None:
+        return params
+    return jax.tree.map(
+        lambda l: jax.ShapeDtypeStruct(l.shape, dtype), params
+    )
+
+
+def _abstract_key():
+    return jax.eval_shape(lambda: jax.random.key(0))
+
+
+def test_llama_forward_backward(one_chip, on_tpu):
+    loss = llama_loss_fn(CONFIG)
+    compiled = _compile(
+        jax.value_and_grad(lambda p, b: loss(
+            jax.tree.map(lambda x: x.astype(jnp.bfloat16), p), b, None
+        )),
+        *_shaped(
+            (_abstract_params(),
+             {"tokens": jax.ShapeDtypeStruct((2, SEQ + 1), jnp.int32)}),
+            one_chip,
+        ),
+    )
+    # flash forward + backward inside the layer scan
+    assert _kernels(compiled) >= 2
+    assert _device_bytes(compiled) < V5E_HBM_BYTES
+
+
+def _train_step(topo, mesh_config, n_devices, batch):
+    """The Trainer's jitted step (auto_accelerate, default AdamW) on
+    described devices: lowered from an abstract state, donated like
+    the real one."""
+    optimizer = optax.adamw(1e-3)
+
+    def init(rng):
+        return llama_init(CONFIG, rng)
+
+    def init_state():
+        params = init(jax.random.key(0))
+        return TrainState(
+            step=jnp.zeros((), jnp.int32), params=params,
+            opt_state=optimizer.init(params),
+        )
+
+    state = jax.eval_shape(init_state)
+    accel = auto_accelerate(
+        llama_loss_fn(CONFIG), init, optimizer,
+        llama_logical_axes(CONFIG),
+        strategy=Strategy(mesh=mesh_config),
+        devices=topo.devices[:n_devices], reuse_state=state,
+    )
+    replicated = NamedSharding(accel.mesh, PartitionSpec())
+    return accel, _compile(
+        accel.train_step,
+        _shaped(state, accel.state_shardings),
+        _shaped(
+            {"tokens": jax.ShapeDtypeStruct((batch, SEQ + 1), jnp.int32)},
+            replicated,
+        ),
+        _shaped(_abstract_key(), replicated),
+        donate_argnums=(0,),
+    )
+
+
+def test_train_step_fits_one_chip(topo, on_tpu):
+    _accel, compiled = _train_step(
+        topo, MeshConfig(data=1, fsdp=1), n_devices=1, batch=2
+    )
+    assert _kernels(compiled) >= 2
+    # state (fp32 params + two AdamW moments) + gradients + activations
+    assert _device_bytes(compiled) < 0.9 * V5E_HBM_BYTES
+
+
+def test_train_step_fsdp_over_four_chips(topo, on_tpu):
+    """The sharded path chip_smoke.py --chips 4 runs: every chip holds
+    its quarter of the state, and the compiler put the fsdp collectives
+    in."""
+    accel, compiled = _train_step(
+        topo, MeshConfig(fsdp=4), n_devices=4, batch=4
+    )
+    assert accel.mesh.shape["fsdp"] == 4
+    text = compiled.as_text()
+    assert "all-gather" in text and "tpu_custom_call" in text
+    one_chip_state = 12 * CONFIG.param_count()  # bytes, unsharded
+    m = compiled.memory_analysis()
+    assert m.argument_size_in_bytes < one_chip_state / 4 * 1.05
+
+
+@pytest.mark.parametrize("program", ["prefill-1024", "decode"])
+def test_serving_programs(one_chip, on_tpu, program):
+    slots, capacity = 8, 1024
+    params = _abstract_params(jnp.bfloat16)
+    cache = jax.eval_shape(
+        lambda: init_slot_cache(CONFIG, slots, capacity)
+    )
+    i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32)
+    f32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32)
+    if program == "decode":
+        fn = functools.partial(slot_decode, CONFIG)
+        args = (
+            params, cache, i32((slots,)), i32((slots,)),
+            jax.ShapeDtypeStruct((slots,), jnp.bool_), _abstract_key(),
+            f32((slots,)),
+        )
+    else:
+        fn = functools.partial(slot_prefill, CONFIG)
+        args = (
+            params, cache, i32((capacity,)), i32(()), i32(()),
+            _abstract_key(), f32(()),
+        )
+    compiled = _compile(fn, *_shaped(args, one_chip))
+    assert _device_bytes(compiled) < V5E_HBM_BYTES

@@ -672,7 +672,9 @@ def test_exclude_straggler_end_to_end(
     def fake_check(*_a, **_k):
         return True, elapsed_by_thread[threading.current_thread().name]
 
-    monkeypatch.setattr(node_check_mod, "run_node_check", fake_check)
+    monkeypatch.setattr(
+        node_check_mod, "run_node_check_child", fake_check
+    )
 
     results = {}
 

@@ -143,6 +143,65 @@ class TestTrainerCheckpointResume:
         t2.close()
 
 
+    def test_resume_replaces_the_fresh_state_in_place(self, tmp_path):
+        """A restore lands INTO the state's place: the freshly
+        initialised buffers are freed before the restored arrays
+        arrive, so a state over half the device memory can resume (two
+        resident copies would not fit the chip)."""
+        loss_fn, init_fn, axes, batches = linear_problem()
+        data = batches()
+        args = make_args(
+            tmp_path, max_steps=4, flash_checkpoint=True
+        )
+        t1 = Trainer(loss_fn, init_fn, axes, args, train_data=data)
+        t1.train()
+        w_after = np.asarray(t1.state.params["w"])
+        t1.close()
+
+        t2 = Trainer(loss_fn, init_fn, axes, args, train_data=data)
+        fresh = jax.tree.leaves(t2.state)
+        shardings = [x.sharding for x in fresh]
+        assert t2.maybe_resume() == 4
+        assert all(x.is_deleted() for x in fresh)
+        restored = jax.tree.leaves(t2.state)
+        assert [x.sharding for x in restored] == shardings
+        np.testing.assert_allclose(
+            np.asarray(t2.state.params["w"]), w_after, rtol=1e-6
+        )
+        # ...and the step still runs on the restored state
+        t2.args.max_steps = 6
+        t2.train()
+        assert t2.global_step == 6
+        t2.close()
+
+    def test_ignored_incompatible_checkpoint_reinitialises(
+        self, tmp_path, monkeypatch
+    ):
+        """The fresh state was released for a restore that then found
+        nothing usable (DLROVER_TPU_IGNORE_CKPT): the trainer
+        initialises again and trains from scratch."""
+        loss_fn, init_fn, axes, batches = linear_problem()
+        data = batches()
+        args = make_args(
+            tmp_path, max_steps=4, flash_checkpoint=True
+        )
+        t1 = Trainer(loss_fn, init_fn, axes, args, train_data=data)
+        t1.train()
+        t1.close()
+
+        def wider_init(rng):
+            return {"w": jnp.zeros((8, 2)), "b": jnp.zeros((2,))}
+
+        monkeypatch.setenv("DLROVER_TPU_IGNORE_CKPT", "1")
+        t2 = Trainer(loss_fn, wider_init, axes, args, train_data=data)
+        assert t2.maybe_resume() == 0
+        assert t2.state.params["w"].shape == (8, 2)
+        assert not any(
+            x.is_deleted() for x in jax.tree.leaves(t2.state)
+        )
+        t2.close()
+
+
 class TestTrainerDataStateResume:
     def _make_loader(self):
         from dlrover_tpu.trainer.elastic.dataloader import (
