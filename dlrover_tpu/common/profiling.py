@@ -32,8 +32,10 @@ jax.profiler's XPlane capture instead of an LD_PRELOAD hook:
 Cost contract: with sampling disabled (``DLROVER_PROF_SAMPLE_STEPS=0``
 or no parse toolchain) the per-step hooks are one attribute load and
 one ``is None``/counter branch. Enabled, the steady-state cost is one
-modulo per step plus one capture+parse every N steps, measured by the
-bench's ``profile_sample_overhead_pct`` key (<2% gate).
+modulo per step plus one capture+parse every N steps. What each sample
+cost the training loop is the counter ``prof.sample.cost_s``, on the
+Trainer's own step clock; the governor spaces samples out until that
+cost amortizes under the budget (PERF.md has the chip readings).
 """
 
 from __future__ import annotations
@@ -46,7 +48,7 @@ import queue
 import threading
 import time
 
-from dlrover_tpu.common import telemetry, trace_summary
+from dlrover_tpu.common import telemetry, trace_summary, tracing
 from dlrover_tpu.common.chaos import chaos_point
 from dlrover_tpu.common.log import get_logger
 
@@ -61,7 +63,10 @@ ENV_REGRESSION_RATIO = "DLROVER_PROF_REGRESSION_RATIO"
 # measured per-window cost amortizes under this. 0 disables governing
 # (fixed cadence — tests, short benches).
 ENV_OVERHEAD_PCT = "DLROVER_PROF_OVERHEAD_PCT"
-DEFAULT_OVERHEAD_PCT = 2.0
+# a share of the job's throughput, really spent since the governor
+# amortizes against the true step time (PERF.md section 6, PR 26, has
+# what a sample costs on a v5e and what 2 % then took)
+DEFAULT_OVERHEAD_PCT = 0.2
 
 DEFAULT_SAMPLE_STEPS = 64
 DEFAULT_CAPTURE_STEPS = 2
@@ -465,13 +470,17 @@ class DeviceTimeSampler:
     payload is worth shipping even unparsed.
 
     **Cost governor**: ``sample_steps`` is the FLOOR of the sampling
-    gap, not a promise. Each window's measured overhead (profiler
-    start/stop + dir churn, on the step thread) is amortized against
-    the EWMA step time, and the next sample is pushed out until the
-    steady-state cost stays under ``overhead_pct`` (default 2 %) — so
-    "always-on" self-limits instead of taxing a fast-stepping job, and
-    the <2 % contract is ENFORCED by construction, not hoped for. Deep
-    captures bypass the governor (someone explicitly asked).
+    gap, not a promise. What a window cost the loop — the step thread's
+    time in the two hooks (profiler start/stop + dir churn) plus what
+    the window's steps took, completion to completion as the Trainer
+    hands it in, beyond as many EWMA steps — is counted into
+    ``prof.sample.cost_s`` and amortized against the EWMA step time:
+    the next sample is pushed out until the steady-state cost stays
+    under ``overhead_pct`` (default 0.2 %), so "always-on" self-limits
+    instead of taxing a fast-stepping job. Deep captures bypass the
+    governor (someone explicitly asked). No window opens while the
+    parse thread is still converting the last one: a second profiler
+    session beside a running conversion stalled the loop for seconds.
     """
 
     def __init__(
@@ -553,6 +562,11 @@ class DeviceTimeSampler:
         return self._sampling
 
     @property
+    def _parsing(self) -> int:
+        """Windows handed to the parse thread and not yet converted."""
+        return self._queue.unfinished_tasks
+
+    @property
     def step_ewma_s(self) -> float:
         """The governor's running estimate of an untraced step's wall
         time — the denominator its overhead budget amortizes against."""
@@ -574,6 +588,8 @@ class DeviceTimeSampler:
         if self._window is not None:
             return
         if self._pending is not None:
+            if self._parsing:
+                return  # the capture opens once the conversion is over
             req = self._pending
             self._pending = None
             self._last_capture_id = req.capture_id
@@ -596,12 +612,17 @@ class DeviceTimeSampler:
                 )
             return
         if self._sampling and step > 0 and step >= self._next_sample:
+            if self._parsing:
+                # as with a refused start below: skip this sample
+                self._next_sample = step + self.sample_steps
+                return
             tdir = os.path.join(self.out_dir, "sample")
             import shutil
 
             t_begin = time.perf_counter()
-            shutil.rmtree(tdir, ignore_errors=True)
-            started = self._backend.start(tdir)
+            with tracing.span("prof.sample.start", step=step):
+                shutil.rmtree(tdir, ignore_errors=True)
+                started = self._backend.start(tdir)
             cost = time.perf_counter() - t_begin
             if started:
                 self._window = {
@@ -611,6 +632,7 @@ class DeviceTimeSampler:
                     "steps": 1,
                     "t0": time.monotonic(),
                     "cost_s": cost,
+                    "steps_s": 0.0,
                 }
             else:
                 # a refused start (another trace active) still re-arms
@@ -629,12 +651,33 @@ class DeviceTimeSampler:
                     else 0.9 * self._step_ewma + 0.1 * dur_s
                 )
             return
+        if win["kind"] == "sample" and step >= win["start_step"]:
+            win["steps_s"] += dur_s
         if step < win["start_step"] + win["steps"] - 1:
             return
         self._window = None
         t_begin = time.perf_counter()
         try:
-            self._backend.stop(block_on=block_on)
+            with tracing.span(
+                f"prof.{win['kind']}.stop", step=step
+            ) as stop_span:
+                try:
+                    self._backend.stop(block_on=block_on)
+                finally:
+                    if win["kind"] == "sample":
+                        # neither hook's time is inside a step of the
+                        # Trainer's clock (a step starts at its
+                        # dispatch), so the two add
+                        slower = (
+                            win["steps_s"] - win["steps"] * self._step_ewma
+                            if self._step_ewma > 0 else 0.0
+                        )
+                        cost = (
+                            win["cost_s"] + (time.perf_counter() - t_begin)
+                            + max(slower, 0.0)
+                        )
+                        stop_span.annotate(cost_s=cost)
+                        self._govern(step, cost)
         except Exception:  # noqa: BLE001 - a stop failure must not
             # take the training step down; the window is simply lost
             logger.warning("profiler stop failed", exc_info=True)
@@ -644,13 +687,6 @@ class DeviceTimeSampler:
                     error="profiler stop failed",
                 )
             return
-        finally:
-            if win["kind"] == "sample":
-                self._govern(
-                    step,
-                    win.get("cost_s", 0.0)
-                    + (time.perf_counter() - t_begin),
-                )
         win["wall_s"] = time.monotonic() - win["t0"]
         win["end_step"] = step
         self._ensure_worker()
@@ -661,6 +697,7 @@ class DeviceTimeSampler:
         amortizes under the budget: gap >= cost / (budget * step_time)
         makes steady-state overhead <= budget by construction."""
         self.last_window_cost_s = window_cost_s
+        telemetry.counter_inc("prof.sample.cost_s", window_cost_s)
         gap = self.sample_steps
         if self._overhead_frac > 0 and self._step_ewma > 0:
             gap = max(gap, int(
@@ -690,10 +727,11 @@ class DeviceTimeSampler:
             if job is None:
                 return
             try:
-                if job["kind"] == "sample":
-                    self._parse_sample(job)
-                else:
-                    self._finish_capture(job)
+                with tracing.span("prof.parse", window=job["kind"]):
+                    if job["kind"] == "sample":
+                        self._parse_sample(job)
+                    else:
+                        self._finish_capture(job)
             except Exception:  # noqa: BLE001 - the parse thread must
                 # survive a bad trace; a capture failure is acked below
                 logger.warning(
@@ -718,6 +756,8 @@ class DeviceTimeSampler:
                         job["request"].capture_id, False,
                         error="capture parse/artifact failed",
                     )
+            finally:
+                self._queue.task_done()
 
     @staticmethod
     def _await_xplane(trace_dir: str, timeout: float = 5.0) -> bool:

@@ -26,6 +26,15 @@ else, which doubles as the flight recorder's payload
 (:mod:`dlrover_tpu.common.flight`): the last ~4096 spans/events of a
 crashing process are exactly its post-mortem.
 
+**On the device's clock**: in a process that has imported JAX every
+span also opens a ``jax.profiler.TraceAnnotation("dlrover." + name)``
+for its lifetime, so whenever a profiler session is on (a benchmark's,
+the device-time sampler's, a deep capture's) the program's spans lie
+in the ``.xplane.pb`` on the profiler's own clock, next to the device
+plane. :func:`annotation` is the trace-only form for spans that occur
+every step and must not fill the ring. A process that never imports
+JAX (agent, master) pays one ``sys.modules`` lookup.
+
 Cost model: the ambient context is a thread-local assignment; the event
 emission is the usual telemetry hook (one lock + one deque append), and
 a no-op when telemetry is disabled. Propagation survives RPC retries
@@ -41,14 +50,30 @@ from __future__ import annotations
 
 import contextlib
 import os
+import sys
 import threading
 import time
 
 from dlrover_tpu.common import telemetry
 
 SPAN_EVENT = "span"
+# prefix of the program's spans in a profiler trace
+ANNOTATION_PREFIX = "dlrover."
 
 _tls = threading.local()
+_NO_ANNOTATION = contextlib.nullcontext()
+
+
+def annotation(name: str, **stats):
+    """A span in the profiler's own trace and nowhere else: a
+    ``TraceAnnotation`` named ``dlrover.<name>`` (``stats`` become the
+    event's stats). With no profiler session on it costs the
+    annotation's disabled check; it never imports JAX itself."""
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None)
+    if profiler is None:
+        return _NO_ANNOTATION
+    return profiler.TraceAnnotation(ANNOTATION_PREFIX + name, **stats)
 
 
 def _new_id(nbytes: int = 8) -> str:
@@ -118,7 +143,12 @@ def span(name: str, **labels):
     sp = Span(name, trace, sid, parent["span"] if parent else "", labels)
     status = "ok"
     try:
-        yield sp
+        # numbers only: a string could hold the separators of the
+        # annotation's own metadata encoding
+        with annotation(name, **{
+            k: v for k, v in labels.items() if isinstance(v, (int, float))
+        }):
+            yield sp
     except BaseException:
         status = "error"
         raise
@@ -134,6 +164,33 @@ def span(name: str, **labels):
             status=status,
             **sp.labels,
         )
+
+
+def spans_from_xplane(path: str) -> list[dict]:
+    """The program's spans as a profiler session recorded them: every
+    ``dlrover.*`` event of the host planes of one ``.xplane.pb``, as
+    ``{"name" (without the prefix), "start_ns", "dur_ns", "thread",
+    "stats"}`` on the profiler's clock, the one the device planes of
+    the same file are on. Spans of one thread nest; ``thread`` tells
+    the loop's from the parse thread's."""
+    from jax.profiler import ProfileData
+
+    spans = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for index, line in enumerate(plane.lines):
+            thread = f"{plane.name}/{index}:{line.name}"
+            for event in line.events:
+                if event.name.startswith(ANNOTATION_PREFIX):
+                    spans.append({
+                        "name": event.name[len(ANNOTATION_PREFIX):],
+                        "start_ns": float(event.start_ns),
+                        "dur_ns": float(event.duration_ns),
+                        "thread": thread,
+                        "stats": dict(event.stats),
+                    })
+    return spans
 
 
 # -------------------------------------------------------------------------

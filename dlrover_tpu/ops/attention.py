@@ -58,6 +58,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from dlrover_tpu.common.backend import use_interpret
+from dlrover_tpu.ops.named import named_pallas_call
 
 NEG_INF = -1e30
 
@@ -604,7 +605,8 @@ def _fwd(q, k, v, layout, heads, kv_heads, sm_scale, causal, block_q,
         out_specs=(q_spec, row_spec),
         scratch_shapes=scratch_shapes,
     )
-    o, lse = pl.pallas_call(
+    o, lse = named_pallas_call(
+        "flash_fwd",
         kernel,
         grid_spec=grid_spec,
         out_shape=(
@@ -856,7 +858,8 @@ def _fwd_fused(q, k, v, heads, kv_heads, sm_scale, causal, block_q,
         (1, block_k, k.shape[2]), lambda b, t, m: (b, m[1, t], 0))
     lse_spec = pl.BlockSpec(
         (1, heads, block_q, STATS_W), lambda b, t, m: (b, 0, m[0, t], 0))
-    o, lse = pl.pallas_call(
+    o, lse = named_pallas_call(
+        "flash_fwd",
         functools.partial(
             _fwdf_kernel, sm_scale=sm_scale, causal=causal,
             block_q=block_q, block_k=block_k, q_len=q_len, kv_len=kv_len,
@@ -908,7 +911,8 @@ def _bwd_fused(heads, kv_heads, sm_scale, causal, block_q, block_k,
 
     meta_q = jnp.asarray(_tile_meta(
         nq, nk, block_q, block_k, q_len, kv_len, causal, False))
-    dq = pl.pallas_call(
+    dq = named_pallas_call(
+        "flash_bwd_dq",
         functools.partial(
             _bwdf_dq_kernel, sm_scale=sm_scale, causal=causal,
             block_q=block_q, block_k=block_k, q_len=q_len, kv_len=kv_len,
@@ -929,7 +933,8 @@ def _bwd_fused(heads, kv_heads, sm_scale, causal, block_q, block_k,
 
     meta_kv = jnp.asarray(_tile_meta(
         nq, nk, block_q, block_k, q_len, kv_len, causal, True))
-    dk, dv = pl.pallas_call(
+    dk, dv = named_pallas_call(
+        "flash_bwd_dkv",
         functools.partial(
             _bwdf_dkv_kernel, sm_scale=sm_scale, causal=causal,
             block_q=block_q, block_k=block_k, q_len=q_len, kv_len=kv_len,
@@ -1149,7 +1154,8 @@ def _delta_bhsd(do, o, block_q, interpret):
         (1, 1, block_q, head_dim), lambda b, h, i: (b, h, i, 0))
     out_spec = pl.BlockSpec(
         (1, 1, block_q, STATS_W), lambda b, h, i: (b, h, i, 0))
-    return pl.pallas_call(
+    return named_pallas_call(
+        "flash_bwd_delta",
         _delta_kernel,
         grid=(batch, H, pl.cdiv(q_len, block_q)),
         in_specs=[spec, spec],
@@ -1314,7 +1320,8 @@ def _bwd_onepass(layout, H, KVH, q_len, kv_len, head_dim, sm_scale,
         in_specs += _rope_specs(block_q, block_k, head_dim)
         operands += [rope_cos, rope_sin, rope_cos, rope_sin]
         scratch.append(pltpu.VMEM((block_k, head_dim), k.dtype))
-    dq, dk_full, dv_full = pl.pallas_call(
+    dq, dk_full, dv_full = named_pallas_call(
+        "flash_bwd_fused",
         functools.partial(
             _bwd_onepass_kernel, sm_scale=sm_scale, causal=causal,
             block_q=block_q, block_k=block_k, q_len=q_len,
@@ -1407,7 +1414,8 @@ def _bwd(layout, heads, kv_heads, sm_scale, causal, block_q, block_k,
 
     meta_q = jnp.asarray(_tile_meta(
         nq, nk, block_q, block_k, q_len, kv_len, causal, False))
-    dq = pl.pallas_call(
+    dq = named_pallas_call(
+        "flash_bwd_dq",
         functools.partial(
             _bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
             block_q=block_q, block_k=block_k, q_len=q_len, kv_len=kv_len,
@@ -1442,7 +1450,8 @@ def _bwd(layout, heads, kv_heads, sm_scale, causal, block_q, block_k,
     else:
         kv_out_shape = (batch, kv_len, H * head_dim)
     kv_out_spec = _kv_out(layout, block_k=block_k, head_dim=head_dim)
-    dk_full, dv_full = pl.pallas_call(
+    dk_full, dv_full = named_pallas_call(
+        "flash_bwd_dkv",
         functools.partial(
             _bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
             block_q=block_q, block_k=block_k, q_len=q_len, kv_len=kv_len,
@@ -1546,7 +1555,8 @@ def ring_fwd_block(q, k, v, q_start, k_start, sm_scale,
     if interpret is None:
         interpret = use_interpret()
     g = _RingSetup(q, k, q_start, k_start, block_q, block_k, False)
-    return pl.pallas_call(
+    return named_pallas_call(
+        "flash_fwd",
         functools.partial(
             _fwd_kernel, sm_scale=sm_scale, **g.kernel_args()),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -1581,7 +1591,8 @@ def ring_dq_block(q, k, v, do, lse, delta, q_start, k_start, sm_scale,
     if interpret is None:
         interpret = use_interpret()
     g = _RingSetup(q, k, q_start, k_start, block_q, block_k, False)
-    return pl.pallas_call(
+    return named_pallas_call(
+        "flash_bwd_dq",
         functools.partial(
             _bwd_dq_kernel, sm_scale=sm_scale, **g.kernel_args()),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -1607,7 +1618,8 @@ def ring_dkv_block(q, k, v, do, lse, delta, q_start, k_start, sm_scale,
     if interpret is None:
         interpret = use_interpret()
     g = _RingSetup(q, k, q_start, k_start, block_q, block_k, True)
-    dk_full, dv_full = pl.pallas_call(
+    dk_full, dv_full = named_pallas_call(
+        "flash_bwd_dkv",
         functools.partial(
             _bwd_dkv_kernel, sm_scale=sm_scale, **g.kernel_args()),
         grid_spec=pltpu.PrefetchScalarGridSpec(

@@ -39,6 +39,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from dlrover_tpu.common.backend import use_interpret
+from dlrover_tpu.ops.named import named_pallas_call
 from dlrover_tpu.ops.quantization import (
     BLOCK,
     LOG_FLOOR,
@@ -334,7 +335,8 @@ def fused_adamw(
         )
         sbuf = functools.partial(jax.ShapeDtypeStruct, (r, 1))
         if bits == 32:
-            upd, mu, nu = pl.pallas_call(
+            upd, mu, nu = named_pallas_call(
+                "fused_adamw",
                 functools.partial(
                     _fused_adam_kernel, b1=b1, b2=b2, eps=eps,
                     wd=weight_decay, clip_norm=clip_norm,
@@ -357,7 +359,8 @@ def fused_adamw(
                 jax.random.fold_in(jax.random.key(0), count_inc),
                 (r, BLOCK), jnp.float32,
             )
-            upd, mu_q, mu_s, nu_q, nu_s = pl.pallas_call(
+            upd, mu_q, mu_s, nu_q, nu_s = named_pallas_call(
+                "fused_adamw_8bit",
                 functools.partial(
                     _fused_adam8bit_kernel, b1=b1, b2=b2, eps=eps,
                     wd=weight_decay, clip_norm=clip_norm,

@@ -20,6 +20,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from dlrover_tpu.common.backend import use_interpret
+from dlrover_tpu.ops.named import named_pallas_call
 
 BLOCK = 256  # quantization group size (elements)
 # rows per grid step: 512 x 256 x 4B = 512 KB per f32 operand. One
@@ -90,7 +91,8 @@ def quantize_int8(x, seed: int = 0, stochastic: bool = True,
     # rows are independent (blockwise absmax), so a ragged last tile
     # only computes garbage rows whose writes are dropped
     tile = min(TILE_ROWS, rows)
-    q, scales = pl.pallas_call(
+    q, scales = named_pallas_call(
+        "quantize_int8",
         functools.partial(_quant_kernel, stochastic=stochastic),
         grid=(pl.cdiv(rows, tile),),
         in_specs=[row_spec(tile), row_spec(tile)],
@@ -160,7 +162,8 @@ def dequantize_int8(q, scales, orig_shape, dtype=jnp.float32,
     if interpret is None:
         interpret = use_interpret()
     tile = min(TILE_ROWS, q.shape[0])
-    out = pl.pallas_call(
+    out = named_pallas_call(
+        "dequantize_int8",
         _dequant_kernel,
         grid=(pl.cdiv(q.shape[0], tile),),
         in_specs=[row_spec(tile), scale_spec(tile)],

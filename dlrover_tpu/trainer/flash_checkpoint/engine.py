@@ -57,6 +57,10 @@ from dlrover_tpu.common.log import get_logger
 
 logger = get_logger(__name__)
 
+# a shard this large waits for the link's bandwidth, not its latency
+# (``last_save_stats["large_leaf_gbps"]``)
+LARGE_LEAF_BYTES = 64 << 20
+
 
 def _path_entry_str(entry) -> str:
     # dotted names ("params.w" not "['params']['w']"): stable across
@@ -380,9 +384,12 @@ class CheckpointEngine:
 
         names, leaves, _treedef = _tree_flatten_with_names(state_dict)
         # Launch every D2H transfer before touching any bytes.
-        for leaf in leaves:
-            if isinstance(leaf, jax.Array):
-                leaf.copy_to_host_async()
+        t0 = time.perf_counter()
+        with tracing.span("ckpt.save.launch", step=step):
+            for leaf in leaves:
+                if isinstance(leaf, jax.Array):
+                    leaf.copy_to_host_async()
+        launch_s = time.perf_counter() - t0
         metas: list[LeafMeta] = []
         offset = 0
         shard_refs: list = []  # device shards or host arrays, unmaterialised
@@ -420,9 +427,12 @@ class CheckpointEngine:
         # two-phase: the meta stays unpublished (readers see "empty")
         # until every byte is drained — a preemption mid-drain must not
         # leave a valid meta over partial tensors
-        buf = self._shm_handler.write_meta_and_reserve(
-            ckpt_meta, publish=False
-        )
+        t0 = time.perf_counter()
+        with tracing.span("ckpt.save.reserve", step=step, bytes=offset):
+            buf = self._shm_handler.write_meta_and_reserve(
+                ckpt_meta, publish=False
+            )
+        reserve_s = time.perf_counter() - t0
         # Hot path: native multi-threaded scatter copy (libdlrtpu) runs at
         # host memory bandwidth with the GIL released; falls back to the
         # per-shard numpy copy when the native lib is unavailable.
@@ -443,39 +453,71 @@ class CheckpointEngine:
         # fill".
         materialize_s = 0.0
         fill_s = 0.0
+        # what the drain waits for, shard by shard: the first wait is
+        # the latency before any byte arrives, the shards of
+        # LARGE_LEAF_BYTES and more give the link's bandwidth with the
+        # per-shard latency taken out. One dict a save; a shard or a
+        # flush leaves a trace only inside a profiler session.
+        first_leaf_s = slowest_leaf_s = 0.0
+        slowest_leaf_bytes = 0
+        large_bytes, large_s = 0, 0.0
 
         def _flush():
             nonlocal pending, pending_bytes, fill_s
             if not pending:
                 return
             t0 = time.perf_counter()
-            if not dlrtpu_native.scatter_copy(buf, pending):
-                for off, host_arr in pending:
-                    dst = np.frombuffer(
-                        buf, dtype=np.uint8, count=host_arr.nbytes,
-                        offset=off,
-                    )
-                    np.copyto(dst, host_arr.reshape(-1).view(np.uint8))
+            with tracing.annotation("ckpt.save.fill", bytes=pending_bytes):
+                if not dlrtpu_native.scatter_copy(buf, pending):
+                    for off, host_arr in pending:
+                        dst = np.frombuffer(
+                            buf, dtype=np.uint8, count=host_arr.nbytes,
+                            offset=off,
+                        )
+                        np.copyto(dst, host_arr.reshape(-1).view(np.uint8))
             fill_s += time.perf_counter() - t0
             pending = []
             pending_bytes = 0
 
-        for i, meta in enumerate(metas):
-            t0 = time.perf_counter()
-            host_arr = np.ascontiguousarray(np.asarray(shard_refs[i]))
-            materialize_s += time.perf_counter() - t0
-            shard_refs[i] = None  # bound host footprint to ~one batch
-            pending.append((meta.offset, host_arr))
-            pending_bytes += host_arr.nbytes
-            if pending_bytes >= flush_bytes:
-                _flush()
-        _flush()
+        with tracing.span("ckpt.save.drain", step=step, leaves=len(metas)):
+            for i, meta in enumerate(metas):
+                t0 = time.perf_counter()
+                with tracing.annotation("ckpt.save.leaf", bytes=meta.nbytes):
+                    host_arr = np.ascontiguousarray(
+                        np.asarray(shard_refs[i])
+                    )
+                leaf_s = time.perf_counter() - t0
+                materialize_s += leaf_s
+                if i == 0:
+                    first_leaf_s = leaf_s
+                if leaf_s > slowest_leaf_s:
+                    slowest_leaf_s = leaf_s
+                    slowest_leaf_bytes = meta.nbytes
+                if meta.nbytes >= LARGE_LEAF_BYTES:
+                    large_bytes += meta.nbytes
+                    large_s += leaf_s
+                shard_refs[i] = None  # bound host footprint to ~one batch
+                pending.append((meta.offset, host_arr))
+                pending_bytes += host_arr.nbytes
+                if pending_bytes >= flush_bytes:
+                    _flush()
+            _flush()
         self._shm_handler.publish_meta()
         self._latest_step = step
         self.last_save_stats = {
             "bytes": offset,
             "materialize_s": materialize_s,
             "fill_s": fill_s,
+            "launch_s": launch_s,
+            "reserve_s": reserve_s,
+            "leaves": len(metas),
+            "first_leaf_s": first_leaf_s,
+            "slowest_leaf_s": slowest_leaf_s,
+            "slowest_leaf_bytes": slowest_leaf_bytes,
+            # 1e9 bytes a second; None where no shard is that large
+            "large_leaf_gbps": (
+                large_bytes / large_s / 1e9 if large_s > 0 else None
+            ),
         }
         if fill_s > 0:
             telemetry.gauge_set(

@@ -13,6 +13,7 @@ the agent/master via write_runtime_metrics + the shm timing ring.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import os
 import time
@@ -25,6 +26,13 @@ from dlrover_tpu.parallel.accelerate import auto_accelerate
 from dlrover_tpu.parallel.strategy import Strategy
 
 logger = get_logger(__name__)
+
+# The step clock: after dispatching step k the loop waits for the loss
+# of step k - STEP_LAG, so the device always has the next step queued
+# while the host sees every completion as it happens. 1 is enough while
+# the host needs less than a step's time for its own work between two
+# dispatches (PERF.md section 6, PR 26, has the chip readings).
+STEP_LAG = 1
 
 
 @dataclasses.dataclass
@@ -164,7 +172,17 @@ class Trainer:
         self._flops_per_token = 0.0
         self._peak_flops: float | None = None
         self._device_mem_ok: bool | None = None
+        # tokens of one step's batch, counted on the first batch after
+        # a (re)shape (None = not counted yet)
+        self._tokens_per_step: int | None = None
         self._refresh_flops()
+        # the step clock: steps dispatched and not yet seen to complete,
+        # oldest first, as (number, its loss on the device, the wall ns
+        # its dispatch began at, steady); the wall ns of the last
+        # completion seen; the number of the last completed step
+        self._pending: collections.deque = collections.deque()
+        self._last_done_ns = 0
+        self._completed_step = 0
         # step the on-disk pending/latest prestep sidecar was last
         # serialized at (skip-rewrite cache; None = dirty)
         self._prestep_sidecar_step = None
@@ -437,18 +455,22 @@ class Trainer:
                     # blame): time the iterator pull into the shm ring
                     t_wait = time.time_ns()
                     try:
-                        batch = next(data_iter)
+                        with tracing.annotation("train.data_wait"):
+                            batch = next(data_iter)
                     except StopIteration:
                         break
                     wait_ns = time.time_ns() - t_wait
-                    if self._timer is not None:
-                        self._timer.record(Tag.DATA_WAIT, t_wait, wait_ns)
-                    if self._profiler is not None:
-                        self._profiler.maybe_start(self.global_step)
-                    self._prof.on_step_start(self.global_step)
-                    t0 = time.time_ns()
+                    with tracing.annotation("train.publish"):
+                        if self._timer is not None:
+                            self._timer.record(
+                                Tag.DATA_WAIT, t_wait, wait_ns
+                            )
+                        if self._profiler is not None:
+                            self._profiler.maybe_start(self.global_step)
+                        self._prof.on_step_start(self.global_step)
+                    t_enter = time.time_ns()
                     with tracing.span(
-                        "train.step", step=self.global_step + 1
+                        "train.dispatch", step=self.global_step + 1
                     ):
                         rng = jax.random.fold_in(
                             jax.random.key(args.seed), self.global_step
@@ -465,68 +487,35 @@ class Trainer:
                             self._profiler.maybe_stop(
                                 self.global_step - 1, block_on=metrics
                             )
-                    dur_ns = time.time_ns() - t0
-                    if self._timer is not None:
-                        self._timer.record(Tag.STEP, t0, dur_ns)
-                    dur_s = dur_ns / 1e9
-                    # the step number the window opened at (pre-
-                    # increment); a finished window parses off-thread
-                    self._prof.on_step_end(
-                        self.global_step - 1, dur_s, block_on=metrics
-                    )
-                    steady = self._compiled_once
-                    if steady:
-                        telemetry.event(
-                            "step.end", step=self.global_step, dur=dur_s
-                        )
-                    else:
-                        telemetry.event(
-                            "compile", step=self.global_step, dur=dur_s
-                        )
-                        self._compiled_once = True
-                    telemetry.observe("train.step.seconds", dur_s)
-                    if dur_s > 0:
-                        telemetry.gauge_set(
-                            "train.steps_per_s", 1.0 / dur_s
-                        )
-                        tokens = self._batch_tokens(batch)
-                        if tokens:
-                            telemetry.gauge_set(
-                                "train.tokens_per_s", tokens / dur_s
+                    if self._tokens_per_step is None:
+                        self._tokens_per_step = self._batch_tokens(batch)
+                    self._pending.append((
+                        self.global_step, metrics.get("loss", metrics),
+                        t_enter, self._compiled_once,
+                    ))
+                    self._compiled_once = True
+                    # a read-back waits for every step in flight, any
+                    # other step for all but the newest STEP_LAG
+                    readback = bool(args.log_steps) and \
+                        self.global_step % args.log_steps == 0
+                    self._close_steps(0 if readback else STEP_LAG)
+                    with tracing.annotation("train.publish"):
+                        self._emit_device_gauges()
+                        write_runtime_metrics(self._completed_step)
+                    if readback:
+                        # two spans of one name: a profiler session that
+                        # starts or stops at the log record (a benchmark's
+                        # does) still holds the one it does not cut
+                        with tracing.annotation("train.readback"):
+                            loss = float(metrics.get("loss", float("nan")))
+                        with tracing.annotation("train.readback"):
+                            logger.info(
+                                "step %d epoch %d loss %.5f",
+                                self.global_step, epoch, loss,
                             )
-                        # steady-state only: the compile step's wall
-                        # time is not a step-time/MFU sample, and one
-                        # giant first point would poison the SLO
-                        # watchdog's rolling baselines
-                        if steady:
-                            telemetry.gauge_set(
-                                "train.step.last_s", dur_s
-                            )
-                            if (
-                                tokens and self._peak_flops
-                                and self._flops_per_token > 0
-                            ):
-                                from dlrover_tpu.common import mfu
-
-                                telemetry.gauge_set(
-                                    "train.mfu",
-                                    mfu.mfu(
-                                        self._flops_per_token * tokens,
-                                        dur_s, self._peak_flops,
-                                    ),
-                                )
-                    self._emit_device_gauges()
-                    if args.log_steps and \
-                            self.global_step % args.log_steps == 0:
-                        loss = float(metrics.get("loss", float("nan")))
-                        logger.info(
-                            "step %d epoch %d loss %.5f",
-                            self.global_step, epoch, loss,
-                        )
-                        telemetry.flush()
-                        self._maybe_adopt_cadence()
-                        self._maybe_reprobe()
-                    write_runtime_metrics(self.global_step)
+                            telemetry.flush()
+                            self._maybe_adopt_cadence()
+                            self._maybe_reprobe()
                     if (
                         self._engine is not None
                         and args.save_steps
@@ -545,6 +534,8 @@ class Trainer:
                             self.global_step >= args.max_steps:
                         stop = True
                         break
+        self._close_steps()
+        write_runtime_metrics(self._completed_step)
         if self._engine is not None:
             # The final checkpoint must not be lost to a cadence save's
             # persist still holding the shm lock: a silently skipped
@@ -582,6 +573,73 @@ class Trainer:
                 )
         telemetry.flush()
         return self.state, metrics
+
+    # ------------------------------------------------------ the step clock
+
+    def _close_steps(self, keep: int = 0):
+        """Wait, oldest first, for the steps in flight down to the
+        newest ``keep`` and record each at its completion, one
+        ``step.end`` a step. A step lasts from the later of the
+        completion before it and the start of its own dispatch to its
+        own completion, so durations tile the wall clock and a save or
+        a read-back before a step is not booked to it. A completion is
+        seen when the host looks: in a loop the host bounds (a slow
+        input pipeline) a step reads the host's iteration, and
+        ``Tag.DATA_WAIT`` says why. Every place the loop syncs with the
+        device calls this first, with ``keep=0``."""
+        import jax
+
+        while len(self._pending) > keep:
+            number, ready, t_enter, steady = self._pending.popleft()
+            with tracing.annotation("train.step_wait"):
+                jax.block_until_ready(ready)
+            now = time.time_ns()
+            start = max(self._last_done_ns, t_enter)
+            self._last_done_ns = now
+            with tracing.annotation("train.publish"):
+                self._record_step(number, start, now - start, steady)
+
+    def _record_step(self, number, start_ns, dur_ns, steady):
+        """The one measurement of a step, handed to all its readers:
+        the shm timer ring (agent exporter, straggler detector), the
+        sampler's governor, the ``step.end`` event (goodput ledger,
+        the master's median step and hang detector) and the gauges
+        ``train.step.last_s`` / ``train.mfu``."""
+        from dlrover_tpu.trainer.timer import Tag
+
+        dur_s = dur_ns / 1e9
+        self._completed_step = number
+        if self._timer is not None:
+            self._timer.record(
+                Tag.STEP if steady else Tag.COMPILE, start_ns, dur_ns
+            )
+        # the sampler numbers a step as the loop does before it
+        # dispatches it; a finished window parses off-thread. A
+        # compiling step is no sample of its governor's step time.
+        self._prof.on_step_end(number - 1, dur_s if steady else 0.0)
+        if not steady:
+            # the first step of an incarnation traces and compiles: its
+            # wall time is no step-time/MFU sample, and one giant first
+            # point would poison the SLO watchdog's rolling baselines
+            telemetry.event("compile", step=number, dur=dur_s)
+            return
+        telemetry.event("step.end", step=number, dur=dur_s)
+        if dur_s <= 0:
+            return
+        telemetry.gauge_set("train.step.last_s", dur_s)
+        if (
+            self._tokens_per_step and self._peak_flops
+            and self._flops_per_token > 0
+        ):
+            from dlrover_tpu.common import mfu
+
+            telemetry.gauge_set(
+                "train.mfu",
+                mfu.mfu(
+                    self._flops_per_token * self._tokens_per_step,
+                    dur_s, self._peak_flops,
+                ),
+            )
 
     # ------------------------------------------- brain cadence adoption
 
@@ -780,9 +838,9 @@ class Trainer:
 
     @staticmethod
     def _batch_tokens(batch) -> int:
-        """Best-effort token count for the throughput gauge: the first
-        2-D integer leaf (token ids) wins; 0 when the batch has none
-        (e.g. dense regression batches)."""
+        """Best-effort token count for the ``train.mfu`` gauge: the
+        first 2-D integer leaf (token ids) wins; 0 when the batch has
+        none (e.g. dense regression batches)."""
         try:
             import jax
             import numpy as np
@@ -797,7 +855,7 @@ class Trainer:
                     and np.issubdtype(np.dtype(dtype), np.integer)
                 ):
                     return int(shape[0]) * int(shape[1])
-        except Exception:  # noqa: BLE001 - throughput gauge is garnish
+        except Exception:  # noqa: BLE001 - the MFU gauge is garnish
             pass
         return 0
 
@@ -827,9 +885,11 @@ class Trainer:
         the classic restart path."""
         if self._reshape_channel is None:
             return False
-        req = self._reshape_channel.poll(self._reshape_round)
+        with tracing.annotation("train.publish"):
+            req = self._reshape_channel.poll(self._reshape_round)
         if req is None:
             return False
+        self._close_steps()
         t0 = time.monotonic()
         ok, stats = False, {}
         # transaction snapshot: _apply_reshape mutates accel/state/
@@ -1088,6 +1148,7 @@ class Trainer:
         )
         self.state = self._accel.state if state is None else state
         self._compiled_once = False
+        self._tokens_per_step = None
         # model FLOPs are a per-(re)shape constant, not a per-step one
         self._refresh_flops()
         # ...and so is the op-cost baseline key (new mesh shape)
@@ -1115,6 +1176,7 @@ class Trainer:
     def save_checkpoint(self, persist: bool = False):
         if self._engine is None:
             return False
+        self._close_steps()
         tree = self._ckpt_tree()
         # PENDING sidecar before the engine commit, promoted to latest
         # only after the save succeeds: a crash on either side of the
@@ -1343,6 +1405,7 @@ class Trainer:
 
         if self.eval_data is None:
             return float("nan")
+        self._close_steps()
         eval_step = getattr(self, "_eval_step", None)
         if eval_step is None:
             def _eval(params, batch):
@@ -1378,6 +1441,7 @@ class Trainer:
         return loss
 
     def close(self):
+        self._pending.clear()
         if self._profiler is not None:
             self._profiler.close()
         self._prof.close()

@@ -123,3 +123,29 @@ def _session_shm_sweep():
             seg.unlink()
         except FileNotFoundError:
             pass
+
+
+@pytest.fixture
+def profiled_spans(tmp_path):
+    """``profiled_spans(body)`` runs ``body`` inside a CPU profiler
+    session (host annotations only, no Python tracer) and returns the
+    program's own spans from its ``.xplane.pb``
+    (``tracing.spans_from_xplane``)."""
+    def run(body):
+        import jax
+
+        from dlrover_tpu.common import trace_summary, tracing
+
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 2
+        trace_dir = str(tmp_path / "profiler_session")
+        jax.profiler.start_trace(trace_dir, profiler_options=options)
+        try:
+            body()
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = trace_summary.xplane_paths(trace_dir)
+        return tracing.spans_from_xplane(path)
+
+    return run

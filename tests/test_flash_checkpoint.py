@@ -613,3 +613,74 @@ def test_two_phase_meta_publish(isolated_ckpt_env):
         np.frombuffer(bytes(got[1][: arr.nbytes]), np.float32), arr
     )
     h.close(unlink=True)
+
+
+# -------------------------------------------------------------------------
+# the save path's own breakdown (last_save_stats + ckpt.save.* spans)
+# -------------------------------------------------------------------------
+
+
+class TestSaveBreakdown:
+    def test_last_save_stats_split_the_save(self, tmp_path, monkeypatch):
+        import time
+
+        from dlrover_tpu.trainer.flash_checkpoint import engine as eng
+
+        # a state with one "large" leaf, by a threshold this size meets
+        monkeypatch.setattr(eng, "LARGE_LEAF_BYTES", 16 * 8 * 4)
+        engine = ReplicatedCheckpointEngine(str(tmp_path / "ckpt"))
+        state = make_state()
+        t0 = time.perf_counter()
+        assert engine.save_to_memory(10, state)
+        wall = time.perf_counter() - t0
+        stats = engine.last_save_stats
+        assert set(stats) == {
+            "bytes", "materialize_s", "fill_s", "launch_s", "reserve_s",
+            "leaves", "first_leaf_s", "slowest_leaf_s",
+            "slowest_leaf_bytes", "large_leaf_gbps",
+        }
+        assert stats["leaves"] == 3
+        assert stats["bytes"] == (16 * 8 + 8) * 4 + 4
+        legs = (stats["launch_s"] + stats["reserve_s"]
+                + stats["materialize_s"] + stats["fill_s"])
+        assert 0 < legs <= wall
+        assert 0 <= stats["first_leaf_s"] <= stats["slowest_leaf_s"] \
+            <= stats["materialize_s"]
+        assert stats["slowest_leaf_bytes"] in (16 * 8 * 4, 8 * 4, 4)
+        assert stats["large_leaf_gbps"] > 0
+        # no leaf that large: no bandwidth to report
+        monkeypatch.setattr(eng, "LARGE_LEAF_BYTES", 1 << 40)
+        assert engine.save_to_memory(11, state)
+        assert engine.last_save_stats["large_leaf_gbps"] is None
+        engine.close()
+
+    def test_save_spans_nest_under_the_shm_save(
+        self, tmp_path, profiled_spans
+    ):
+        """Three ring spans a save, children of ``ckpt.save.shm``; a
+        shard or a flush leaves a trace only inside a profiler session."""
+        from dlrover_tpu.common import telemetry
+
+        prev = telemetry.active_registry()
+        telemetry.enable(source="test-0-1")
+        engine = ReplicatedCheckpointEngine(str(tmp_path / "ckpt"))
+        try:
+            traced = [s["name"] for s in profiled_spans(
+                lambda: engine.save_to_memory(10, make_state())
+            )]
+            events = telemetry.snapshot()["events"]
+        finally:
+            telemetry._REGISTRY = prev
+            engine.close()
+        ring = {e["name"]: e for e in events if e["kind"] == "span"}
+        assert set(ring) == {
+            "ckpt.save.shm", "ckpt.save.launch", "ckpt.save.reserve",
+            "ckpt.save.drain",
+        }
+        for child in ("launch", "reserve", "drain"):
+            assert ring["ckpt.save." + child]["parent"] == \
+                ring["ckpt.save.shm"]["span"]
+        assert ring["ckpt.save.drain"]["leaves"] == 3
+        assert traced.count("ckpt.save.leaf") == 3
+        assert traced.count("ckpt.save.fill") == 1
+        assert set(ring) <= set(traced)

@@ -669,7 +669,7 @@ class TestTrainerMfu:
         # steady-state only: the compile step contributes no sample
         events = [e for e in snap["events"] if e["kind"] == "compile"]
         assert len(events) == 1
-        assert len(series["train.steps_per_s"]) == 8
+        assert len(dur_pts) == 7
         # the host-arena gauge emits EVERY step, independent of the
         # backend's device memory_stats support
         assert len(series["ckpt.arena.pooled_bytes"]) == 8
@@ -824,8 +824,10 @@ class TestLiveMetricsPlaneEndToEnd:
                 for s in svc.telemetry.snapshots()
             )
 
-            # --- phase 2: inject a 6x step-time regression
-            delay["s"] = 0.03
+            # --- phase 2: inject a step-time regression, several times
+            # the toy's true step (its compute included, since a step is
+            # timed at its completion) even on a loaded test host
+            delay["s"] = 0.2
             args.max_steps = 40
             trainer.train()
             reporter.report_once()

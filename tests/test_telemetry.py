@@ -393,9 +393,10 @@ def test_trainer_emits_compile_then_step_events(
     assert kinds.count("compile") == 1
     assert kinds.count("step.end") == 4
     assert kinds.index("compile") < kinds.index("step.end")
-    hists = {h["name"] for h in snap["histograms"]}
-    assert "train.step.seconds" in hists
-    assert {g["name"] for g in snap["gauges"]} >= {"train.steps_per_s"}
+    # one clock: the steady steps' gauge, no second copy of it
+    assert {g["name"] for g in snap["gauges"]} >= {"train.step.last_s"}
+    steps = [e["step"] for e in snap["events"] if e["kind"] == "step.end"]
+    assert steps == [2, 3, 4, 5]
 
 
 # -------------------------------------------------------------------------

@@ -1028,3 +1028,60 @@ class TestReportSurfaces:
         assert out["master_rpc_p99_ms"] > 0
         assert out["joins_per_sec"] > 0
         assert out["master_rpc_calls"] > 0
+
+
+# -------------------------------------------------------------------------
+# spans on the profiler's clock
+# -------------------------------------------------------------------------
+
+
+class TestProfilerAnnotations:
+    def test_span_lands_in_a_profiler_session(
+        self, profiled_spans, fresh_telemetry
+    ):
+        def body():
+            with tracing.span("unit.outer", step=3, why="text"):
+                with tracing.annotation("unit.inner", bytes=7):
+                    time.sleep(0.002)
+
+        before = len(telemetry.snapshot()["events"])
+        spans = {s["name"]: s for s in profiled_spans(body)}
+        outer, inner = spans["unit.outer"], spans["unit.inner"]
+        # on one thread and one clock, the inner inside the outer
+        assert outer["thread"] == inner["thread"]
+        assert outer["start_ns"] <= inner["start_ns"]
+        assert inner["start_ns"] + inner["dur_ns"] <= \
+            outer["start_ns"] + outer["dur_ns"]
+        assert inner["dur_ns"] >= 2e6
+        # numbers travel as the event's stats, text does not
+        assert outer["stats"] == {"step": 3}
+        assert inner["stats"] == {"bytes": 7}
+        # the ring got the span's one event and nothing for the
+        # trace-only annotation
+        new = telemetry.snapshot()["events"][before:]
+        assert [(e["kind"], e["name"]) for e in new] == \
+            [("span", "unit.outer")]
+        assert new[0]["why"] == "text"
+
+    def test_no_session_no_trace_and_the_ring_is_unchanged(
+        self, fresh_telemetry
+    ):
+        with tracing.annotation("unit.quiet", n=1):
+            pass
+        assert telemetry.snapshot()["events"] == []
+
+    def test_a_process_without_jax_stays_without_it(self):
+        """The agent and the master open spans and never import JAX."""
+        script = (
+            "import sys\n"
+            "from dlrover_tpu.common import tracing\n"
+            "with tracing.span('rdzv.round', round=1):\n"
+            "    with tracing.annotation('rdzv.inner'):\n"
+            "        pass\n"
+            "assert 'jax' not in sys.modules, 'jax imported'\n"
+        )
+        proc = subprocess.run(
+            [sys.executable, "-c", script], timeout=60,
+            capture_output=True,
+        )
+        assert proc.returncode == 0, proc.stderr.decode()
