@@ -509,36 +509,18 @@ def _layer(config: LlamaConfig, x, layer_params, rope_cos, rope_sin):
     return shard_logical(x, ("batch", "seq", "embed")), aux
 
 
-def _offload_dots_save_attn_policy():
-    """dots -> pinned-host offload, "attn_out" names -> saved in HBM,
-    everything else -> recompute. Composed with policy_or_names because
-    save_from_both_policies only merges boolean policies and the
-    offload variants return Offloadable markers / a truthy Recompute
-    sentinel."""
-    from dlrover_tpu.parallel.pipeline import policy_or_names
-
-    return policy_or_names(
-        jax.checkpoint_policies.offload_dot_with_no_batch_dims(
-            "device", "pinned_host"
-        ),
-        jax.checkpoint_policies.save_only_these_names("attn_out"),
-    )
-
-
 def _stage_fn(config: LlamaConfig):
     """Per-stage layer-scan closure shared by the pipeline schedules."""
-    from dlrover_tpu.parallel.pipeline import stage_layer_scan
+    from dlrover_tpu.parallel.pipeline import (
+        minimal_save_policy,
+        stage_layer_scan,
+    )
 
     policy = {
-        "dots_attn": jax.checkpoint_policies.save_from_both_policies(
-            jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-            jax.checkpoint_policies.save_only_these_names("attn_out"),
-        ),
+        "dots_attn": minimal_save_policy(),
         # selective offload: the dot saves go to pinned host memory,
-        # attn_out (the costliest recompute) stays in HBM.
-        # save_from_both_policies cannot combine offload policies (they
-        # return Offloadable markers, not booleans) — compose by hand.
-        "dots_attn_offload": _offload_dots_save_attn_policy(),
+        # attn_out (the costliest recompute) stays in HBM
+        "dots_attn_offload": minimal_save_policy(offload=True),
         "dots_no_batch":
             jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
         "dots": jax.checkpoint_policies.dots_saveable,

@@ -1662,10 +1662,10 @@ def ring_dkv_block(q, k, v, do, lse, delta, q_start, k_start, sm_scale,
 # The VJP is attached to an *identity* function whose inputs include the
 # kernel outputs (o, lse). The pallas forward call then lives in the
 # primal graph where ``checkpoint_name`` can tag it: under jax.checkpoint
-# with a policy saving "attn_out", the backward pass reuses the saved
-# (o, lse) instead of re-running the forward kernel — a custom_vjp's own
-# fwd residuals are invisible to checkpoint policies, so tagging must
-# happen at the primal level.
+# with a policy saving "attn_out" (pipeline.minimal_save_policy), the
+# backward pass reuses the saved (o, lse) instead of re-running the
+# forward kernel — a custom_vjp's own fwd residuals are invisible to
+# checkpoint policies, so tagging must happen at the primal level.
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(7, 19)))
@@ -1685,12 +1685,17 @@ def _anchor_bwd(layout, heads, kv_heads, sm_scale, causal, block_q, block_k,
                 bwd_block_q, bwd_block_k, interpret, window, prefix, res,
                 do):
     q, k, v, o, lse, rope_cos, rope_sin = res
+    # where _flash kept one column, rebuild the STATS_W-lane operand the
+    # kernels read (they take column 0, so this is bit for bit the
+    # forward kernel's own output)
+    lse_wide = lse if lse.ndim == 4 else jnp.broadcast_to(
+        lse[..., None], (*lse.shape, STATS_W))
     # the backward is traced outside the public entry's dynamic extent —
     # re-establish the mask extras around the kernel construction
     with _mask_extras(window, prefix):
         dq, dk, dv = _bwd(
             layout, heads, kv_heads, sm_scale, causal, bwd_block_q,
-            bwd_block_k, interpret, (q, k, v, o, lse), do,
+            bwd_block_k, interpret, (q, k, v, o, lse_wide), do,
             rope_cos=rope_cos, rope_sin=rope_sin,
         )
     zc = None if rope_cos is None else jnp.zeros_like(rope_cos)
@@ -1706,6 +1711,8 @@ def _flash(q, k, v, layout, heads, kv_heads, sm_scale, causal, block_q,
            rope_cos=None, rope_sin=None, window=None, prefix=None):
     from jax.ad_checkpoint import checkpoint_name
 
+    from dlrover_tpu.ops.fp8 import remat_disabled
+
     # stop_gradient on the *inputs* keeps AD tracing out of the pallas
     # call entirely (it has no JVP rule); gradients flow only through
     # the anchor's q/k/v arguments.
@@ -1719,8 +1726,17 @@ def _flash(q, k, v, layout, heads, kv_heads, sm_scale, causal, block_q,
             causal, block_q, block_k, interpret,
             rope_cos=rope_cos, rope_sin=rope_sin,
         )
-    o = checkpoint_name(o, "attn_out")
-    lse = checkpoint_name(lse, "attn_out")
+    if not remat_disabled():
+        o = checkpoint_name(o, "attn_out")
+        # the kernel writes the row statistic STATS_W identical lanes
+        # wide; what a checkpoint policy saving "attn_out" holds from
+        # forward to backward, stacked per layer, is one column of it,
+        # 1/STATS_W of the bytes: at gpt2-xl's shapes 0.4 MB a layer in
+        # place of 52 MB, twice the (lane-padded) output itself. A step
+        # traced for Strategy.remat="none" has no checkpoint to save
+        # anything: there the kernel's own output goes to the backward
+        # kernels as it is, with no narrowing and no rebuilding.
+        lse = checkpoint_name(lse[..., 0], "attn_out")
     return _anchor(q, k, v, rope_cos, rope_sin, o, lse, layout, heads,
                    kv_heads, sm_scale, causal, block_q, block_k,
                    bwd_block_q, bwd_block_k, interpret, window, prefix)

@@ -93,27 +93,29 @@ def _compute_cast(params, dtype):
 def _remat_wrap(loss_fn, policy_name: str):
     import jax
 
+    from dlrover_tpu.parallel.pipeline import (
+        minimal_save_policy,
+        quant_aware_policy,
+    )
+
     if policy_name == "none":
         return loss_fn
-    if policy_name == "minimal":
-        policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-    elif policy_name == "offload":
-        # selective activation offloading (reference
-        # selective_offloading_checkpoint.py:1): the tensors "minimal"
+    if policy_name in ("minimal", "offload"):
+        # "offload": selective activation offloading (reference
+        # selective_offloading_checkpoint.py:1): the dots "minimal"
         # would keep in HBM round-trip to pinned host memory instead —
         # HBM high-water drops toward the "full" level while the
-        # backward re-reads saves over PCIe/DMA instead of recomputing
-        policy = jax.checkpoint_policies.offload_dot_with_no_batch_dims(
-            "device", "pinned_host"
-        )
+        # backward re-reads saves over PCIe/DMA instead of recomputing.
+        # Both keep the attention kernel's outputs: this checkpoint's
+        # policy also rules the model's per-layer checkpoints on the
+        # first forward pass, and what it drops there they recompute.
+        policy = minimal_save_policy(offload=policy_name == "offload")
     else:  # "full"
         policy = jax.checkpoint_policies.nothing_saveable
     # same int8 adaptation the per-layer scan applies: without it, a
     # model with config.remat=False under strategy remat would save the
     # stacked int32 qa@qb accumulators (HBM OOM) and recompute every
     # quantization chain in the backward. No-op for unquantized models.
-    from dlrover_tpu.parallel.pipeline import quant_aware_policy
-
     return jax.checkpoint(loss_fn, policy=quant_aware_policy(policy))
 
 

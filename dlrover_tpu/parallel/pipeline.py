@@ -1129,6 +1129,31 @@ def policy_or_names(policy, names):
     return p
 
 
+def minimal_save_policy(offload: bool = False):
+    """What remat level "minimal" keeps from forward to backward: the
+    dots without batch dimensions (the weight matmuls) and the
+    attention kernel's output and row statistics (``o`` and ``lse``,
+    tagged "attn_out" in ops/attention.py), the costliest thing a layer
+    could recompute. ``offload`` sends the dots to pinned host memory
+    and keeps "attn_out" in HBM.
+
+    The single home of that set: every checkpoint level of the step
+    program reads it (accelerate._remat_wrap round the whole loss,
+    stage_layer_scan's default round each layer, llama's "dots_attn" /
+    "dots_attn_offload"). A level that leaves the names out drops
+    ``o``/``lse`` in the forward pass, and the level inside it then
+    runs the forward kernel a second time to have them."""
+    dots = (
+        jax.checkpoint_policies.offload_dot_with_no_batch_dims(
+            "device", "pinned_host")
+        if offload
+        else jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+    )
+    return policy_or_names(
+        dots, jax.checkpoint_policies.save_only_these_names("attn_out")
+    )
+
+
 def quant_aware_policy(policy):
     """Adapt a remat save policy to the int8 quantized-matmul path.
 
@@ -1175,8 +1200,9 @@ def stage_layer_scan(
     ``lax.scan``), accumulating per-layer aux losses.
 
     ``layer_fn(h, one_layer_params, *extras) -> (h, aux)``. Whatever
-    save policy applies (passed or default) is adapted to the int8
-    quantized path via :func:`quant_aware_policy`.
+    save policy applies (passed, or :func:`minimal_save_policy` by
+    default) is adapted to the int8 quantized path via
+    :func:`quant_aware_policy`.
 
     ``layer_axes`` (a pytree matching ONE layer's params whose leaves
     are logical-axis tuples) opts the scan into collective–compute
@@ -1197,8 +1223,7 @@ def stage_layer_scan(
         from dlrover_tpu.parallel.overlap import layer_gather_fn
 
         chosen_policy = quant_aware_policy(
-            policy
-            or jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+            policy or minimal_save_policy()
         )
         # the strategy's remat="none" wins over the model config: a
         # no-remat trace must emit no checkpoint at any layer

@@ -31,9 +31,14 @@ class Strategy:
     rules: LogicalRules = DEFAULT_RULES
     # compute precision for matmuls/activations; params stay fp32 master.
     compute_dtype: str = "bfloat16"
-    # remat policy name: none | minimal | offload | full
-    # (jax.checkpoint policies; "offload" round-trips the minimal-level
-    # saves through pinned host memory — HBM relief without recompute).
+    # remat policy name: none | minimal | offload | full — what the
+    # step program's checkpoints keep from forward to backward.
+    # "minimal" keeps the dots without batch dimensions (the weight
+    # matmuls) and the attention kernel's output and row statistic
+    # (pipeline.minimal_save_policy); "offload" keeps the same set but
+    # round-trips the dots through pinned host memory — HBM relief
+    # without recompute; "full" keeps nothing and recomputes the whole
+    # forward pass, attention too; "none" sets no checkpoint at all.
     # Under int8/fp8 compute every level is quant-adapted
     # (pipeline.quant_aware_policy): even "full" still saves the
     # quantized-matmul outputs, because recomputing a quantization

@@ -568,9 +568,9 @@ class TestOffloadRemat:
 
         from jax.ad_checkpoint import checkpoint_name
 
-        from dlrover_tpu.models.llama import _offload_dots_save_attn_policy
+        from dlrover_tpu.parallel.pipeline import minimal_save_policy
 
-        pol = _offload_dots_save_attn_policy()
+        pol = minimal_save_policy(offload=True)
 
         def f(w, x):
             h = x @ w

@@ -137,6 +137,49 @@ def test_flash_attention_bshd_grads_match_reference(q_len, kv_len, fused):
         assert float(jnp.max(jnp.abs(a - b))) / scale < 5e-2
 
 
+# 96 rows in blocks of 64: the split kernels (delta, dq, dkv) and not
+# the one-pass backward, which needs whole blocks
+@pytest.mark.parametrize("layout,seq", [
+    ("bhsd", 128), ("bhsd", 96), ("bshd", 128), ("bshdf", 128),
+])
+def test_backward_from_one_column_of_lse_is_bit_identical(layout, seq):
+    """What is held from forward to backward is one column of the
+    forward kernel's 128-lane row statistic (``_flash``). The backward
+    kernels fed the operand rebuilt from that column give the same
+    dq, dk, dv bit for bit as fed the kernel's own output: the lanes
+    are copies, and every kernel reads column 0."""
+    from dlrover_tpu.ops import attention as A
+
+    H, KVH, D = 4, 2, 64
+    q, k, v = _qkv(batch=2, heads=H, kv_heads=KVH, seq=seq, dim=D, seed=5)
+    do = jnp.asarray(
+        np.random.RandomState(6).randn(*q.shape), jnp.float32)
+    if layout != "bhsd":
+        q, k, v, do = (
+            x.transpose(0, 2, 1, 3).reshape(2, seq, -1)
+            for x in (q, k, v, do)
+        )
+    static = (layout, H, KVH, D ** -0.5, True, 64, 64, True)
+    o, lse = A._fwd(q, k, v, *static)
+    assert lse.shape[-1] == A.STATS_W
+    rebuilt = jnp.broadcast_to(lse[..., :1], lse.shape)
+    np.testing.assert_array_equal(np.asarray(lse), np.asarray(rebuilt))
+    own = A._bwd(*static, (q, k, v, o, lse), do)
+    again = A._bwd(*static, (q, k, v, o, rebuilt), do)
+    for a, b in zip(own, again):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    if layout == "bhsd":
+        # and through the public entry, whose residual is that column
+        _, vjp = jax.vjp(
+            lambda q, k, v: A.flash_attention(
+                q, k, v, block_q=64, block_k=64),
+            q, k, v,
+        )
+        for a, b in zip(own, vjp(do)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_softmax_cross_entropy_matches_optax():
     rng = np.random.RandomState(0)
     logits = jnp.asarray(rng.randn(4, 16, 64), jnp.float32)

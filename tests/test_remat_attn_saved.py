@@ -1,0 +1,188 @@
+"""The attention forward kernel runs once a layer a step.
+
+The step program nests two checkpoint levels: ``Strategy.remat`` wraps
+the whole loss (accelerate._remat_wrap) and the model's layer scan
+wraps each layer (pipeline.stage_layer_scan). The outer level's policy
+rules the first forward pass right through the inner one, so a level
+that does not keep the kernel's tagged outputs ("attn_out": ``o`` and
+the row statistic) drops them there, and the inner level runs
+``flash_fwd`` a second time to have them: two forward kernels a layer a
+step on the chip, 12 ms of ``gpt2-xl.steady``'s 194 ms step (PERF.md,
+PR 27). Both levels, and llama's named policies, read one set now:
+``pipeline.minimal_save_policy``.
+
+Counted here without running a kernel: the ``pallas_call``s by name in
+the dead-code-eliminated jaxpr of the step ``auto_accelerate`` builds.
+Each sits in a scan body, so a count of 1 is one call a layer.
+"""
+
+import dataclasses
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+from jax._src.interpreters import partial_eval as pe
+
+from dlrover_tpu.models import PRESETS, llama_init, llama_loss_fn
+from dlrover_tpu.models.gpt2 import GPT2Config, gpt2_init, gpt2_loss_fn
+from dlrover_tpu.models.gpt2 import gpt2_logical_axes
+from dlrover_tpu.models.llama import llama_logical_axes
+from dlrover_tpu.parallel import MeshConfig, Strategy
+from dlrover_tpu.parallel.accelerate import _remat_wrap, auto_accelerate
+
+SEQ = 32  # two whole blocks of 16: the one-pass backward kernel
+
+
+def _model(name: str, remat: bool):
+    """(loss_fn, init_fn, logical axes, vocabulary) of a 2-layer model
+    whose attention is the Pallas wrapper (interpret mode here)."""
+    if name == "gpt2":
+        cfg = GPT2Config(
+            vocab_size=64, dim=32, n_layers=2, n_heads=2, mlp_dim=64,
+            max_seq_len=SEQ, dtype="float32", attn_impl="flash",
+            attn_block_q=16, attn_block_k=16, remat=remat,
+        )
+        return (gpt2_loss_fn(cfg), lambda rng: gpt2_init(cfg, rng),
+                gpt2_logical_axes(cfg), cfg.vocab_size)
+    cfg = dataclasses.replace(
+        PRESETS["tiny"], n_layers=2, attn_impl="flash", ce_chunks=1,
+        dtype="float32", attn_block_q=16, attn_block_k=16, remat=remat,
+    )
+    return (llama_loss_fn(cfg), lambda rng: llama_init(cfg, rng),
+            llama_logical_axes(cfg), cfg.vocab_size)
+
+
+def _batch(vocab: int):
+    tokens = np.random.RandomState(0).randint(0, vocab, (2, SEQ + 1))
+    return {"tokens": jnp.asarray(tokens, jnp.int32)}
+
+
+def _subjaxprs(val):
+    if hasattr(val, "jaxpr"):  # ClosedJaxpr
+        yield val.jaxpr
+    elif hasattr(val, "eqns"):  # Jaxpr
+        yield val
+    elif isinstance(val, (tuple, list)):
+        for v in val:
+            yield from _subjaxprs(v)
+
+
+def _count(jaxpr, key, counts=None) -> dict:
+    """Equations at any depth, counted under ``key(eqn)`` (None: not
+    counted)."""
+    counts = {} if counts is None else counts
+    for eqn in jaxpr.eqns:
+        k = key(eqn)
+        if k is not None:
+            counts[k] = counts.get(k, 0) + 1
+        for val in eqn.params.values():
+            for sub in _subjaxprs(val):
+                _count(sub, key, counts)
+    return counts
+
+
+def _kernel_name(eqn):
+    if eqn.primitive.name == "pallas_call":
+        return eqn.params["name"]
+
+
+def _tag(eqn):
+    if eqn.primitive.name == "name":
+        return eqn.params["name"]
+
+
+@functools.lru_cache(maxsize=None)
+def _step_jaxpr(model: str, level: str, remat: bool):
+    """The dead-code-eliminated jaxpr of the step auto_accelerate
+    builds: a replay's call whose outputs nothing reads is in the trace
+    and not in the program, so count what the compiler keeps."""
+    loss_fn, init_fn, axes, vocab = _model(model, remat)
+    accel = auto_accelerate(
+        loss_fn, init_fn, optax.sgd(1e-2), axes,
+        strategy=Strategy(mesh=MeshConfig(data=1, fsdp=1), remat=level),
+        devices=jax.devices()[:1],
+    )
+    closed = jax.make_jaxpr(accel.train_step)(
+        accel.state, _batch(vocab), jax.random.key(0)
+    )
+    live, _ = pe.dce_jaxpr(
+        closed.jaxpr, [True] * len(closed.jaxpr.outvars)
+    )
+    return live
+
+
+# flash_fwd calls a layer a step, whatever the model's own remat.
+# "minimal" and "offload" keep the kernel's outputs at every level.
+# "full" recomputes everything, attention too: its replay is the second
+# call (a third would mean the per-layer level dropped them as well).
+# "none" has no checkpoint at any level.
+FWD_CALLS = {"minimal": 1, "offload": 1, "full": 2, "none": 1}
+
+
+@pytest.mark.parametrize("remat", [True, False], ids=["remat", "plain"])
+@pytest.mark.parametrize("level", list(FWD_CALLS))
+@pytest.mark.parametrize("model", ["gpt2", "llama"])
+def test_attention_forward_kernel_runs_once_a_layer(model, level, remat):
+    calls = _count(_step_jaxpr(model, level, remat), _kernel_name)
+    assert calls == {
+        "flash_fwd": FWD_CALLS[level], "flash_bwd_fused": 1,
+    }, calls
+
+
+@pytest.mark.parametrize("model", ["gpt2", "llama"])
+def test_a_step_without_checkpoints_carries_no_tag(model):
+    """``Strategy.remat="none"`` has no checkpoint to read a tag: the
+    step is traced without them, and the kernel's own statistic goes to
+    the backward kernel with no narrowing and no rebuilding, as it did
+    before there was anything to save."""
+    assert _count(_step_jaxpr(model, "none", True), _tag) == {}
+    tags = _count(_step_jaxpr(model, "minimal", True), _tag)
+    assert tags.get("attn_out", 0) >= 2, tags
+
+
+@pytest.mark.parametrize("model", ["gpt2", "llama"])
+def test_gradients_equal_those_without_any_checkpoint(model):
+    """Saving ``o`` and the statistic changes no number: the gradients
+    under both checkpoint levels equal those of the same model with
+    none at all."""
+    grads = {}
+    for level, remat in (("minimal", True), ("none", False)):
+        loss_fn, init_fn, _axes, vocab = _model(model, remat)
+        wrapped = _remat_wrap(loss_fn, level)
+        grads[level] = jax.jit(jax.grad(
+            lambda p, b: wrapped(p, b, jax.random.key(0))
+        ))(init_fn(jax.random.key(1)), _batch(vocab))
+    flat = jax.tree.leaves(jax.tree.map(
+        lambda a, b: jnp.max(jnp.abs(a - b)) / (jnp.max(jnp.abs(b)) + 1e-30),
+        grads["minimal"], grads["none"],
+    ))
+    assert len(flat) > 4
+    assert max(float(x) for x in flat) <= 1e-6
+
+
+@pytest.mark.parametrize("level,on_device,on_host", [
+    ("minimal", 3, 0), ("offload", 1, 2), ("full", 0, 0),
+])
+def test_strategy_level_keeps_the_tagged_output(level, on_device, on_host):
+    """The whole-loss checkpoint itself, on a function of two dots and
+    one tagged value: "minimal" holds all three on the device from
+    forward to backward, "offload" sends the dots to the host and holds
+    the tagged value on the device, "full" holds nothing."""
+    from jax._src.ad_checkpoint import saved_residuals
+    from jax.ad_checkpoint import checkpoint_name
+
+    def loss(w, x, rng):
+        h = checkpoint_name(jnp.tanh(x @ w), "attn_out")
+        return jnp.sum((h @ w) ** 2)
+
+    kept = [
+        str(aval) for aval, why in saved_residuals(
+            _remat_wrap(loss, level), jnp.ones((8, 8)), jnp.ones((4, 8)),
+            None,
+        ) if "from the argument" not in why
+    ]
+    host = [aval for aval in kept if "<host>" in aval]
+    assert (len(kept) - len(host), len(host)) == (on_device, on_host), kept

@@ -363,7 +363,9 @@ def test_train_step_fits_one_chip(topo, on_tpu):
     # under one the compiler made (closed_call.19, checkpoint.15)
     names = _kernel_names(compiled)
     assert len(names) == _kernels(compiled)
-    assert "flash_fwd" in names
+    # one forward kernel a layer: every checkpoint level keeps its
+    # outputs (tests/test_remat_attn_saved.py counts every level)
+    assert names.count("flash_fwd") == 1, names
     assert all(n.startswith("flash_bwd") for n in set(names) - {"flash_fwd"})
     # state (fp32 params + two AdamW moments) + gradients + activations
     assert _device_bytes(compiled) < 0.9 * V5E_HBM_BYTES
