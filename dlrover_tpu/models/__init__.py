@@ -34,3 +34,11 @@ from dlrover_tpu.models.gpt2 import (  # noqa: F401
     gpt2_apply,
     gpt2_loss_fn,
 )
+
+from dlrover_tpu.models.granite_hybrid import (  # noqa: F401
+    GraniteHybridConfig,
+    granite_hybrid_logical_axes,
+    granite_hybrid_init,
+    granite_hybrid_apply,
+    granite_hybrid_loss_fn,
+)

@@ -295,7 +295,7 @@ def _maybe_full_rope(config, cos, sin):
 
 
 def _sharded_flash(config: LlamaConfig, qt, kt, vt, layout: str = "bhsd",
-                   rope_cos=None, rope_sin=None):
+                   rope_cos=None, rope_sin=None, sm_scale=None):
     """pallas_call does not auto-partition under GSPMD: without an explicit
     shard_map, jit would all-gather q/k/v to run the kernel replicated.
     Map the kernel over the mesh's batch/head axes (seq stays local here —
@@ -316,7 +316,7 @@ def _sharded_flash(config: LlamaConfig, qt, kt, vt, layout: str = "bhsd",
             {"rope_cos": tables[0], "rope_sin": tables[1]} if rope else {}
         )
         return fa(
-            q, k, v, causal=True,
+            q, k, v, causal=True, sm_scale=sm_scale,
             block_q=config.attn_block_q, block_k=config.attn_block_k,
             bwd_block_q=config.attn_bwd_block_q,
             bwd_block_k=config.attn_bwd_block_k,
@@ -374,8 +374,10 @@ def flash_einsum_path(config) -> bool:
     )
 
 
-def bhsd_flash_attention(config, qt, kt, vt, rope_cos=None, rope_sin=None):
+def bhsd_flash_attention(config, qt, kt, vt, rope_cos=None, rope_sin=None,
+                         sm_scale=None):
     """Shard + run the Pallas flash kernel on [B,H,S,Dh] operands.
+    ``sm_scale`` None is the kernels' ``1 / sqrt(head_dim)``.
 
     With ``rope_cos``/``rope_sin`` (full-width [B,S,Dh] tables), rope is
     fused into the kernels (q/k passed raw, dq/dk un-roped on the way
@@ -384,7 +386,7 @@ def bhsd_flash_attention(config, qt, kt, vt, rope_cos=None, rope_sin=None):
     kt = shard_logical(kt, ("batch", "kv_heads", "seq", "head_dim"))
     vt = shard_logical(vt, ("batch", "kv_heads", "seq", "head_dim"))
     return _sharded_flash(config, qt, kt, vt, rope_cos=rope_cos,
-                          rope_sin=rope_sin)
+                          rope_sin=rope_sin, sm_scale=sm_scale)
 
 
 def _seq_axis_active() -> bool:
@@ -396,14 +398,16 @@ def _seq_axis_active() -> bool:
         return False
 
 
-def _attention(config: LlamaConfig, q, k, v):
-    """q: [B,S,H,Dh], k/v: [B,S,KVH,Dh] -> [B,S,H,Dh]."""
+def _attention(config: LlamaConfig, q, k, v, sm_scale=None):
+    """q: [B,S,H,Dh], k/v: [B,S,KVH,Dh] -> [B,S,H,Dh]. ``sm_scale`` None
+    is every implementation's ``1 / sqrt(head_dim)``."""
     if config.attn_impl == "bshd" and not _seq_axis_active():
         # model-native layout end to end: no q/k/v/o transposes
         q = shard_logical(q, ("batch", "seq", "heads", "head_dim"))
         k = shard_logical(k, ("batch", "seq", "kv_heads", "head_dim"))
         v = shard_logical(v, ("batch", "seq", "kv_heads", "head_dim"))
-        return _sharded_flash(config, q, k, v, layout="bshd")
+        return _sharded_flash(config, q, k, v, layout="bshd",
+                              sm_scale=sm_scale)
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
@@ -415,11 +419,12 @@ def _attention(config: LlamaConfig, q, k, v):
         from dlrover_tpu.parallel.sequence import sequence_sharded_attention
 
         impl = "ulysses" if config.attn_impl == "ulysses" else "ring"
-        out = sequence_sharded_attention(qt, kt, vt, impl=impl, causal=True)
+        out = sequence_sharded_attention(qt, kt, vt, impl=impl, causal=True,
+                                         sm_scale=sm_scale)
     elif config.attn_impl in ("flash", "bshd"):
-        out = _sharded_flash(config, qt, kt, vt)
+        out = _sharded_flash(config, qt, kt, vt, sm_scale=sm_scale)
     else:
-        out = mha_reference(qt, kt, vt, causal=True)
+        out = mha_reference(qt, kt, vt, causal=True, sm_scale=sm_scale)
     return out.transpose(0, 2, 1, 3)
 
 

@@ -1,0 +1,143 @@
+"""State-space (Mamba-2 / SSD) scan and its causal depthwise convolution.
+
+The recurrence of a Mamba-2 layer (Dao & Gu 2024, "Transformers are
+SSMs"), one scalar decay a head::
+
+    H_t = exp(dt_t * A) * H_{t-1} + dt_t * x_t B_t^T      H in R^{P x N}
+    y_t = H_t C_t + D * x_t
+
+computed in its chunked ("state-space dual") form: the sequence is cut
+into chunks of ``chunk`` positions; inside a chunk the output is the
+masked product ``((C B^T) o L) (dt x)`` with ``L_ij = exp(cum_i -
+cum_j)`` for ``i >= j`` (``cum`` the running sum of ``dt * A`` inside
+the chunk); each chunk's end state is summed from its own positions;
+the end states are passed from chunk to chunk; and the state that
+enters a chunk adds ``exp(cum_i) C_i H_in`` to its outputs. The work is
+matmuls over chunk-sized blocks, which is what the MXU wants, where the
+recurrence itself is S dependent steps.
+
+One implementation, plain ``jax.numpy`` einsums differentiated by JAX,
+the same on the CPU and on the chip. ``dt``, ``A``, the running sums and
+every ``exp`` stay in float32; the chunk-sized matmul operands take the
+activations' dtype (bf16 in a bf16 model). The chunk-to-chunk pass is
+float32 at full precision: it is a thousandth of the work and carries
+the state across the whole sequence.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+
+def causal_conv1d(x, weight, bias):
+    """Depthwise causal convolution along the sequence.
+
+    ``x`` [B, S, C], ``weight`` [K, C], ``bias`` [C]:
+    ``y_t = bias + sum_k weight[k] * x_{t - (K - 1) + k}``, positions
+    before the sequence's start reading zero (``torch.nn.Conv1d`` with
+    ``groups=C, padding=K - 1``, cut to the first S outputs).
+    Accumulates in float32; returns float32."""
+    taps, seq = weight.shape[0], x.shape[1]
+    padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (taps - 1, 0), (0, 0)))
+    weight = weight.astype(jnp.float32)
+    out = bias.astype(jnp.float32)
+    for k in range(taps):
+        out = out + weight[k] * padded[:, k:k + seq]
+    return out
+
+
+def _decay_between(cum):
+    """``cum`` [..., T], the running sum of the steps' log-decays ->
+    [..., T, T]: ``exp(cum_i - cum_j)``, the decay from position j to
+    position i, for ``i >= j`` and 0 where j is in i's future. The mask
+    sits under the ``exp``: above the diagonal the difference is
+    positive and would overflow."""
+    size = cum.shape[-1]
+    diff = cum[..., :, None] - cum[..., None, :]
+    lower = jnp.tril(jnp.ones((size, size), bool))
+    return jnp.exp(jnp.where(lower, diff, -jnp.inf))
+
+
+def ssd_scan(x, dt, a, b, c, d, chunk: int):
+    """The chunked scan.
+
+    ``x``  [B, S, H, P]  per-head inputs (after convolution and silu)
+    ``dt`` [B, S, H]     step sizes, positive (after softplus), float32
+    ``a``  [H]           ``-exp(A_log)``, negative, float32
+    ``b``, ``c`` [B, S, G, N]  input and output projections of the
+                         state, each group shared by H / G heads
+    ``d``  [H]           skip weight
+    -> ``y`` [B, S, H, P] in ``x``'s dtype.
+
+    The sequence must be a whole number of chunks: a shorter tail would
+    need padding that the caller, which knows what a padded position
+    means for its loss, has to choose."""
+    batch, seq, heads, head = x.shape
+    groups, state = b.shape[2], b.shape[3]
+    if seq % chunk:
+        raise ValueError(
+            f"ssd_scan: sequence length {seq} is not a multiple of the "
+            f"chunk size {chunk}; pad the sequence or choose a chunk "
+            "that divides it"
+        )
+    if heads % groups:
+        raise ValueError(
+            f"ssd_scan: {heads} heads do not divide into {groups} groups"
+        )
+    n_chunks, per_group = seq // chunk, heads // groups
+    dtype = x.dtype
+    f32 = jnp.float32
+
+    with jax.named_scope("ssd_scan"):
+        dt = dt.astype(f32)
+        # [B, S, ...] -> [B, chunks, chunk, G, heads a group, ...]
+        xc = x.reshape(batch, n_chunks, chunk, groups, per_group, head)
+        dtc = dt.reshape(batch, n_chunks, chunk, groups, per_group)
+        bc = b.reshape(batch, n_chunks, chunk, groups, state)
+        cc = c.reshape(batch, n_chunks, chunk, groups, state)
+        # log-decay of every step, and its running sum inside the chunk
+        decay = dtc * a.astype(f32).reshape(groups, per_group)
+        decay = decay.transpose(0, 1, 3, 4, 2)          # [B, c, G, h, l]
+        cum = jnp.cumsum(decay, axis=-1)
+        # dt x: what a position adds to the state, per unit of B. Rounded
+        # once to the matmuls' dtype and read twice: kept in float32 for
+        # the end states it is twice the bytes and 2.8 ms of a 514 ms
+        # step at granite-4.0-h-micro's widths (PERF.md, PR 29)
+        xdt = (xc.astype(f32) * dtc[..., None]).astype(dtype)
+
+        # 1. inside a chunk: ((C B^T) o L) (dt x)
+        cb = jnp.einsum("bclgn,bcsgn->bcgls", cc, bc,
+                        preferred_element_type=f32)
+        within = _decay_between(cum)                    # [B, c, G, h, l, s]
+        mixed = (cb[:, :, :, None] * within).astype(dtype)
+        y = jnp.einsum("bcghls,bcsghp->bclghp", mixed, xdt,
+                       preferred_element_type=f32)
+
+        # 2. each chunk's own end state: sum_s decay(s -> end) B_s (dt x)_s
+        to_end = jnp.exp(cum[..., -1:] - cum)           # [B, c, G, h, s]
+        xdt_end = (xdt.astype(f32)
+                   * to_end.transpose(0, 1, 4, 2, 3)[..., None]).astype(dtype)
+        own = jnp.einsum("bcsgn,bcsghp->bcghpn", bc, xdt_end,
+                         preferred_element_type=f32)
+
+        # 3. from chunk to chunk: the state that enters chunk z is the
+        # sum of the earlier chunks' own end states, each decayed over
+        # the whole chunks between (float32, full precision)
+        total = cum[..., -1].transpose(0, 2, 3, 1)      # [B, G, h, c]
+        carry = _decay_between(
+            jnp.pad(jnp.cumsum(total, axis=-1), ((0, 0),) * 3 + ((1, 0),)))
+        entering = jnp.einsum(
+            "bghzc,bcghpn->bzghpn", carry[..., :-1, 1:], own,
+            precision=jax.lax.Precision.HIGHEST,
+        )
+
+        # 4. what the entering state adds: exp(cum_l) C_l H_in
+        from_start = jnp.exp(cum).transpose(0, 1, 4, 2, 3)  # [B, c, l, G, h]
+        y_in = jnp.einsum("bclgn,bcghpn->bclghp", cc, entering.astype(dtype),
+                          preferred_element_type=f32)
+        y = y + y_in * from_start[..., None]
+
+        y = y.reshape(batch, seq, heads, head)
+        y = y + x.astype(f32) * d.astype(f32)[:, None]
+        return y.astype(dtype)

@@ -25,6 +25,7 @@ and ZeRO sharding compose with pipelining without any model changes.
 from __future__ import annotations
 
 import functools
+import itertools
 from typing import Callable
 
 import jax
@@ -1189,6 +1190,41 @@ def quant_aware_policy(policy):
     return p
 
 
+# ``stage_layer_scan``'s ``policy`` for a layer that keeps its input
+# and nothing else from forward to backward
+LAYER_INPUT = "layer_input"
+
+
+def _recomputed_from_inputs(body):
+    """``body`` with its whole forward pass recomputed in the backward
+    pass from its inputs, whatever checkpoint encloses it. A
+    ``jax.checkpoint`` cannot promise that: an enclosing checkpoint's
+    policy (accelerate._remat_wrap round the whole loss) rules the
+    first forward pass right through it and keeps what *it* names, the
+    weight matmuls' outputs. Those are 0.44 GiB a layer at 8192 tokens
+    and hidden 2048 with a 4x feed-forward and a 2x state-space mixer:
+    the difference between ten such layers fitting one chip and not.
+    The residuals of a ``custom_vjp`` are what its forward rule
+    returns, here the inputs alone."""
+
+    @jax.custom_vjp
+    def run(*args):
+        return body(*args)
+
+    def forward(*args):
+        return body(*args), args
+
+    def backward(args, cotangent):
+        # the barrier ties the recomputation to the cotangent's
+        # arrival: without it the compiler may merge it with the first
+        # forward pass (a run of one layer is no loop) and keep it all
+        args, cotangent = jax.lax.optimization_barrier((args, cotangent))
+        return jax.vjp(body, *args)[1](cotangent)
+
+    run.defvjp(forward, backward)
+    return run
+
+
 def stage_layer_scan(
     layer_fn: Callable,
     remat: bool = True,
@@ -1202,7 +1238,8 @@ def stage_layer_scan(
     ``layer_fn(h, one_layer_params, *extras) -> (h, aux)``. Whatever
     save policy applies (passed, or :func:`minimal_save_policy` by
     default) is adapted to the int8 quantized path via
-    :func:`quant_aware_policy`.
+    :func:`quant_aware_policy`. ``policy=LAYER_INPUT`` keeps a layer's
+    input alone (:func:`_recomputed_from_inputs`).
 
     ``layer_axes`` (a pytree matching ONE layer's params whose leaves
     are logical-axis tuples) opts the scan into collective–compute
@@ -1222,12 +1259,24 @@ def stage_layer_scan(
         from dlrover_tpu.ops.fp8 import remat_disabled
         from dlrover_tpu.parallel.overlap import layer_gather_fn
 
-        chosen_policy = quant_aware_policy(
-            policy or minimal_save_policy()
-        )
         # the strategy's remat="none" wins over the model config: a
         # no-remat trace must emit no checkpoint at any layer
         do_remat = remat and not remat_disabled()
+        layer_input = policy == LAYER_INPUT
+        # LAYER_INPUT wraps the layer itself, operands as arguments (a
+        # custom_vjp must not close over what is differentiated); any
+        # other policy is a jax.checkpoint round the scan's body
+        step = (
+            _recomputed_from_inputs(body) if do_remat and layer_input
+            else body
+        )
+
+        def checkpointed(scan_body):
+            if not do_remat or layer_input:
+                return scan_body
+            return jax.checkpoint(scan_body, policy=quant_aware_policy(
+                policy or minimal_save_policy()
+            ))
 
         gather = layer_gather_fn(layer_axes)
         if gather is not None:
@@ -1250,13 +1299,10 @@ def stage_layer_scan(
                 # (the last iteration re-fetches its own layer — the
                 # buffer is unused but keeps one compiled body)
                 p_next = fetch(jnp.minimum(i + 1, L - 1))
-                inner, _ = body((h, aux_sum), p_cur, *extras)
+                inner, _ = step((h, aux_sum), p_cur, *extras)
                 return (inner, p_next), None
 
-            if do_remat:
-                overlap_body = jax.checkpoint(
-                    overlap_body, policy=chosen_policy
-                )
+            overlap_body = checkpointed(overlap_body)
             carry0 = (
                 (h, jnp.zeros((), jnp.float32)),
                 fetch(jnp.int32(0)),
@@ -1267,13 +1313,56 @@ def stage_layer_scan(
             return h, aux_sum
 
         def scan_body(carry, layer_params):
-            return body(carry, layer_params, *extras)
+            return step(carry, layer_params, *extras)
 
-        if do_remat:
-            scan_body = jax.checkpoint(scan_body, policy=chosen_policy)
+        scan_body = checkpointed(scan_body)
         (h, aux_sum), _ = jax.lax.scan(
             scan_body, (h, jnp.zeros((), jnp.float32)), local_params
         )
+        return h, aux_sum
+
+    return stage_fn
+
+
+def layer_runs(layer_types):
+    """A declared pattern of layer kinds, one entry a layer, as its
+    runs of like layers in order: ``("mamba",) * 5 + ("attention",) +
+    ("mamba",) * 4 -> [("mamba", 5), ("attention", 1), ("mamba", 4)]``.
+    A run is what one stacked parameter tree and one scan can hold."""
+    return [
+        (kind, len(list(group)))
+        for kind, group in itertools.groupby(layer_types)
+    ]
+
+
+def stage_run_scan(
+    layer_fns: dict,
+    runs,
+    remat: bool = True,
+    policy=None,
+    layer_axes=None,
+):
+    """A ``stage_fn`` over a stack of unlike layers: ``runs`` is
+    ``[(name, kind)]`` in the stack's order, ``layer_fns[kind]`` the
+    layer body of a kind (``stage_layer_scan``'s contract) and
+    ``local_params[name]`` the run's parameters stacked on axis 0. Each
+    run goes through :func:`stage_layer_scan` — one compiled body a
+    kind and position in the pattern, the same save policy, remat gate
+    and overlap hook as a homogeneous stack — and the runs are chained
+    in order. ``policy`` and ``layer_axes`` are keyed by kind."""
+    stages = {
+        kind: stage_layer_scan(
+            fn, remat=remat, policy=(policy or {}).get(kind),
+            layer_axes=(layer_axes or {}).get(kind),
+        )
+        for kind, fn in layer_fns.items()
+    }
+
+    def stage_fn(local_params, h, *extras):
+        aux_sum = jnp.zeros((), jnp.float32)
+        for name, kind in runs:
+            h, aux = stages[kind](local_params[name], h, *extras)
+            aux_sum = aux_sum + aux
         return h, aux_sum
 
     return stage_fn

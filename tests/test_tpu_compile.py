@@ -240,6 +240,15 @@ ATTENTION_NAMES = {
         flash_attention_bshd, (2, 2048, 32, 128), (2, 2048, 8, 128),
         {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"},
     ),
+    # granite-4.0-h-micro's attention layer: 32 heads of 64 over 8 KV
+    # heads, 1 x 8192, the published multiplier as the softmax scale;
+    # at 8192 positions the dispatcher takes the split backward
+    "granite-4.0-h-micro": (
+        functools.partial(flash_attention, sm_scale=0.015625,
+                          block_q=1024, block_k=1024),
+        (1, 32, 8192, 64), (1, 8, 8192, 64),
+        {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "flash_bwd_delta"},
+    ),
 }
 
 
@@ -257,6 +266,36 @@ def test_attention_kernels_carry_their_names(one_chip, widths):
     for name in names:
         # in the operation's metadata too, under the scope of the call
         assert f"/{name}/pallas_call" in text
+
+
+def test_mamba_layer_forward_backward(one_chip, on_tpu):
+    """One Mamba-2 layer of granite-4.0-h-micro at its published widths
+    and the cell's 8192 tokens, forward and backward: the chunked scan
+    (ops/ssd.py, plain einsums) compiles for the chip, under its scope,
+    beside a layer's own weights and gradients."""
+    from dlrover_tpu.models import granite_hybrid as gh
+
+    config = gh.GraniteHybridConfig(vocab_size=12544,
+                                    layer_types=("mamba",))
+    loss = gh.granite_hybrid_loss_fn(config)
+    params = jax.eval_shape(
+        lambda: gh.granite_hybrid_init(config, jax.random.key(0)))
+    compiled = _compile(
+        jax.value_and_grad(lambda p, b: loss(
+            jax.tree.map(lambda x: x.astype(jnp.bfloat16), p), b, None
+        )),
+        *_shaped(
+            (params,
+             {"tokens": jax.ShapeDtypeStruct((1, 8192 + 1), jnp.int32)}),
+            one_chip,
+        ),
+    )
+    text = compiled.as_text()
+    for scope in ("mamba_in_proj", "mamba_conv", "ssd_scan",
+                  "mamba_gate_norm", "mamba_out_proj", "mlp", "head"):
+        assert f"/{scope}/" in text or f"({scope})" in text, scope
+    assert _kernels(compiled) == 0      # no Pallas kernel in a Mamba layer
+    assert _device_bytes(compiled) < 0.5 * V5E_HBM_BYTES
 
 
 def test_quantization_kernels_carry_their_names(one_chip):
