@@ -66,7 +66,7 @@ from dlrover_tpu.models.llama import (
 )
 from dlrover_tpu.ops.cross_entropy import softmax_cross_entropy
 from dlrover_tpu.ops.fp8 import qdot, qeinsum
-from dlrover_tpu.ops.ssd import causal_conv1d, ssd_scan
+from dlrover_tpu.ops.ssd import causal_conv_silu, ssd_scan
 from dlrover_tpu.parallel.sharding import shard_logical
 
 KINDS = ("mamba", "attention")
@@ -326,11 +326,11 @@ def _mamba_mixer(config, y, p):
     with jax.named_scope("mamba_in_proj"):
         zxbcdt = qdot(y, p["in_proj"].astype(dtype), site="mamba_proj")
         z = zxbcdt[..., :inner]
-        xbc = zxbcdt[..., inner:inner + config.mamba_conv_dim]
         dt = zxbcdt[..., inner + config.mamba_conv_dim:]
     with jax.named_scope("mamba_conv"):
-        xbc = jax.nn.silu(
-            causal_conv1d(xbc, p["conv_w"], p["conv_b"])).astype(dtype)
+        # xBC, channels inner.. of the projection's output: the kernel
+        # reads them where they lie
+        xbc = causal_conv_silu(zxbcdt, p["conv_w"], p["conv_b"], first=inner)
     x = xbc[..., :inner].reshape(B, S, heads, config.mamba_head_dim)
     b = xbc[..., inner:inner + groups * state].reshape(B, S, groups, state)
     c = xbc[..., inner + groups * state:].reshape(B, S, groups, state)

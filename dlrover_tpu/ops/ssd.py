@@ -16,18 +16,35 @@ enters a chunk adds ``exp(cum_i) C_i H_in`` to its outputs. The work is
 matmuls over chunk-sized blocks, which is what the MXU wants, where the
 recurrence itself is S dependent steps.
 
-One implementation, plain ``jax.numpy`` einsums differentiated by JAX,
-the same on the CPU and on the chip. ``dt``, ``A``, the running sums and
-every ``exp`` stay in float32; the chunk-sized matmul operands take the
-activations' dtype (bf16 in a bf16 model). The chunk-to-chunk pass is
-float32 at full precision: it is a thousandth of the work and carries
-the state across the whole sequence.
+The scan is one implementation, plain ``jax.numpy`` einsums
+differentiated by JAX, the same on the CPU and on the chip. ``dt``,
+``A``, the running sums and every ``exp`` stay in float32; the
+chunk-sized matmul operands take the activations' dtype (bf16 in a bf16
+model). The chunk-to-chunk pass is float32 at full precision: it is a
+thousandth of the work and carries the state across the whole sequence.
+
+The convolution with its activation, :func:`causal_conv_silu`, is one
+algorithm in two forms, chosen by what the input shows and by nothing
+else: the Pallas kernel pair of ``ops/causal_conv.py`` where its blocks
+tile the input (sequence a multiple of 128, channels and the first of
+them a multiple of 16) and the mesh does not split the sequence;
+everywhere else (the short sequences and toy widths of the CPU tests,
+a ``seq`` mesh axis: a halo across sequence shards is not built) the
+plain form, ``silu(causal_conv1d(...))``, which is also the kernels'
+reference.
 """
 
 from __future__ import annotations
 
+import functools
+import math
+
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec
+
+from dlrover_tpu.common import telemetry
+from dlrover_tpu.ops import causal_conv
 
 
 def causal_conv1d(x, weight, bias):
@@ -45,6 +62,47 @@ def causal_conv1d(x, weight, bias):
     for k in range(taps):
         out = out + weight[k] * padded[:, k:k + seq]
     return out
+
+
+def causal_conv_silu(x, weight, bias, first: int = 0):
+    """``silu(causal_conv1d(x[..., first:first + C], weight, bias))`` in
+    ``x``'s dtype: the Mamba-2 mixer's convolution with its activation,
+    over the C channels of ``x`` [B, S, first + C or more] that
+    ``weight`` [K, C] has. The module's docstring has the rule that
+    picks the kernel pair or the plain form; the gauge
+    ``model.conv.impl`` says which was traced."""
+    from dlrover_tpu.parallel.mesh import get_mesh
+
+    try:
+        mesh = get_mesh()
+        axes = dict(mesh.shape)
+    except RuntimeError:
+        mesh, axes = None, {}
+    # pallas_call does not partition itself: on a mesh that splits the
+    # batch the kernels are mapped over those axes
+    batch_axes = tuple(a for a in ("data", "fsdp") if axes.get(a, 1) > 1)
+    taps, channels = weight.shape
+    kernel = (
+        causal_conv.kernel_takes(x.shape[1], channels, first, taps)
+        and axes.get("seq", 1) == 1
+        and x.shape[0] % math.prod(axes[a] for a in batch_axes) == 0
+    )
+    telemetry.gauge_set(
+        "model.conv.impl", 1, impl="kernel" if kernel else "plain")
+    if not kernel:
+        x = x[..., first:first + channels]
+        return jax.nn.silu(causal_conv1d(x, weight, bias)).astype(x.dtype)
+    run = functools.partial(causal_conv.causal_conv_silu_kernel, first=first)
+    if not batch_axes:
+        return run(x, weight, bias)
+    rows = PartitionSpec(batch_axes)
+    return jax.shard_map(
+        run,
+        mesh=mesh,
+        in_specs=(rows, PartitionSpec(), PartitionSpec()),
+        out_specs=rows,
+        check_vma=False,
+    )(x, weight, bias)
 
 
 def _decay_between(cum):

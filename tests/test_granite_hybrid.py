@@ -356,10 +356,10 @@ def test_model_is_near_the_reference_in_bf16_and_int8_is_not(toy):
     assert _rel_rms(logits8, toy["logits"]) > BF16_LOGITS
 
 
-def _shifted_conv(x, weight, bias):
+def _shifted_conv(x, weight, bias, first=0):
     """The fault: every tap reads one position too early."""
-    return ssd.causal_conv1d(
-        jnp.pad(x, ((0, 0), (1, 0), (0, 0)))[:, :-1], weight, bias)
+    return ssd.causal_conv_silu(
+        jnp.pad(x, ((0, 0), (1, 0), (0, 0)))[:, :-1], weight, bias, first)
 
 
 def _gate_after_norm(y, z, scale, eps):
@@ -370,7 +370,7 @@ def _gate_after_norm(y, z, scale, eps):
 
 
 @pytest.mark.parametrize("name,fault", [
-    ("causal_conv1d", _shifted_conv), ("_gated_norm", _gate_after_norm),
+    ("causal_conv_silu", _shifted_conv), ("_gated_norm", _gate_after_norm),
 ])
 def test_planted_fault_fails_the_comparison(toy, monkeypatch, name, fault):
     monkeypatch.setattr(gh, name, fault)
@@ -378,6 +378,26 @@ def test_planted_fault_fails_the_comparison(toy, monkeypatch, name, fault):
     # not by a hair: the bf16 limits fail too (read 0.2 to 0.6)
     assert _rel_rms(logits, toy["logits"]) > 4 * BF16_LOGITS
     assert _rel_rms(grads, toy["grads"]) > 4 * BF16_GRAD
+
+
+def test_toy_model_runs_the_convolution_kernels(toy):
+    """128 positions and 160 channels from channel 128 on: the model
+    tests above ran the kernel pair (interpret mode), not the plain
+    form, and the gauge says so."""
+    from dlrover_tpu.common import telemetry
+
+    cfg = toy["family"].model_config
+    telemetry.enable("test")
+    try:
+        jaxpr = jax.make_jaxpr(
+            lambda p: gh.granite_hybrid_apply(cfg, p, toy["tokens"][:, :-1])
+        )(toy["params"])
+        impls = {g["labels"]["impl"] for g in telemetry.snapshot()["gauges"]
+                 if g["name"] == "model.conv.impl"}
+    finally:
+        telemetry.install_from_env()
+    assert impls == {"kernel"}
+    assert "causal_conv_fwd" in str(jaxpr)
 
 
 # ---------------------------------------------------------- configuration

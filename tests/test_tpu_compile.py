@@ -268,11 +268,36 @@ def test_attention_kernels_carry_their_names(one_chip, widths):
         assert f"/{name}/pallas_call" in text
 
 
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_causal_conv_kernels_at_the_cell_size(one_chip, dtype):
+    """The convolution's kernel pair alone at granite-4.0-h-micro's
+    sizes, ``xBC`` read out of the projection's output of one
+    8192-token sequence: Mosaic takes the default blocks in the cell's
+    bf16 and in float32, and each kernel carries its name."""
+    from dlrover_tpu.ops.causal_conv import causal_conv_silu_kernel
+
+    x = jax.ShapeDtypeStruct((1, 8192, 8512), dtype)
+    weight = jax.ShapeDtypeStruct((4, 4352), dtype)
+    bias = jax.ShapeDtypeStruct((4352,), dtype)
+    compiled = _compile(
+        _sum_grad(functools.partial(causal_conv_silu_kernel, first=4096,
+                                    interpret=False)),
+        *_shaped((x, weight, bias), one_chip),
+    )
+    assert sorted(_kernel_names(compiled)) == [
+        "causal_conv_bwd", "causal_conv_fwd"]
+    text = compiled.as_text()
+    for name in ("causal_conv_fwd", "causal_conv_bwd"):
+        assert f"/{name}/pallas_call" in text
+
+
 def test_mamba_layer_forward_backward(one_chip, on_tpu):
     """One Mamba-2 layer of granite-4.0-h-micro at its published widths
     and the cell's 8192 tokens, forward and backward: the chunked scan
     (ops/ssd.py, plain einsums) compiles for the chip, under its scope,
-    beside a layer's own weights and gradients."""
+    beside a layer's own weights and gradients; the convolution is its
+    kernel pair (forward, the recomputed forward, backward) and the
+    layer has no other kernel."""
     from dlrover_tpu.models import granite_hybrid as gh
 
     config = gh.GraniteHybridConfig(vocab_size=12544,
@@ -294,7 +319,10 @@ def test_mamba_layer_forward_backward(one_chip, on_tpu):
     for scope in ("mamba_in_proj", "mamba_conv", "ssd_scan",
                   "mamba_gate_norm", "mamba_out_proj", "mlp", "head"):
         assert f"/{scope}/" in text or f"({scope})" in text, scope
-    assert _kernels(compiled) == 0      # no Pallas kernel in a Mamba layer
+    names = _kernel_names(compiled)
+    assert len(names) == _kernels(compiled)
+    assert set(names) == {"causal_conv_fwd", "causal_conv_bwd"}, names
+    assert names.count("causal_conv_bwd") == 1
     assert _device_bytes(compiled) < 0.5 * V5E_HBM_BYTES
 
 
