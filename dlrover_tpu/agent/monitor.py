@@ -491,29 +491,6 @@ class MetricsEndpoint:
             )
         except Exception:  # noqa: BLE001 - no worker progress yet
             pass
-        kpath = os.environ.get(
-            ConfigPath.ENV_KERNEL_METRICS, ConfigPath.KERNEL_METRICS
-        )
-        try:
-            with open(kpath) as f:
-                kern = json.load(f)
-            ops = kern.get("top_ops") or []
-            if ops:
-                # per-op self time from the latest XPlane step window
-                # (trainer/profiler.py publish_kernel_stats) — the
-                # online xpu_timer-style named-kernel export
-                metric(
-                    "dlrtpu_kernel_self_ms",
-                    "Top HLO ops by self time per step (XPlane window)",
-                    "gauge",
-                    [
-                        ({"op": o["op"], "category": o["category"]},
-                         o["self_ms_per_step"])
-                        for o in ops
-                    ],
-                )
-        except Exception:  # noqa: BLE001 - no profiled window yet
-            pass
         metric(
             "dlrtpu_host_memory_used_mb", "Host memory in use",
             "gauge", [({}, get_used_memory_mb())],

@@ -3,11 +3,10 @@
 Equivalent capability: the reference pins and reuses host staging
 buffers for its D2H/H2D checkpoint legs (atorch's pinned-memory pools)
 so a multi-GB save/restore does not pay page-fault-in on every pass.
-The cold-vs-warm bench gap (``ckpt_engine_cold_gbps`` 1.31 vs 5.81
-warm in a pre-PR-1 chip run) was exactly that tax: a fresh buffer's first touch
-faults pages in single-threaded, while a reused one runs at memory
-bandwidth. This arena keeps freed checkpoint buffers alive for the
-process lifetime so repeat saves/restores hit warm pages.
+A fresh buffer's first touch faults pages in single-threaded, while a
+reused one runs at memory bandwidth. This arena keeps freed checkpoint
+buffers alive for the process lifetime so repeat saves/restores hit
+warm pages.
 
 Ownership rules (enforced by the API shape, documented in
 docs/DESIGN.md "Restore data path"):
@@ -27,7 +26,7 @@ docs/DESIGN.md "Restore data path"):
 
 Telemetry: ``ckpt.arena.hits`` / ``ckpt.arena.misses`` counters and a
 ``ckpt.arena.pooled_bytes`` gauge make reuse visible in
-``tools/obs_report.py`` and the bench.
+``tools/obs_report.py``.
 """
 
 from __future__ import annotations

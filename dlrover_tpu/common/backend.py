@@ -1,7 +1,7 @@
 """Which JAX backend a process runs on, and where it caches compiles.
 
 One decision, made here for every entry point (tpu-run workers, the
-decode engine, the probe payloads, bench, chip_smoke):
+decode engine, the probe payloads, the benchmark, chip_smoke):
 
 - JAX drops to the CPU with a warning when it cannot take the chip.
   Nothing in this package may ride that fallback: a process runs on the
@@ -80,7 +80,8 @@ def compile_cache_env(env: dict) -> dict:
 def enable_compile_cache() -> str:
     """Switch this process's persistent cache on (entry points that
     were not spawned with :func:`compile_cache_env`'s environment: the
-    decode worker, bench). Call before the first compile."""
+    decode worker, ``benchmark/run.py``). Call before the first
+    compile."""
     import jax
 
     compile_cache_env(os.environ)

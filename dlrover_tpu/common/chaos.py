@@ -655,14 +655,14 @@ NAMED_SCHEDULES: dict[str, dict] = {
     # expiry must re-queue each of them exactly once onto the
     # survivors, throughput degrades instead of requests dropping, and
     # the ledger ends with zero failed / zero double-served requests.
-    # Driven by tools/chaos_run.py ``_run_serve_kill``, which publishes
+    # Driven by tools/chaos_run.py ``_run_serve_kill``, which prints
     # serve_tokens_per_s / serve_ttft_p50_ms / serve_ttft_p99_ms /
-    # serve_goodput_pct (gated by tools/bench_diff.py).
+    # serve_goodput_pct.
     "serve-kill": {
         "desc": "kill one decode worker mid-sweep; its leased requests "
         "must re-queue exactly once onto the survivors — throughput "
-        "degrades, nothing is dropped or double-served; publishes the "
-        "serve_* bench keys",
+        "degrades, nothing is dropped or double-served; prints the "
+        "serve_* keys",
         "seed": 41,
         "rules": [
             {
@@ -684,8 +684,8 @@ NAMED_SCHEDULES: dict[str, dict] = {
     # survivor restarts. ``max: 6`` bounds host 3's affliction to two
     # probes (3 legs each): its backoff re-probe comes back clean and
     # the gate re-admits it. Driven by tools/chaos_run.py
-    # ``_run_bad_host``, which publishes probe_join_overhead_s /
-    # bad_host_quarantine_s (gated by tools/bench_diff.py).
+    # ``_run_bad_host``, which prints probe_join_overhead_s /
+    # bad_host_quarantine_s.
     "bad-host": {
         "desc": "degrade host 3's join probe (quarantined at the door, "
         "re-admitted after its backoff re-probe comes back clean) and "

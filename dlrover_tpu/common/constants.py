@@ -203,8 +203,6 @@ class ConfigPath:
     PARAL_CONFIG = "/tmp/dlrover_tpu/auto_paral_config.json"
     ENV_RUNTIME_METRICS = "DLROVER_RUNTIME_METRICS_PATH"
     RUNTIME_METRICS = "/tmp/dlrover_tpu/runtime_metrics.json"
-    ENV_KERNEL_METRICS = "DLROVER_KERNEL_METRICS_PATH"
-    KERNEL_METRICS = "/tmp/dlrover_tpu/kernel_metrics.json"
 
 
 class CheckpointConstant:

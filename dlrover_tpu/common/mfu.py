@@ -1,12 +1,9 @@
-"""Model-FLOPs utilization accounting — ONE definition shared by the
-trainer's per-step ``train.mfu`` gauge and the bench's offline
-``mfu_pct`` key, so the live and offline numbers cannot drift.
+"""Model-FLOPs utilization accounting for the trainer's per-step
+``train.mfu`` gauge.
 
-The FLOPs model is the standard dense-transformer estimate: 6 FLOPs per
-parameter per token (fwd 2 + bwd 4) plus the causal-attention
-``QK^T``/``AV`` term ``12 * n_layers * dim * tokens * seq / 2`` that the
-parameter count does not capture. Models without the attention term
-(recsys, linear probes) use the dense part alone.
+The FLOPs model is the dense estimate, 6 FLOPs per parameter per token
+(fwd 2 + bwd 4), unless the caller hands the Trainer its own count
+(``TrainingArgs.model_flops_per_token``).
 
 Peak FLOP/s comes from ONE table keyed by the device kind JAX reports.
 An accelerator that is not in it is an error, never a default: a
@@ -37,22 +34,6 @@ def peak_flops(device) -> float | None:
             f"with its source"
         )
     return peak
-
-
-def transformer_step_flops(
-    params: int,
-    tokens: int,
-    n_layers: int = 0,
-    dim: int = 0,
-    seq: int = 0,
-) -> float:
-    """Model FLOPs of one train step over ``tokens`` tokens: dense
-    ``6 * params * tokens`` plus the causal attention score/value term
-    when the transformer shape is known (0s = dense-only estimate)."""
-    flops = 6.0 * params * tokens
-    if n_layers and dim and seq:
-        flops += 12.0 * n_layers * dim * tokens * seq / 2
-    return flops
 
 
 def mfu(flops_per_step: float, step_seconds: float, peak: float) -> float:

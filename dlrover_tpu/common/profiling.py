@@ -61,7 +61,7 @@ ENV_REGRESSION_RATIO = "DLROVER_PROF_REGRESSION_RATIO"
 # the sampler's steady-state overhead budget as a percent of training
 # wall-clock: the cost governor stretches the sampling gap until the
 # measured per-window cost amortizes under this. 0 disables governing
-# (fixed cadence — tests, short benches).
+# (fixed cadence — tests).
 ENV_OVERHEAD_PCT = "DLROVER_PROF_OVERHEAD_PCT"
 # a share of the job's throughput, really spent since the governor
 # amortizes against the true step time (PERF.md section 6, PR 26, has
@@ -427,8 +427,8 @@ def execute_capture(
 
 
 class _JaxProfilerBackend:
-    """Thin seam over jax.profiler so tests (and the bench's stub
-    parse) can swap the capture mechanism without touching jax."""
+    """Thin seam over jax.profiler so tests can swap the capture
+    mechanism without touching jax."""
 
     def start(self, log_dir: str) -> bool:
         import jax
@@ -438,8 +438,8 @@ class _JaxProfilerBackend:
             jax.profiler.start_trace(log_dir)
             return True
         except Exception as e:  # noqa: BLE001 - a trace already active
-            # (e.g. the bench's StepProfiler window) must not kill the
-            # training step; skip this sample window
+            # (another profiler session in this process) must not kill
+            # the training step; skip this sample window
             logger.warning("profiler start skipped: %s", e)
             return False
 
@@ -565,12 +565,6 @@ class DeviceTimeSampler:
     def _parsing(self) -> int:
         """Windows handed to the parse thread and not yet converted."""
         return self._queue.unfinished_tasks
-
-    @property
-    def step_ewma_s(self) -> float:
-        """The governor's running estimate of an untraced step's wall
-        time — the denominator its overhead budget amortizes against."""
-        return self._step_ewma
 
     # --------------------------------------------------------- step hooks
 
@@ -774,7 +768,7 @@ class DeviceTimeSampler:
     def _parse(self, trace_dir: str, steps: int) -> dict:
         if self.parse_fn is not None:
             # an injected parser owns its own input contract (it may
-            # not read trace files at all — bench stubs, tests)
+            # not read trace files at all — tests)
             return dict(self.parse_fn(trace_dir, steps) or {})
         self._await_xplane(trace_dir)
         summary = trace_summary.summarize(trace_dir, steps=steps)

@@ -101,9 +101,8 @@ class _Handler(socketserver.BaseRequestHandler):
                 logger.exception("rpc dispatch error")
                 reply = (False, f"{type(e).__name__}: {e}")
             # per-verb/message servicer latency: the control-plane
-            # surface (master_rpc_p99_ms, joins_per_sec) the bench and
-            # obs_report publish, and the baseline the future swarm
-            # harness regresses against
+            # surface (master_rpc_p99_ms, joins_per_sec) that
+            # tools/obs_report.py publishes
             telemetry.observe(
                 "master.rpc.seconds",
                 time.perf_counter() - t0,

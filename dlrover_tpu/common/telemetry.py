@@ -145,8 +145,8 @@ def nearest_rank_percentile(values, q: float) -> float:
     """Nearest-rank percentile (q in [0, 1]) of an unsorted iterable,
     0.0 when empty. One definition shared by the serving SLO rule
     (``metrics_store.SloWatchdog``) and the load generator's headline
-    TTFT keys (``serving/loadgen.py``) so the gate and the bench can
-    never drift."""
+    TTFT keys (``serving/loadgen.py``) so the gate and the load
+    generator can never drift."""
     ordered = sorted(values)
     if not ordered:
         return 0.0
@@ -183,7 +183,7 @@ def sum_bucket_counts(hists):
     bounds win; series with mismatched bounds are skipped rather than
     mis-merged. Returns ``(bounds, counts)`` — ``(None, None)`` when
     the input is empty. Shared by every surface that collapses
-    per-label series into one quantile (bench, obs_report)."""
+    per-label series into one quantile (``tools/obs_report.py``)."""
     hists = list(hists)
     if not hists:
         return None, None

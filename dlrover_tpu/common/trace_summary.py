@@ -1,13 +1,11 @@
 """Shared XPlane trace summarizer: ONE trace-walking implementation.
 
-Three consumers used to carry their own copy of the xprof ``hlo_stats``
-walk — ``tools/parse_profile.py`` (offline CLI), ``tools/
-profile_step.py`` (ad-hoc step profiler), and ``trainer/profiler.py``
-(the bench/agent per-op export). The deep-profiling plane adds a fourth
-(``common/profiling.py``'s sampler parses a trace on every sampled
-step), which is one copy too many: this module is now the only place
-that knows the xprof table layout, so a format drift breaks in ONE
-spot with ONE fix.
+Two consumers read the xprof ``hlo_stats`` walk from here:
+``tools/parse_profile.py`` (the operator's CLI) and
+``common/profiling.py`` (the sampler parses a trace on every sampled
+step, and a deep capture summarizes its window). This module is the
+only place that knows the xprof table layout, so a format drift breaks
+in ONE spot with ONE fix.
 
 Also the one place that knows the **canonical op-category buckets** the
 always-on accounting publishes (``device.optime_ms{category=...}``):
@@ -18,8 +16,7 @@ category strings drift.
 
 xprof is optional (CPU smoke environments ship without it):
 :func:`toolchain_available` probes once, and every consumer degrades —
-the CLI prints a clear message, the sampler disables itself, the bench
-publishes a sentinel.
+the CLI prints a clear message, the sampler disables itself.
 """
 
 from __future__ import annotations
@@ -27,10 +24,6 @@ from __future__ import annotations
 import glob
 import json
 import os
-
-from dlrover_tpu.common.log import get_logger
-
-logger = get_logger(__name__)
 
 # canonical category buckets, coarsest-useful granularity for per-step
 # accounting and baselines (raw xprof category strings vary by version)
@@ -197,32 +190,6 @@ def summarize(trace_dir: str, steps: int = 1, top: int = 45) -> dict | None:
             for o in ops[:top]
         ],
     }
-
-
-def top_ops(log_dir: str, k: int = 15, steps: int = 1) -> list[dict]:
-    """Top-k HLO ops of the NEWEST trace under ``log_dir`` by self
-    time, per profiled step: ``[{op, category, self_ms_per_step}]``.
-    Best-effort (returns ``[]`` on a missing toolchain or a layout it
-    cannot read) — this is the online agent-export path, where a parse
-    failure must never take the caller down."""
-    paths = xplane_paths(log_dir)
-    if not paths:
-        return []
-    try:
-        ops = op_table([paths[-1]])
-    except Exception:  # noqa: BLE001 - xprof optional / format drift
-        logger.warning("xprof unavailable; no per-op stats", exc_info=True)
-        return []
-    return [
-        {
-            "op": o["op"],
-            "category": o["category"],
-            "self_ms_per_step": round(
-                o["self_us"] / max(steps, 1) / 1e3, 4
-            ),
-        }
-        for o in ops[:k]
-    ]
 
 
 def render(summary: dict) -> str:

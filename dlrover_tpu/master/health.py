@@ -112,6 +112,7 @@ class HostHealthManager:
         self._backoff = backoff_s
         self._backoff_cap = backoff_cap_s
         self._persist_obs = max(persist_obs, 1)
+        self._slack_ms = SLACK_MS
         # durability hooks (the servicer's state-store passthroughs);
         # None degrades to in-memory verdicts, like the brain's plans
         self._wal_fn = wal_fn
@@ -165,7 +166,7 @@ class HostHealthManager:
                 med = median_baseline(fleet)
                 if (
                     med > 0
-                    and mine - med >= SLACK_MS
+                    and mine - med >= self._slack_ms
                     and mine / med > worst
                 ):
                     worst, blamed, basis = mine / med, leg, "fleet"
@@ -173,7 +174,7 @@ class HostHealthManager:
             base = own.get(leg, 0)
             if (
                 base > 0
-                and mine - base >= SLACK_MS
+                and mine - base >= self._slack_ms
                 and mine / base > worst
             ):
                 worst, blamed, basis = mine / base, leg, "self"

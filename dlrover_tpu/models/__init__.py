@@ -1,5 +1,5 @@
-"""Model zoo: TPU-first reference models used by the trainer, benches and
-the auto_accelerate strategy tests.
+"""Model zoo: TPU-first reference models used by the trainer, the benchmark
+and the auto_accelerate strategy tests.
 
 Equivalent capability: the reference accelerates HF models (Llama/GPT2/
 GLM/Bert attention swaps, atorch/atorch/modules/transformer/layers.py) and

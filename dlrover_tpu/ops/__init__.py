@@ -25,7 +25,4 @@ from dlrover_tpu.ops.collectives import (  # noqa: F401
     ring_all_gather,
     ring_reduce_scatter,
 )
-from dlrover_tpu.ops.fused_optim import (  # noqa: F401
-    fused_adamw,
-    pallas_call_count,
-)
+from dlrover_tpu.ops.fused_optim import fused_adamw  # noqa: F401

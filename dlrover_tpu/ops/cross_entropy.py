@@ -195,8 +195,9 @@ def fused_linear_cross_entropy(
     the remat=none headline arm of a pre-PR-1 chip run, its #3 op).
     The ``custom_vjp`` form expresses
     the identical recompute schedule with zero remat machinery, so a
-    remat="none" step is now genuinely checkpoint-free (the bench's
-    StepProfiler forbid-ops gate pins it).
+    remat="none" step is now genuinely checkpoint-free
+    (``tests/test_remat_gate.py::TestNoRematGate`` pins it on the
+    jaxpr).
 
     ``norm_scale``/``norm_eps``: fuse the model's final RMSNorm into
     each chunk (the production path — models/llama.py). ``norm_fn``

@@ -12,8 +12,8 @@ concatenated into one flat ``[rows, BLOCK]`` buffer; grad-norm
 clipping, the Adam moment update, the parameter update, and (for 8-bit
 state) the moment decode/encode all run in ONE ``pallas_call`` over
 that buffer — a bounded dispatch count regardless of how many leaves
-the model has (pinned by :func:`pallas_call_count` in the tests and the
-bench's ``opt_fused_dispatches`` key). Because each leaf starts at a
+the model has (``tests/test_hot_loop.py`` counts the ``pallas_call``s
+in the update's jaxpr). Because each leaf starts at a
 block boundary, the 8-bit blockwise scales are identical to the
 per-leaf kernels' and the state stays checkpoint-compatible
 (plain pytree of arrays).
@@ -55,7 +55,6 @@ __all__ = [
     "FusedAdam8bitState",
     "flatten_to_blocks",
     "unflatten_from_blocks",
-    "pallas_call_count",
 ]
 
 # ---------------------------------------------------------------------------
@@ -395,38 +394,3 @@ def fused_adamw(
         return unflatten_from_blocks(upd, meta), new_state
 
     return optax.GradientTransformation(init_fn, update_fn)
-
-
-# ---------------------------------------------------------------------------
-# dispatch-count gate
-# ---------------------------------------------------------------------------
-
-
-def _count_eqns(jaxpr, prim_name: str) -> int:
-    total = 0
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == prim_name:
-            total += 1
-        for val in eqn.params.values():
-            for sub in _sub_jaxprs(val):
-                total += _count_eqns(sub, prim_name)
-    return total
-
-
-def _sub_jaxprs(val):
-    if hasattr(val, "jaxpr"):
-        yield val.jaxpr
-    elif hasattr(val, "eqns"):
-        yield val
-    elif isinstance(val, (tuple, list)):
-        for v in val:
-            yield from _sub_jaxprs(v)
-
-
-def pallas_call_count(fn, *args, **kwargs) -> int:
-    """Number of ``pallas_call`` dispatches in ``fn``'s trace — the
-    fused-step gate: the count must stay bounded (no per-leaf tail),
-    asserted in tests and published by bench as
-    ``opt_fused_dispatches``."""
-    jaxpr = jax.make_jaxpr(fn)(*args, **kwargs)
-    return _count_eqns(jaxpr.jaxpr, "pallas_call")

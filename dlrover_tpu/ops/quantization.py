@@ -189,7 +189,8 @@ def dequantize_int8(q, scales, orig_shape, dtype=jnp.float32,
 #
 # Measured on v5e (DESIGN.md "Low-precision compute"): int8
 # dot_general with int32 accumulation DOES hit the MXU's 2x int8
-# throughput — at the bench model's GEMM shapes the full quantized dot
+# throughput — at the ``llama2-1b`` preset's GEMM shapes the full
+# quantized dot
 # (on-the-fly per-channel quantization included) runs 1.4-2.7x faster
 # than the bf16 dot. The earlier "int8 is slower" conclusion measured
 # a training step that lost the einsum-form flash path (transposes +
@@ -354,7 +355,7 @@ def _scale_to_out(s, sub, out_sub):
     be a dot_general over the size-1 contracted axes, which remat
     policies then dutifully SAVE as a full [out]-shaped f32 buffer per
     scan iteration (measured: 3 GB of stacked broadcast scale products
-    at the bench model)."""
+    at the ``llama2-1b`` preset)."""
     keep = [(ch, d) for ch, d in zip(sub, s.shape) if ch in out_sub]
     s = s.reshape([d for _ch, d in keep])
     order = sorted(range(len(keep)), key=lambda i: out_sub.index(keep[i][0]))

@@ -471,8 +471,8 @@ class TieredKvEmbedding(KvEmbedding):
     set outruns it) — a demotion is a row-block copy into the array, a
     promotion a row-block copy out, never a per-row dict operation.
     ``counters`` tracks prepare_batch traffic (``vectorized_batches``,
-    ``demoted_rows``, ``promoted_rows``, ``fresh_rows``) so benches and
-    the CI perf smoke can assert the vectorized path actually ran.
+    ``demoted_rows``, ``promoted_rows``, ``fresh_rows``) so a test can
+    assert the vectorized path actually ran.
     """
 
     def __init__(self, dim: int, capacity: int = 1 << 16,

@@ -27,9 +27,7 @@ from dlrover_tpu.parallel.strategy import Strategy, auto_strategy
 
 logger = get_logger(__name__)
 
-# default relative loss tolerance for selecting a quantized dtype —
-# shared with bench.py so the published selection measures the policy
-# the product ships
+# default relative loss tolerance for selecting a quantized dtype
 LOSS_PARITY_TOL = 0.05
 
 

@@ -1163,7 +1163,7 @@ def quant_aware_policy(policy):
     1. NEVER save integer dot_generals: the qa @ qb accumulators are
        int32 [*, out]-shaped — the dots_* policies would save them
        stacked per scan layer (measured: 5.5 GB for the gate/up
-       accumulator alone at the bench model, the difference between
+       accumulator alone at the ``llama2-1b`` preset, the difference between
        fitting HBM and OOM). The backward never consumes the
        accumulator (the custom_vjp residuals are the small int8
        operands), so nothing is recomputed from excluding it.

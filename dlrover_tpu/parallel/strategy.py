@@ -57,8 +57,8 @@ class Strategy:
     # latency-hiding scheduler; "manual" = same schedule with the
     # gathers decomposed into ppermute rings (ops/collectives.py) the
     # scheduler can interleave step-by-step. Like int8, the product
-    # default comes from measured selection (bench/engine), not from
-    # hardcoding "on".
+    # default comes from measured selection (parallel/engine.py's
+    # dry-runner), not from hardcoding "on".
     overlap_collectives: str = "off"
     # which qdot/qeinsum call sites quantize under compute_dtype=
     # "int8"/"fp8": "all", or a comma-separated subset of the site

@@ -1,12 +1,12 @@
 """Poisson load generator + latency summarizer for the serving arm.
 
-The bench story ("millions of users", scaled down to a harness): open-
+"Millions of users", scaled down to a harness: open-
 loop Poisson arrivals at a configured rate — arrival times are drawn
 once from a seeded RNG, so a sweep replays identically across
 comparison arms (chaos-killed worker vs clean) — submitted through any
 ``submit(payload) -> bool`` door (the master RPC arm, or the manager
 directly in-process). :func:`summarize` turns the finished-request
-records into the headline keys ``tools/bench_diff.py`` gates:
+records into the keys the serve-kill chaos schedule prints:
 
 - ``serve_tokens_per_s``  — generated tokens per wall second;
 - ``serve_ttft_p50_ms`` / ``serve_ttft_p99_ms`` — time-to-first-token
@@ -29,7 +29,7 @@ logger = get_logger(__name__)
 
 
 # the one nearest-rank definition, shared with the SLO watchdog so the
-# bench keys and the gate can never drift
+# TTFT keys and the gate can never drift
 percentile = telemetry.nearest_rank_percentile
 
 

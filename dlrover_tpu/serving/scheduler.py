@@ -111,7 +111,7 @@ class ContinuousBatchingScheduler:
     ):
         self._engine = engine
         # a worker-owned registry keeps per-worker sources; None falls
-        # back to the process-global one (standalone/bench use)
+        # back to the process-global one (standalone use)
         self._registry = registry
         # rides the TTFT/token histograms as a label, so the rollup
         # view (/metrics merges histograms across sources) still keeps

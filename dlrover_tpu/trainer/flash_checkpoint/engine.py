@@ -176,7 +176,9 @@ def _restore_threads() -> int:
 # from reader threads as each leaf's host bytes become ready (transfers
 # overlap the remaining disk reads because dispatch is async); the lock
 # keeps the dispatch call itself single-threaded for runtimes that do
-# not like concurrent device_put entry.
+# not like concurrent device_put entry. device_put under it only
+# DISPATCHES (it returns before the transfer completes); the blocking
+# wait is the restore's one barrier, outside the lock.
 _H2D_DISPATCH_LOCK = threading.Lock()
 
 
@@ -203,34 +205,6 @@ def _publish_restore_stats(stats: dict):
         telemetry.event(
             "ckpt.restore.h2d", dur=h2d, mb=nbytes / 1e6
         )
-
-
-def pipelined_device_put(tree, stats: dict | None = None):
-    """Host pytree -> device, per-leaf: every leaf's transfer is
-    dispatched before any is waited on (async dispatch overlaps the
-    transfers), then one barrier at the end. Emits the ``ckpt.restore.h2d`` interval so
-    the blocking leg lands in the goodput ledger's checkpoint bucket."""
-    import jax
-
-    t0 = time.perf_counter()
-    leaves, treedef = jax.tree_util.tree_flatten(tree)
-    out = [None] * len(leaves)
-    for i, leaf in enumerate(leaves):
-        # dlint: allow-blocking(device_put only DISPATCHES here — it returns before the transfer completes; serializing dispatch is exactly what this lock is for, the blocking wait is the single barrier below)
-        with _H2D_DISPATCH_LOCK:
-            out[i] = jax.device_put(leaf)
-    jax.block_until_ready(out)
-    h2d_s = time.perf_counter() - t0
-    nbytes = sum(
-        int(np.prod(np.shape(x), dtype=np.int64))
-        * np.dtype(getattr(x, "dtype", np.float32)).itemsize
-        for x in leaves
-    )
-    s = {"h2d_s": h2d_s, "bytes": nbytes}
-    if stats is not None:
-        stats.update(s)
-    _publish_restore_stats(s)
-    return jax.tree_util.tree_unflatten(treedef, out)
 
 
 class CheckpointEngine:
@@ -320,8 +294,9 @@ class CheckpointEngine:
             self._done_queue = SharedQueue(
                 persist_done_queue_name(local_rank), create=False
             )
-        # staged-pipeline observability: the bench and telemetry read
-        # the last save/restore's per-leg breakdown from these
+        # staged-pipeline observability: the last save's / restore's
+        # per-leg breakdown, for a caller that times a save (the
+        # benchmark does); telemetry publishes the same legs
         self.last_save_stats: dict = {}
         self.last_restore_stats: dict = {}
 
@@ -1276,13 +1251,13 @@ class CheckpointEngine:
                 if isinstance(leaf_t, jax.Array) and hasattr(
                     leaf_t, "sharding"
                 ):
-                    # dlint: allow-blocking(async dispatch only — see pipelined_device_put)
+                    # dlint: allow-blocking(async dispatch only — see _H2D_DISPATCH_LOCK)
                     with _H2D_DISPATCH_LOCK:
                         host = jax.device_put(host, leaf_t.sharding)
                 elif isinstance(leaf_t, jax.ShapeDtypeStruct):
                     sharding = getattr(leaf_t, "sharding", None)
                     if sharding is not None:
-                        # dlint: allow-blocking(async dispatch only — see pipelined_device_put)
+                        # dlint: allow-blocking(async dispatch only — see _H2D_DISPATCH_LOCK)
                         with _H2D_DISPATCH_LOCK:
                             host = jax.device_put(host, sharding)
                     else:
@@ -1477,7 +1452,7 @@ def _restore_leaf_to_sharding(pieces, leaf_target, read_box=None):
         # async dispatch under the lock: the transfer itself overlaps
         # the next shard's read (and other leaves' reads — this runs on
         # the restore pool's worker threads)
-        # dlint: allow-blocking(async dispatch only — see pipelined_device_put)
+        # dlint: allow-blocking(async dispatch only — see _H2D_DISPATCH_LOCK)
         with _H2D_DISPATCH_LOCK:
             shard_arrays.append(jax.device_put(out, dev))
     with _H2D_DISPATCH_LOCK:

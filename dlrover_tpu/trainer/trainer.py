@@ -65,15 +65,9 @@ class TrainingArgs:
     # logging/eval
     log_steps: int = 10
     eval_steps: int = 0
-    # profiling: capture an XPlane trace of steps
-    # [profile_start_step, +profile_num_steps) into output_dir/profile
-    profile: bool = False
-    profile_start_step: int = 10
-    profile_num_steps: int = 3
-    # model FLOPs per TOKEN for the live ``train.mfu`` gauge. 0 = the
-    # dense estimate 6 * param_count; transformer callers pass the
-    # exact value (common/mfu.transformer_step_flops(...) / tokens) so
-    # the live gauge and bench's offline mfu_pct agree by construction
+    # the caller's count of model FLOPs per TOKEN for the live
+    # ``train.mfu`` gauge; 0 = common/mfu.py's dense estimate,
+    # 6 * param_count
     model_flops_per_token: float = 0.0
 
 
@@ -237,19 +231,6 @@ class Trainer:
             self._timer = get_step_timer()
         except Exception:  # noqa: BLE001 - shm unavailable (bare env)
             pass
-        self._profiler = None
-        if args.profile:
-            from dlrover_tpu.trainer.profiler import StepProfiler
-
-            self._profiler = StepProfiler(
-                os.path.join(args.output_dir, "profile"),
-                start_step=args.profile_start_step,
-                num_steps=args.profile_num_steps,
-                # publish top-op self times where the agent's /metrics
-                # endpoint serves them (dlrtpu_kernel_self_ms) — the
-                # online per-kernel attribution, not just trace files
-                publish_top_ops=True,
-            )
         # always-on device-time accounting + deep-capture execution
         # (common/profiling.py): one sampled step every
         # DLROVER_PROF_SAMPLE_STEPS becomes device.optime_ms gauges +
@@ -465,8 +446,6 @@ class Trainer:
                             self._timer.record(
                                 Tag.DATA_WAIT, t_wait, wait_ns
                             )
-                        if self._profiler is not None:
-                            self._profiler.maybe_start(self.global_step)
                         self._prof.on_step_start(self.global_step)
                     t_enter = time.time_ns()
                     with tracing.span(
@@ -483,10 +462,6 @@ class Trainer:
                             self.state, batch, rng
                         )
                         self.global_step += 1
-                        if self._profiler is not None:
-                            self._profiler.maybe_stop(
-                                self.global_step - 1, block_on=metrics
-                            )
                     if self._tokens_per_step is None:
                         self._tokens_per_step = self._batch_tokens(batch)
                     self._pending.append((
@@ -726,9 +701,9 @@ class Trainer:
     def _refresh_flops(self):
         """Model FLOPs per token and the mesh's peak FLOP/s, computed
         once per (re)shape — never in the step loop. Explicit
-        ``model_flops_per_token`` wins (transformers pass the exact
-        attention-inclusive value via common/mfu); the fallback is the
-        dense 6 * params estimate."""
+        ``model_flops_per_token`` wins (a caller that knows its
+        attention term passes it); the fallback is the dense
+        6 * params estimate."""
         from dlrover_tpu.common import mfu
 
         devices = self._accel.mesh.devices
@@ -1442,8 +1417,6 @@ class Trainer:
 
     def close(self):
         self._pending.clear()
-        if self._profiler is not None:
-            self._profiler.close()
         self._prof.close()
         if self._engine is not None:
             self._engine.close()

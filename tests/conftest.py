@@ -16,8 +16,7 @@ if "xla_force_host_platform_device_count" not in flags:
 # buys nothing here but dominates the suite's wall clock on CPU (compile
 # >> execute for every jitted step). -O0 keeps numerics deterministic
 # per-compilation, so bit-exactness assertions between two functions
-# compiled in the same process still hold. bench.py does NOT import this
-# file and measures at full optimization.
+# compiled in the same process still hold.
 if "xla_backend_optimization_level" not in flags:
     flags = (flags + " --xla_backend_optimization_level=0").strip()
 os.environ["XLA_FLAGS"] = flags
@@ -36,6 +35,29 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from dlrover_tpu.common.rpc import find_free_port  # noqa: E402
 from dlrover_tpu.master.master import LocalJobMaster  # noqa: E402
 from dlrover_tpu.scheduler.job import new_job_args  # noqa: E402
+
+
+def count_eqns(jaxpr, prim_names) -> int:
+    """Equations of ``jaxpr`` (a ``Jaxpr``), sub-jaxprs included,
+    whose primitive is one of ``prim_names``."""
+    total = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in prim_names:
+            total += 1
+        for val in eqn.params.values():
+            for sub in _subjaxprs(val):
+                total += count_eqns(sub, prim_names)
+    return total
+
+
+def _subjaxprs(val):
+    if hasattr(val, "jaxpr"):  # ClosedJaxpr
+        yield val.jaxpr
+    elif hasattr(val, "eqns"):  # Jaxpr
+        yield val
+    elif isinstance(val, (tuple, list)):
+        for v in val:
+            yield from _subjaxprs(v)
 
 
 def start_local_master(node_num: int = 1):

@@ -528,7 +528,7 @@ def test_schedule_bad_host_gate_drain_readmit(
     standing verdict survives a master failover verbatim, and the
     recovered host re-admits once its backoff re-probe comes back
     clean. Also publishes the probe_join_overhead_s /
-    bad_host_quarantine_s bench keys and asserts the < 5 s join
+    bad_host_quarantine_s keys and asserts the < 5 s join
     budget."""
     from tools.chaos_run import _run_bad_host
 

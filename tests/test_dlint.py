@@ -1304,8 +1304,7 @@ class TestRepoGate:
         t0 = time.monotonic()
         findings = run_checks(
             [os.path.join(REPO_ROOT, "dlrover_tpu"),
-             os.path.join(REPO_ROOT, "tools"),
-             os.path.join(REPO_ROOT, "bench.py")],
+             os.path.join(REPO_ROOT, "tools")],
             repo_root=REPO_ROOT,
         )
         elapsed = time.monotonic() - t0
@@ -1328,8 +1327,7 @@ class TestRepoGate:
         finding — stale entries mean fixed code, prune them."""
         findings = run_checks(
             [os.path.join(REPO_ROOT, "dlrover_tpu"),
-             os.path.join(REPO_ROOT, "tools"),
-             os.path.join(REPO_ROOT, "bench.py")],
+             os.path.join(REPO_ROOT, "tools")],
             repo_root=REPO_ROOT,
         )
         bl = Baseline.load(

@@ -338,7 +338,7 @@ class TestReviewFixes:
 class TestAsyncSave:
     def test_async_save_matches_sync(self, tmp_path):
         """save_to_memory_async must produce the same restorable state
-        as the blocking save (the bench's headline path)."""
+        as the blocking save."""
         engine = ReplicatedCheckpointEngine(str(tmp_path / "ckpt"))
         state = make_state(seed=5)
         assert engine.save_to_memory_async(11, state)

@@ -7,18 +7,15 @@ cheap). This e2e reproduces the scenario on the local agent stack:
 1. a worker trains with per-step flash checkpoints into shm,
 2. it is KILLED mid-run (injected preemption, no cleanup),
 3. the agent restarts it; the new incarnation resumes from shm,
-4. goodput is computed the way bench.py computes it — useful time over
-   useful time plus the measured loss — where the loss per preemption
-   is (restart latency + replayed work), amortized at the reference's
-   production preemption cadence.
+4. goodput is useful time over useful time plus the measured loss,
+   where the loss per preemption is (restart latency + replayed
+   work), amortized at the reference's production preemption cadence.
 
-Emits a JSON artifact (GOODPUT_PREEMPTION.json next to the test's tmp
-dir; also to the repo root when DLRTPU_WRITE_ARTIFACTS=1) and asserts
-goodput >= 95%.
+Asserts goodput >= 95% (a CPU rehearsal of the control flow: the
+seconds are this host's, not a chip's).
 """
 
 import json
-import os
 import time
 
 from dlrover_tpu.agent.master_client import MasterClient
@@ -111,34 +108,19 @@ def test_goodput_under_one_preemption(local_master, tmp_path, monkeypatch,
     restart_gap = steps[crash_at + 1]["t"] - steps[crash_at]["t"]
     lost_s = max(restart_gap - step_s, 0.0)
     replayed = max(crash_at - result["resumed_from"], 0) * step_s
-    # goodput at the production preemption cadence, computed the way
-    # bench.py amortizes the checkpoint pause over its interval
+    # goodput at the production preemption cadence
     goodput = PREEMPTION_PERIOD_S / (
         PREEMPTION_PERIOD_S + lost_s + replayed)
 
-    artifact = {
-        "metric": "goodput_under_preemption",
-        "value": round(goodput * 100, 3),
-        "unit": "%",
-        "vs_baseline": round(goodput / 0.95, 4),
-        "detail": {
-            "restart_latency_s": round(lost_s, 3),
-            "replayed_work_s": round(replayed, 3),
-            "preemption_period_s": PREEMPTION_PERIOD_S,
-            "resumed_from_step": result["resumed_from"],
-            "crash_at_step": crash_at,
-            "total_wall_s": round(wall, 3),
-            "recovery": "shm flash checkpoint (zero replay)",
-        },
+    detail = {
+        "goodput_pct": round(goodput * 100, 3),
+        "restart_latency_s": round(lost_s, 3),
+        "replayed_work_s": round(replayed, 3),
+        "resumed_from_step": result["resumed_from"],
+        "crash_at_step": crash_at,
+        "total_wall_s": round(wall, 3),
     }
-    (tmp_path / "GOODPUT_PREEMPTION.json").write_text(
-        json.dumps(artifact, indent=2))
-    if os.environ.get("DLRTPU_WRITE_ARTIFACTS") == "1":
-        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        with open(os.path.join(repo, "GOODPUT_PREEMPTION.json"), "w") as f:
-            json.dump(artifact, f, indent=2)
-
-    assert goodput >= 0.95, artifact
+    assert goodput >= 0.95, detail
     # the restart must be seconds, not minutes (the reference's 69%
     # baseline loses ~10 min/event)
-    assert lost_s < 60.0, artifact
+    assert lost_s < 60.0, detail

@@ -187,6 +187,20 @@ class TestGateMatrix:
         out = mgr.gate(3, _report(12.0, 12.0, 12.0), now=0.0)
         assert out["verdict"] == "pass"
 
+    def test_a_harness_may_widen_the_slack_of_its_own_gate(self):
+        # what the bad-host chaos harness does (tools/chaos_run.py):
+        # 35 -> 75 ms is a neighbour's load on a shared CPU there, and
+        # its injected degradation (+400 ms a leg) still parks a host
+        mgr = _seed_fleet(_mgr(), ms=35.0)
+        assert mgr.gate(3, _report(75.0, 35.0, 35.0), now=0.0)[
+            "verdict"] == "quarantine"
+        wide = _seed_fleet(_mgr(), ms=35.0)
+        wide._slack_ms = 200.0
+        assert wide.gate(3, _report(75.0, 35.0, 35.0), now=0.0)[
+            "verdict"] == "pass"
+        assert wide.gate(4, _report(435.0, 35.0, 35.0), now=0.0)[
+            "verdict"] == "refuse"
+
     def test_severe_degradation_refused_with_longer_backoff(self):
         mgr = _seed_fleet(_mgr())
         out = mgr.gate(3, _report(matmul=100.0 * 5 * RATIO), now=0.0)

@@ -9,6 +9,7 @@ import json
 import os
 import subprocess
 import sys
+import time
 
 import jax
 import pytest
@@ -72,7 +73,13 @@ def test_two_node_spmd_via_tpu_run(tmp_path, local_master_2nodes):
         "PYTHONPATH": REPO,
         "JAX_PLATFORMS": "cpu",
         "XLA_FLAGS": "--xla_force_host_platform_device_count=4",
-        "DLROVER_TPU_SOCKET_DIR": str(tmp_path / "socks"),
+        # both "hosts" are this one CPU: their join-time probes race
+        # each other and the neighbouring test workers, and the health
+        # gate has refused node 1 for 120 s and then 240 s at "matmul
+        # 155.6x fleet baseline", past this test's deadline. What is
+        # under test is the two-process SPMD job, not the gate
+        # (tests/test_health.py, the bad-host chaos schedule).
+        "DLROVER_PROBE_DISABLE": "1",
     }
 
     procs = []
@@ -81,6 +88,10 @@ def test_two_node_spmd_via_tpu_run(tmp_path, local_master_2nodes):
         for rank in range(2):
             env = dict(env_base)
             env["ELASTIC_JOB_NAME"] = f"mh{os.getpid()}r{rank}"
+            # a host has one agent: two that share a socket directory
+            # race to bind the saver's factory socket, and the loser
+            # dies with EADDRINUSE while the winner waits for it
+            env["DLROVER_TPU_SOCKET_DIR"] = str(tmp_path / f"socks{rank}")
             # log files, not PIPEs: two children drained sequentially
             # could deadlock on a full pipe mid-collective
             log = open(tmp_path / f"agent{rank}.log", "wb")
@@ -94,8 +105,14 @@ def test_two_node_spmd_via_tpu_run(tmp_path, local_master_2nodes):
                 env=env, cwd=REPO,
                 stdout=log, stderr=subprocess.STDOUT,
             ))
-        for p in procs:
-            p.wait(timeout=240)
+        # until both are done or one has failed: a node that dies
+        # leaves the other waiting for it, and its log says why
+        deadline = time.monotonic() + 240
+        while time.monotonic() < deadline:
+            codes = [p.poll() for p in procs]
+            if None not in codes or any(codes):
+                break
+            time.sleep(0.2)
     finally:
         for p in procs:
             if p.poll() is None:

@@ -33,7 +33,7 @@ def fresh_telemetry(monkeypatch):
     telemetry._REGISTRY = prev
 
 
-def wait_until(cond, timeout=10.0, poll=0.02):
+def wait_until(cond, timeout=60.0, poll=0.02):
     deadline = time.time() + timeout
     while time.time() < deadline:
         if cond():
@@ -127,11 +127,6 @@ class TestTraceSummary:
 
     def test_summarize_none_without_traces(self, tmp_path):
         assert trace_summary.summarize(str(tmp_path)) is None
-
-    def test_top_ops_empty_without_traces(self, tmp_path):
-        from dlrover_tpu.trainer.profiler import top_ops_from_trace
-
-        assert top_ops_from_trace(str(tmp_path)) == []
 
     def test_parse_profile_cli_missing_dir(self, tmp_path, capsys):
         from tools.parse_profile import main

@@ -14,34 +14,13 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from dlrover_tpu.models import PRESETS, llama_init, llama_loss_fn
 from dlrover_tpu.ops.fp8 import no_remat_autocast, quant_autocast
+from tests.conftest import count_eqns
 
 CHECKPOINT_PRIMS = ("remat2", "checkpoint")
 NAME_PRIMS = ("name",)
-
-
-def _count_eqns(jaxpr, prim_names) -> int:
-    total = 0
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name in prim_names:
-            total += 1
-        for val in eqn.params.values():
-            for sub in _subjaxprs(val):
-                total += _count_eqns(sub, prim_names)
-    return total
-
-
-def _subjaxprs(val):
-    if hasattr(val, "jaxpr"):  # ClosedJaxpr
-        yield val.jaxpr
-    elif hasattr(val, "eqns"):  # Jaxpr
-        yield val
-    elif isinstance(val, (tuple, list)):
-        for v in val:
-            yield from _subjaxprs(v)
 
 
 def _traced_loss(cfg, ctx_factories):
@@ -70,21 +49,21 @@ class TestNoRematGate:
 
     def test_model_checkpoint_stripped_under_gate(self):
         cfg = self._cfg(remat=True, ce_chunks=1)
-        before = _count_eqns(_traced_loss(cfg, []), CHECKPOINT_PRIMS)
+        before = count_eqns(_traced_loss(cfg, []), CHECKPOINT_PRIMS)
         assert before >= 1  # config.remat=True checkpoints the scan body
-        after = _count_eqns(
+        after = count_eqns(
             _traced_loss(cfg, [no_remat_autocast]), CHECKPOINT_PRIMS
         )
         assert after == 0
 
     def test_qdot_residual_tags_stripped_under_gate(self):
         cfg = self._cfg(remat=True, ce_chunks=1)
-        tagged = _count_eqns(
+        tagged = count_eqns(
             _traced_loss(cfg, [lambda: quant_autocast("int8")]),
             NAME_PRIMS,
         )
         assert tagged >= 1  # qdot_out/qdot_res tags for the save policy
-        untagged = _count_eqns(
+        untagged = count_eqns(
             _traced_loss(
                 cfg,
                 [lambda: quant_autocast("int8"), no_remat_autocast],
@@ -101,12 +80,12 @@ class TestNoRematGate:
         arm). Gate off or on, the chunked-CE loss must carry zero
         checkpoint primitives."""
         cfg = self._cfg(remat=False, ce_chunks=2)
-        n = _count_eqns(
+        n = count_eqns(
             _traced_loss(cfg, [no_remat_autocast]), CHECKPOINT_PRIMS
         )
         assert n == 0
         # without the gate too: the custom-vjp recompute needs no remat
-        n_plain = _count_eqns(_traced_loss(cfg, []), CHECKPOINT_PRIMS)
+        n_plain = count_eqns(_traced_loss(cfg, []), CHECKPOINT_PRIMS)
         assert n_plain == 0
 
     def test_ce_legacy_norm_fn_path_keeps_checkpoint(self):
@@ -130,7 +109,7 @@ class TestNoRematGate:
             return ls
 
         jaxpr = jax.make_jaxpr(jax.grad(run))(h).jaxpr
-        assert _count_eqns(jaxpr, CHECKPOINT_PRIMS) == 1
+        assert count_eqns(jaxpr, CHECKPOINT_PRIMS) == 1
 
     def test_strategy_none_sets_gate_in_accelerate(self):
         """End-to-end: auto_accelerate with remat='none' produces a step
@@ -163,42 +142,3 @@ class TestNoRematGate:
             res.state, {"tokens": tokens}, jax.random.key(0)
         )
         assert np.isfinite(float(m["loss"]))
-
-
-class TestProfilerForbidOps:
-    def test_assert_ops_absent_raises_on_match(self, tmp_path,
-                                               monkeypatch):
-        from dlrover_tpu.trainer import profiler as prof_mod
-
-        monkeypatch.setattr(
-            prof_mod, "top_ops_from_trace",
-            lambda log_dir, k=15, steps=1: [
-                {"op": "fusion.1", "category": "fusion",
-                 "self_ms_per_step": 1.0},
-                {"op": "checkpoint.10", "category": "custom-call",
-                 "self_ms_per_step": 25.7},
-            ],
-        )
-        p = prof_mod.StepProfiler(str(tmp_path))
-        with pytest.raises(AssertionError, match="checkpoint.10"):
-            p.assert_ops_absent(("checkpoint",))
-        p.assert_ops_absent(("somethingelse",))
-
-    def test_forbid_ops_checked_at_window_stop(self, tmp_path,
-                                               monkeypatch):
-        from dlrover_tpu.trainer import profiler as prof_mod
-
-        monkeypatch.setattr(
-            prof_mod, "top_ops_from_trace",
-            lambda log_dir, k=15, steps=1: [
-                {"op": "checkpoint.3", "category": "custom-call",
-                 "self_ms_per_step": 1.0},
-            ],
-        )
-        p = prof_mod.StepProfiler(
-            str(tmp_path), start_step=0, num_steps=1,
-            forbid_ops=("checkpoint",),
-        )
-        p.maybe_start(0)
-        with pytest.raises(AssertionError):
-            p.maybe_stop(0)

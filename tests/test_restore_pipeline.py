@@ -27,7 +27,6 @@ from dlrover_tpu.common.storage import PosixDiskStorage
 from dlrover_tpu.trainer.flash_checkpoint.engine import (
     ReplicatedCheckpointEngine,
     ShardedCheckpointEngine,
-    pipelined_device_put,
 )
 
 
@@ -179,15 +178,6 @@ class TestPipelinedBitExact:
             )
         finally:
             engine.close()
-
-    def test_pipelined_device_put_roundtrip(self):
-        tree = {
-            "a": np.arange(64, dtype=np.float32).reshape(8, 8),
-            "b": np.ones((3,), np.int32),
-        }
-        out = pipelined_device_put(tree)
-        assert np.array_equal(np.asarray(out["a"]), tree["a"])
-        assert np.array_equal(np.asarray(out["b"]), tree["b"])
 
 
 class TestChunkGranularIntegrity:

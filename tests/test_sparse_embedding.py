@@ -528,7 +528,7 @@ class TestTieredPerfSmoke:
             c0["vectorized_batches"] == 3
         assert kv.counters["demoted_rows"] > c0["demoted_rows"]
         # 3 vectorized calls run in ~0.1 s on CPU; the old per-id path
-        # took seconds at this size (bench: 0.012 Mrows/s)
+        # took seconds at this size
         assert wall < 1.5, f"prepare_batch too slow: {wall:.2f}s"
 
 

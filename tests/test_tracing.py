@@ -1018,17 +1018,6 @@ class TestReportSurfaces:
         assert control["joins_per_sec"] >= 0
         assert "rpc_get_p99_ms" in control or "rpc_report_p99_ms" in control
 
-    def test_bench_control_plane_keys(self):
-        """The bench arm publishes the baseline keys; kept tiny (2
-        agents, ~0.3 s) so tier-1 stays fast."""
-        import bench
-
-        out = bench._control_plane_bench(n_agents=2, seconds=0.3)
-        assert out.get("control_plane_errors") == 0, out
-        assert out["master_rpc_p99_ms"] > 0
-        assert out["joins_per_sec"] > 0
-        assert out["master_rpc_calls"] > 0
-
 
 # -------------------------------------------------------------------------
 # spans on the profiler's clock

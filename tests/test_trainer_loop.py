@@ -298,29 +298,3 @@ class TestTrainerDataStateResume:
         assert loader2.sampler.epoch == 0
         assert loader2.sampler.completed_num == 40
         t2.close()
-
-
-class TestProfiler:
-    def test_step_window_produces_trace(self, tmp_path):
-        loss_fn, init_fn, axes, batches = linear_problem()
-        trainer = Trainer(
-            loss_fn, init_fn, axes,
-            make_args(tmp_path, max_steps=6, profile=True,
-                      profile_start_step=2, profile_num_steps=2),
-            train_data=batches(),
-        )
-        trainer.train()
-        trainer.close()
-        prof_dir = tmp_path / "out" / "profile"
-        assert prof_dir.is_dir()
-        traces = list(prof_dir.rglob("*.xplane.pb"))
-        assert traces, "no xplane trace produced"
-
-    def test_one_shot_trace(self, tmp_path):
-        import jax.numpy as jnp
-
-        from dlrover_tpu.trainer.profiler import trace
-
-        with trace(str(tmp_path / "t")):
-            _ = jnp.ones((8, 8)) @ jnp.ones((8, 8))
-        assert list((tmp_path / "t").rglob("*.xplane.pb"))

@@ -1,9 +1,9 @@
 """DL007 metric-name drift.
 
 Invariant: every metric/gauge/counter/event name the operator surfaces
-QUERY (``tools/obs_report.py`` summaries, ``bench.py`` key extraction)
-must actually be EMITTED somewhere in the package. The emit and query
-sides are plain string literals with no shared constant, so a renamed
+QUERY (``tools/obs_report.py`` summaries) must actually be EMITTED
+somewhere in the package. The emit and query sides are plain string
+literals with no shared constant, so a renamed
 gauge (``ckpt.restore.read_gbps`` -> ``ckpt.read_gbps``) silently
 turns the consumer's section empty — the report keeps "working" while
 the number the ROADMAP tracks quietly disappears. This is the DL006
@@ -36,7 +36,7 @@ _EMIT_FUNCS = {"counter_inc", "gauge_set", "observe", "event"}
 
 # consumer seams: the operator-facing summaries whose queried names
 # must stay live (relpath suffix match, forward slashes)
-_CONSUMER_SUFFIXES = ("tools/obs_report.py", "bench.py")
+_CONSUMER_SUFFIXES = ("tools/obs_report.py",)
 
 
 def _is_consumer(relpath: str) -> bool:

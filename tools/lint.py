@@ -33,9 +33,7 @@ if _REPO_ROOT not in sys.path:
 
 from tools.dlint import Baseline, run_checks  # noqa: E402
 
-# bench.py rides along for DL007: it is a metric-name CONSUMER (its
-# summaries query telemetry names), and drift checks need both sides
-DEFAULT_PATHS = ("dlrover_tpu", "tools", "bench.py")
+DEFAULT_PATHS = ("dlrover_tpu", "tools")
 BASELINE_PATH = os.path.join(_REPO_ROOT, "tools", "dlint", "baseline.json")
 
 # --checker accepts either form: the stable code or the checker name

@@ -13,15 +13,15 @@ Usage:
     # shard dispatches — parent/child nesting across processes)
     python tools/obs_report.py --dir ... --trace
 
-    # embed the XPlane per-category breakdown when a trace exists
-    python tools/obs_report.py --dir ... --trace-dir out/profile --steps 3
+    # embed the XPlane per-category breakdown of a capture's trace
+    python tools/obs_report.py --dir ... --trace-dir <artifact>/trace --steps 2
 
     # live view: poll a running master every 2 s — compact goodput /
     # step-time / MFU tiles with text sparklines from the master's
     # tiered metrics store, breaches and recent events underneath
     python tools/obs_report.py --master 127.0.0.1:12345 --live
 
-    # machine-readable (the bench embeds this)
+    # machine-readable
     python tools/obs_report.py --dir ... --json
 """
 
@@ -84,7 +84,7 @@ def build_report(
     report["health"] = _health_summary(report.get("timeline", []))
     if trace_dir:
         try:
-            from tools.parse_profile import summarize
+            from dlrover_tpu.common.trace_summary import summarize
 
             report["profile"] = summarize(trace_dir, steps=steps)
         except ImportError as e:
@@ -201,7 +201,7 @@ def _serving_summary(metrics: dict, ledger: dict) -> dict:
     (queue depth, requests by state, per-worker TTFT), merged TTFT
     percentiles from the ``serve.ttft.seconds`` histograms, and the
     throughput headline (``serve_tokens_per_s``) — the offline twin of
-    the dashboard's serving panel and the bench sweep's key source."""
+    the dashboard's serving panel."""
     from dlrover_tpu.common.telemetry import (
         hist_quantile,
         sum_bucket_counts,

@@ -6,9 +6,7 @@ Usage:
 
 A thin CLI over the ONE shared trace walker
 (``dlrover_tpu/common/trace_summary.py``), which the deep-profiling
-sampler and ``trainer/profiler.py`` consume too. ``summarize`` stays
-importable from here (``tools/obs_report.py`` embeds the per-category
-step breakdown next to the goodput ledger when a trace exists).
+sampler consumes too.
 
 Exit codes: 0 parsed, 1 no traces under the directory, 2 the xprof
 toolchain is unavailable or the trace would not parse — always a clear
@@ -30,8 +28,6 @@ from dlrover_tpu.common.trace_summary import (  # noqa: E402
     render,
     summarize,
 )
-
-__all__ = ["summarize", "render", "main"]
 
 
 def main(argv=None) -> int:
