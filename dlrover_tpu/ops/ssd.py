@@ -16,22 +16,26 @@ enters a chunk adds ``exp(cum_i) C_i H_in`` to its outputs. The work is
 matmuls over chunk-sized blocks, which is what the MXU wants, where the
 recurrence itself is S dependent steps.
 
-The scan is one implementation, plain ``jax.numpy`` einsums
-differentiated by JAX, the same on the CPU and on the chip. ``dt``,
-``A``, the running sums and every ``exp`` stay in float32; the
-chunk-sized matmul operands take the activations' dtype (bf16 in a bf16
-model). The chunk-to-chunk pass is float32 at full precision: it is a
+In either form of the scan ``dt``, ``A``, the running sums and every
+``exp`` stay in float32; the chunk-sized matmul operands take the
+activations' dtype (bf16 in a bf16 model); the state that goes from
+chunk to chunk is float32, decayed in float32: that pass is a
 thousandth of the work and carries the state across the whole sequence.
 
-The convolution with its activation, :func:`causal_conv_silu`, is one
-algorithm in two forms, chosen by what the input shows and by nothing
-else: the Pallas kernel pair of ``ops/causal_conv.py`` where its blocks
-tile the input (sequence a multiple of 128, channels and the first of
-them a multiple of 16) and the mesh does not split the sequence;
-everywhere else (the short sequences and toy widths of the CPU tests,
-a ``seq`` mesh axis: a halo across sequence shards is not built) the
-plain form, ``silu(causal_conv1d(...))``, which is also the kernels'
-reference.
+The scan, :func:`ssd_scan`, and the convolution with its activation,
+:func:`causal_conv_silu`, are each one algorithm in two forms, chosen
+by what the input shows and by nothing else: a Pallas kernel pair
+(``ops/ssd_kernel.py``, ``ops/causal_conv.py``) where its blocks tile
+the input and the mesh does not split the sequence, mapped over the
+mesh's batch axes; everywhere else (the short sequences and toy widths
+of the CPU tests, a ``seq`` mesh axis: neither a halo nor a state
+handed across sequence shards is built) the plain form, ``jax.numpy``
+differentiated by JAX, which is also the kernels' reference. The scan's
+kernels take a chunk and a state size in multiples of 128, a head size
+in multiples of 16 and each group's heads in eights; the convolution's
+a sequence in multiples of 128, channels and the first of them in
+multiples of 16. The gauges ``model.ssd.impl`` and ``model.conv.impl``
+say which form was traced.
 """
 
 from __future__ import annotations
@@ -44,7 +48,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec
 
 from dlrover_tpu.common import telemetry
-from dlrover_tpu.ops import causal_conv
+from dlrover_tpu.ops import causal_conv, ssd_kernel
 
 
 def causal_conv1d(x, weight, bias):
@@ -71,6 +75,24 @@ def causal_conv_silu(x, weight, bias, first: int = 0):
     ``weight`` [K, C] has. The module's docstring has the rule that
     picks the kernel pair or the plain form; the gauge
     ``model.conv.impl`` says which was traced."""
+    taps, channels = weight.shape
+    mesh, batch_axes = _kernel_mesh(
+        "model.conv.impl", x.shape[0],
+        causal_conv.kernel_takes(x.shape[1], channels, first, taps))
+    if batch_axes is None:
+        x = x[..., first:first + channels]
+        return jax.nn.silu(causal_conv1d(x, weight, bias)).astype(x.dtype)
+    run = functools.partial(causal_conv.causal_conv_silu_kernel, first=first)
+    return _over_batch(run, mesh, batch_axes, "x..")(x, weight, bias)
+
+
+def _kernel_mesh(gauge: str, batch: int, tiled: bool):
+    """Where a kernel pair may run: ``(mesh, its batch axes)`` if the
+    kernels' blocks tile the input (``tiled``), no ``seq`` mesh axis is
+    active and the batch divides over the ``data`` / ``fsdp`` axes;
+    ``(mesh, None)`` for the plain form. Sets ``gauge`` under the label
+    ``impl`` = ``kernel`` or ``plain``: the choice is made at trace
+    time, here."""
     from dlrover_tpu.parallel.mesh import get_mesh
 
     try:
@@ -78,31 +100,30 @@ def causal_conv_silu(x, weight, bias, first: int = 0):
         axes = dict(mesh.shape)
     except RuntimeError:
         mesh, axes = None, {}
-    # pallas_call does not partition itself: on a mesh that splits the
-    # batch the kernels are mapped over those axes
     batch_axes = tuple(a for a in ("data", "fsdp") if axes.get(a, 1) > 1)
-    taps, channels = weight.shape
     kernel = (
-        causal_conv.kernel_takes(x.shape[1], channels, first, taps)
-        and axes.get("seq", 1) == 1
-        and x.shape[0] % math.prod(axes[a] for a in batch_axes) == 0
+        tiled and axes.get("seq", 1) == 1
+        and batch % math.prod(axes[a] for a in batch_axes) == 0
     )
-    telemetry.gauge_set(
-        "model.conv.impl", 1, impl="kernel" if kernel else "plain")
-    if not kernel:
-        x = x[..., first:first + channels]
-        return jax.nn.silu(causal_conv1d(x, weight, bias)).astype(x.dtype)
-    run = functools.partial(causal_conv.causal_conv_silu_kernel, first=first)
+    telemetry.gauge_set(gauge, 1, impl="kernel" if kernel else "plain")
+    return mesh, batch_axes if kernel else None
+
+
+def _over_batch(run, mesh, batch_axes, split: str):
+    """``run`` mapped over the mesh's batch axes: ``pallas_call`` does
+    not partition itself. ``split`` has a letter an operand, ``x`` for
+    one split along its first dimension, as the output is."""
     if not batch_axes:
-        return run(x, weight, bias)
+        return run
     rows = PartitionSpec(batch_axes)
     return jax.shard_map(
         run,
         mesh=mesh,
-        in_specs=(rows, PartitionSpec(), PartitionSpec()),
+        in_specs=tuple(
+            rows if letter == "x" else PartitionSpec() for letter in split),
         out_specs=rows,
         check_vma=False,
-    )(x, weight, bias)
+    )
 
 
 def _decay_between(cum):
@@ -143,59 +164,73 @@ def ssd_scan(x, dt, a, b, c, d, chunk: int):
         raise ValueError(
             f"ssd_scan: {heads} heads do not divide into {groups} groups"
         )
+    mesh, batch_axes = _kernel_mesh(
+        "model.ssd.impl", batch,
+        ssd_kernel.kernel_takes(seq, chunk, heads, groups, head, state))
+    with jax.named_scope("ssd_scan"):
+        if batch_axes is None:
+            return ssd_scan_plain(x, dt, a, b, c, d, chunk)
+        run = functools.partial(ssd_kernel.ssd_scan_kernel, chunk=chunk)
+        return _over_batch(run, mesh, batch_axes, "xx.xx.")(x, dt, a, b, c, d)
+
+
+def ssd_scan_plain(x, dt, a, b, c, d, chunk: int):
+    """:func:`ssd_scan` in plain ``jax.numpy`` einsums, differentiated
+    by JAX: the masked decay and ``C B^T`` are arrays."""
+    batch, seq, heads, head = x.shape
+    groups, state = b.shape[2], b.shape[3]
     n_chunks, per_group = seq // chunk, heads // groups
     dtype = x.dtype
     f32 = jnp.float32
 
-    with jax.named_scope("ssd_scan"):
-        dt = dt.astype(f32)
-        # [B, S, ...] -> [B, chunks, chunk, G, heads a group, ...]
-        xc = x.reshape(batch, n_chunks, chunk, groups, per_group, head)
-        dtc = dt.reshape(batch, n_chunks, chunk, groups, per_group)
-        bc = b.reshape(batch, n_chunks, chunk, groups, state)
-        cc = c.reshape(batch, n_chunks, chunk, groups, state)
-        # log-decay of every step, and its running sum inside the chunk
-        decay = dtc * a.astype(f32).reshape(groups, per_group)
-        decay = decay.transpose(0, 1, 3, 4, 2)          # [B, c, G, h, l]
-        cum = jnp.cumsum(decay, axis=-1)
-        # dt x: what a position adds to the state, per unit of B. Rounded
-        # once to the matmuls' dtype and read twice: kept in float32 for
-        # the end states it is twice the bytes and 2.8 ms of a 514 ms
-        # step at granite-4.0-h-micro's widths (PERF.md, PR 29)
-        xdt = (xc.astype(f32) * dtc[..., None]).astype(dtype)
+    dt = dt.astype(f32)
+    # [B, S, ...] -> [B, chunks, chunk, G, heads a group, ...]
+    xc = x.reshape(batch, n_chunks, chunk, groups, per_group, head)
+    dtc = dt.reshape(batch, n_chunks, chunk, groups, per_group)
+    bc = b.reshape(batch, n_chunks, chunk, groups, state)
+    cc = c.reshape(batch, n_chunks, chunk, groups, state)
+    # log-decay of every step, and its running sum inside the chunk
+    decay = dtc * a.astype(f32).reshape(groups, per_group)
+    decay = decay.transpose(0, 1, 3, 4, 2)          # [B, c, G, h, l]
+    cum = jnp.cumsum(decay, axis=-1)
+    # dt x: what a position adds to the state, per unit of B. Rounded
+    # once to the matmuls' dtype and read twice: kept in float32 for
+    # the end states it is twice the bytes and 2.8 ms of a 514 ms
+    # step at granite-4.0-h-micro's widths (PERF.md, PR 29)
+    xdt = (xc.astype(f32) * dtc[..., None]).astype(dtype)
 
-        # 1. inside a chunk: ((C B^T) o L) (dt x)
-        cb = jnp.einsum("bclgn,bcsgn->bcgls", cc, bc,
-                        preferred_element_type=f32)
-        within = _decay_between(cum)                    # [B, c, G, h, l, s]
-        mixed = (cb[:, :, :, None] * within).astype(dtype)
-        y = jnp.einsum("bcghls,bcsghp->bclghp", mixed, xdt,
-                       preferred_element_type=f32)
+    # 1. inside a chunk: ((C B^T) o L) (dt x)
+    cb = jnp.einsum("bclgn,bcsgn->bcgls", cc, bc,
+                    preferred_element_type=f32)
+    within = _decay_between(cum)                    # [B, c, G, h, l, s]
+    mixed = (cb[:, :, :, None] * within).astype(dtype)
+    y = jnp.einsum("bcghls,bcsghp->bclghp", mixed, xdt,
+                   preferred_element_type=f32)
 
-        # 2. each chunk's own end state: sum_s decay(s -> end) B_s (dt x)_s
-        to_end = jnp.exp(cum[..., -1:] - cum)           # [B, c, G, h, s]
-        xdt_end = (xdt.astype(f32)
-                   * to_end.transpose(0, 1, 4, 2, 3)[..., None]).astype(dtype)
-        own = jnp.einsum("bcsgn,bcsghp->bcghpn", bc, xdt_end,
-                         preferred_element_type=f32)
+    # 2. each chunk's own end state: sum_s decay(s -> end) B_s (dt x)_s
+    to_end = jnp.exp(cum[..., -1:] - cum)           # [B, c, G, h, s]
+    xdt_end = (xdt.astype(f32)
+               * to_end.transpose(0, 1, 4, 2, 3)[..., None]).astype(dtype)
+    own = jnp.einsum("bcsgn,bcsghp->bcghpn", bc, xdt_end,
+                     preferred_element_type=f32)
 
-        # 3. from chunk to chunk: the state that enters chunk z is the
-        # sum of the earlier chunks' own end states, each decayed over
-        # the whole chunks between (float32, full precision)
-        total = cum[..., -1].transpose(0, 2, 3, 1)      # [B, G, h, c]
-        carry = _decay_between(
-            jnp.pad(jnp.cumsum(total, axis=-1), ((0, 0),) * 3 + ((1, 0),)))
-        entering = jnp.einsum(
-            "bghzc,bcghpn->bzghpn", carry[..., :-1, 1:], own,
-            precision=jax.lax.Precision.HIGHEST,
-        )
+    # 3. from chunk to chunk: the state that enters chunk z is the
+    # sum of the earlier chunks' own end states, each decayed over
+    # the whole chunks between (float32, full precision)
+    total = cum[..., -1].transpose(0, 2, 3, 1)      # [B, G, h, c]
+    carry = _decay_between(
+        jnp.pad(jnp.cumsum(total, axis=-1), ((0, 0),) * 3 + ((1, 0),)))
+    entering = jnp.einsum(
+        "bghzc,bcghpn->bzghpn", carry[..., :-1, 1:], own,
+        precision=jax.lax.Precision.HIGHEST,
+    )
 
-        # 4. what the entering state adds: exp(cum_l) C_l H_in
-        from_start = jnp.exp(cum).transpose(0, 1, 4, 2, 3)  # [B, c, l, G, h]
-        y_in = jnp.einsum("bclgn,bcghpn->bclghp", cc, entering.astype(dtype),
-                          preferred_element_type=f32)
-        y = y + y_in * from_start[..., None]
+    # 4. what the entering state adds: exp(cum_l) C_l H_in
+    from_start = jnp.exp(cum).transpose(0, 1, 4, 2, 3)  # [B, c, l, G, h]
+    y_in = jnp.einsum("bclgn,bcghpn->bclghp", cc, entering.astype(dtype),
+                      preferred_element_type=f32)
+    y = y + y_in * from_start[..., None]
 
-        y = y.reshape(batch, seq, heads, head)
-        y = y + x.astype(f32) * d.astype(f32)[:, None]
-        return y.astype(dtype)
+    y = y.reshape(batch, seq, heads, head)
+    y = y + x.astype(f32) * d.astype(f32)[:, None]
+    return y.astype(dtype)

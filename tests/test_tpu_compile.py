@@ -291,12 +291,57 @@ def test_causal_conv_kernels_at_the_cell_size(one_chip, dtype):
         assert f"/{name}/pallas_call" in text
 
 
+# sequence, heads, head size, groups, states, chunk, dtype
+SCAN_SHAPES = [
+    # granite-4.0-h-micro's cell, and the same in float32 (8 heads a
+    # grid step where bf16 has 16)
+    (8192, 64, 64, 1, 128, 256, "bfloat16"),
+    (8192, 64, 64, 1, 128, 256, "float32"),
+    # what the CPU tests run in interpret mode: a chunk of one lane tile
+    (384, 8, 16, 1, 128, 128, "float32"),
+    (256, 32, 16, 2, 128, 128, "bfloat16"),
+    # two groups, wider states, a longer chunk
+    (2048, 16, 32, 2, 256, 512, "bfloat16"),
+]
+
+
+@pytest.mark.parametrize("shape", SCAN_SHAPES,
+                         ids=lambda s: "x".join(map(str, s)))
+def test_ssd_scan_kernels_compile(one_chip, shape):
+    """The chunked scan's kernel pair alone, at the cell's sizes (64
+    heads of 64 in one group, 128 states, one 8192-token sequence in
+    chunks of 256) and at others the rule takes: Mosaic takes the
+    blocks, each kernel carries its name, and the masked decay
+    [.., chunk, chunk] is nowhere in the program."""
+    from dlrover_tpu.ops import ssd_kernel
+
+    seq, heads, head, groups, states, chunk, dtype = shape
+    assert ssd_kernel.kernel_takes(seq, chunk, heads, groups, head, states)
+    x = jax.ShapeDtypeStruct((1, seq, heads, head), dtype)
+    dt = jax.ShapeDtypeStruct((1, seq, heads), jnp.float32)
+    per_head = jax.ShapeDtypeStruct((heads,), jnp.float32)
+    state = jax.ShapeDtypeStruct((1, seq, groups, states), dtype)
+    compiled = _compile(
+        jax.value_and_grad(
+            lambda *operands: ssd_kernel.ssd_scan_kernel(
+                *operands, chunk=chunk, interpret=False
+            ).astype(jnp.float32).sum(), argnums=tuple(range(6))),
+        *_shaped((x, dt, per_head, state, state, per_head), one_chip),
+    )
+    assert sorted(_kernel_names(compiled)) == ["ssd_scan_bwd", "ssd_scan_fwd"]
+    text = compiled.as_text()
+    for name in ("ssd_scan_fwd", "ssd_scan_bwd"):
+        assert f"/{name}/pallas_call" in text
+    if chunk not in (states, heads * head):     # no other [.., n, n]
+        assert f"{chunk},{chunk}]" not in text
+
+
 def test_mamba_layer_forward_backward(one_chip, on_tpu):
     """One Mamba-2 layer of granite-4.0-h-micro at its published widths
-    and the cell's 8192 tokens, forward and backward: the chunked scan
-    (ops/ssd.py, plain einsums) compiles for the chip, under its scope,
-    beside a layer's own weights and gradients; the convolution is its
-    kernel pair (forward, the recomputed forward, backward) and the
+    and the cell's 8192 tokens, forward and backward: it compiles for
+    the chip, every part under its scope, beside a layer's own weights
+    and gradients; the convolution and the chunked scan are their
+    kernel pairs (forward, the recomputed forward, backward) and the
     layer has no other kernel."""
     from dlrover_tpu.models import granite_hybrid as gh
 
@@ -321,8 +366,10 @@ def test_mamba_layer_forward_backward(one_chip, on_tpu):
         assert f"/{scope}/" in text or f"({scope})" in text, scope
     names = _kernel_names(compiled)
     assert len(names) == _kernels(compiled)
-    assert set(names) == {"causal_conv_fwd", "causal_conv_bwd"}, names
+    assert set(names) == {"causal_conv_fwd", "causal_conv_bwd",
+                          "ssd_scan_fwd", "ssd_scan_bwd"}, names
     assert names.count("causal_conv_bwd") == 1
+    assert names.count("ssd_scan_bwd") == 1
     assert _device_bytes(compiled) < 0.5 * V5E_HBM_BYTES
 
 
