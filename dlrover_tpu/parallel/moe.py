@@ -17,6 +17,15 @@ einsum formulation instead of a translated all-to-all:
   inserts the all-to-alls over ICI), and combines back.
 
 Everything is differentiable jnp; no process groups, no custom autograd.
+
+:func:`routed_experts` is the other form: no capacity and no dropped
+token. Tokens are sorted by their expert, the experts run as grouped
+matmuls over groups of unequal size, and the results go back to the
+tokens' places times the router's weight. The layer is told which
+experts of the model's it holds (``held = (first, count)``): it takes
+choices over all of them and computes its own experts' part of the
+result. The exchange over an ``expert`` mesh axis is not here yet, and
+nothing stands in for it.
 """
 
 from __future__ import annotations
@@ -29,7 +38,8 @@ import jax.numpy as jnp
 
 from dlrover_tpu.parallel.sharding import shard_logical
 
-__all__ = ["MoEConfig", "top_k_gating", "moe_ffn", "moe_init"]
+__all__ = ["MoEConfig", "top_k_gating", "moe_ffn", "moe_init",
+           "routed_experts"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -139,3 +149,131 @@ def moe_ffn(x, params, config: MoEConfig, rules=None):
 
     y = jnp.einsum("egcd,gtec->gtd", expert_out, combine)
     return y, metrics
+
+
+# ---------------------------------------------------------------------------
+# routed experts without capacity
+# ---------------------------------------------------------------------------
+
+# The grouped matmul: ``jax.lax.ragged_dot``, which XLA:TPU compiles to
+# its own kernels; their device events are named ``ragged-dot-*``
+# (``ragged-dot-metadata`` the group bookkeeping, ``ragged-dot-none``
+# the matmuls: forward, both transposes). Measured against megablox's
+# ``gmm`` at 16,384 rows of 2048, 8 of 16 experts (PERF.md, Findings,
+# PR 34): this op forward and backward 11.4 ms against 9.9 at gmm's
+# best tiling. Kept for being one path on every backend, no tiling to
+# choose and none that runs out of VMEM; the 14% are a ``perf_opt``'s.
+
+
+@jax.custom_vjp
+def _permute(x, perm, inverse):
+    """``x[perm]`` along axis 0, ``perm`` a permutation and ``inverse``
+    its inverse: the cotangent goes back as a gather too, where the
+    transpose of a gather is a scatter-add."""
+    return jnp.take(x, perm, axis=0)
+
+
+def _permute_fwd(x, perm, inverse):
+    return jnp.take(x, perm, axis=0), (perm, inverse)
+
+
+def _permute_bwd(res, g):
+    perm, inverse = res
+    return jnp.take(g, inverse, axis=0), None, None
+
+
+_permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+def _routed_rows(h, choice, weight, w_in, w_out, first):
+    """The op on one device's rows: h [N, D], choice [N], weight [N]."""
+    n, count = h.shape[0], w_in.shape[0]
+    mid_dim = w_out.shape[1]
+    local = choice.astype(jnp.int32) - first
+    here = (local >= 0) & (local < count)
+    # tokens of absent experts sort last and belong to no group: the
+    # grouped matmuls do not visit their rows
+    key = jnp.where(here, local, count)
+    with jax.named_scope("moe_dispatch"):
+        order = jnp.argsort(key, stable=True)
+        inverse = jnp.zeros((n,), jnp.int32).at[order].set(
+            jnp.arange(n, dtype=jnp.int32))
+        sizes = jnp.sum(
+            key[:, None] == jnp.arange(count, dtype=jnp.int32), axis=0,
+            dtype=jnp.int32)
+        held = jnp.arange(n, dtype=jnp.int32) < jnp.sum(sizes)
+        # the mask is for the way back: what the matmuls leave in the
+        # unvisited rows of a cotangent must not reach ``h``
+        rows = jnp.where(held[:, None], _permute(h, order, inverse), 0)
+    with jax.named_scope("moe_experts"):
+        gate_up = jax.lax.ragged_dot(
+            rows, w_in, sizes, preferred_element_type=h.dtype)
+        mid = jax.nn.silu(gate_up[:, :mid_dim]) * gate_up[:, mid_dim:]
+        out = jax.lax.ragged_dot(
+            mid, w_out, sizes, preferred_element_type=h.dtype)
+    with jax.named_scope("moe_combine"):
+        scale = jnp.take(weight.astype(jnp.float32), order)
+        out = jnp.where(
+            held[:, None], out.astype(jnp.float32) * scale[:, None], 0
+        ).astype(h.dtype)
+        return _permute(out, inverse, order)
+
+
+def routed_experts(h, choice, weight, experts, held):
+    """``weight * FFN_choice(h)`` a token, for the experts held here,
+    and 0 for a token whose expert is not. No token is dropped: there
+    is no capacity.
+
+    h [B, S, D]; choice [B, S] int, an expert of the model's a token;
+    weight [B, S], the router's; experts ``{"w_in": [count, D, 2 M]
+    (gate | up), "w_out": [count, M, D]}``, ``FFN(h) = (silu(h Wg) * (h
+    Wu)) Wd``; ``held = (first, count)``: the experts ``first .. first
+    + count - 1`` of the model's are the ``count`` stacked here.
+
+    Differentiable in ``h``, ``weight`` and the experts. On a mesh
+    whose ``data`` / ``fsdp`` axes divide the batch, each device sorts
+    and computes its own rows. An ``expert`` mesh axis larger than 1 is
+    refused: the exchange of tokens between the chips that share a
+    layer is not written, and nothing here stands in for it."""
+    from dlrover_tpu.parallel.mesh import get_mesh
+    from dlrover_tpu.parallel.sharding import logical_to_mesh_axes
+
+    first, count = held
+    w_in, w_out = experts["w_in"], experts["w_out"]
+    if w_in.shape[0] != count or w_out.shape[0] != count:
+        raise ValueError(
+            f"held = {held} names {count} experts, the weights hold "
+            f"{w_in.shape[0]} and {w_out.shape[0]}"
+        )
+    try:
+        mesh = get_mesh()
+    except RuntimeError:
+        mesh = None
+    if mesh is not None and mesh.shape.get("expert", 1) > 1:
+        raise NotImplementedError(
+            "routed_experts on an expert mesh axis of "
+            f"{mesh.shape['expert']}: the exchange of tokens between "
+            "the chips that share a layer is not implemented; use a "
+            "mesh with expert=1 and tell the layer what it holds"
+        )
+    batch, seq, dim = h.shape
+
+    def rows(h, choice, weight, w_in, w_out):
+        out = _routed_rows(
+            h.reshape(-1, dim), choice.reshape(-1), weight.reshape(-1),
+            w_in, w_out, first)
+        return out.reshape(h.shape)
+
+    if mesh is None or all(
+        mesh.shape.get(a, 1) == 1 for a in ("data", "fsdp")
+    ):
+        return rows(h, choice, weight, w_in, w_out)
+    from jax.sharding import PartitionSpec as P
+
+    batch_axes = logical_to_mesh_axes(
+        ("batch",), (("batch", ("data", "fsdp")),))[0]
+    return jax.shard_map(
+        rows, mesh=mesh,
+        in_specs=(P(batch_axes), P(batch_axes), P(batch_axes), P(), P()),
+        out_specs=P(batch_axes), check_vma=False,
+    )(h, choice, weight, w_in, w_out)

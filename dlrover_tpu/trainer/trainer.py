@@ -147,14 +147,7 @@ class Trainer:
         if args.compute_dtype is not None:
             overrides["compute_dtype"] = args.compute_dtype
         strategy = dataclasses.replace(strategy, **overrides)
-        self._accel = auto_accelerate(
-            loss_fn,
-            init_fn,
-            self.optimizer,
-            param_logical_axes,
-            strategy=strategy,
-            seed=args.seed,
-        )
+        self._accel = self._accelerate(strategy)
         self.state = self._accel.state
         self.global_step = 0
         # first train_step of this process incarnation traces+compiles;
@@ -483,6 +476,7 @@ class Trainer:
                         # does) still holds the one it does not cut
                         with tracing.annotation("train.readback"):
                             loss = float(metrics.get("loss", float("nan")))
+                            self._count_step(metrics)
                         with tracing.annotation("train.readback"):
                             logger.info(
                                 "step %d epoch %d loss %.5f",
@@ -1102,6 +1096,38 @@ class Trainer:
             "devices": len(devices),
         }
 
+    def _count_step(self, metrics):
+        """The loss function's own counts of the step just read back,
+        on the host with the loss and with no wait of their own: only
+        the names it declares as ``counters`` (any other aux is no
+        count), each added to the counter of its name and all together
+        left as one ``step.counts`` event, so that a reader can take
+        the increments of a stretch of the run. The step hands back
+        the mean over its micro-batches: times their number is the
+        step's sum."""
+        names = getattr(self.loss_fn, "counters", ())
+        if not names:
+            return
+        accum = max(int(self._accel.strategy.grad_accum), 1)
+        counts = {name: float(metrics[name]) * accum for name in names}
+        for name, count in counts.items():
+            telemetry.counter_inc(name, count)
+        telemetry.event("step.counts", step=self.global_step, **counts)
+
+    def _accelerate(self, strategy, **kwargs):
+        """``auto_accelerate`` over this Trainer's model. A loss
+        function may carry a form of itself that returns ``(loss,
+        aux)`` as its ``with_aux`` attribute (aux: a flat dict of
+        scalars, e.g. the tokens an expert layer routed). The step
+        then trains on that form, and the aux comes back with the loss
+        at each read-back (``_count_step``)."""
+        with_aux = getattr(self.loss_fn, "with_aux", None)
+        return auto_accelerate(
+            with_aux or self.loss_fn, self.init_fn, self.optimizer,
+            self.param_logical_axes, strategy=strategy,
+            seed=self.args.seed, has_aux=with_aux is not None, **kwargs,
+        )
+
     def _adopt_accel(self, devices, state):
         """Rebuild mesh + shardings + jitted step for the new device
         set. ``state=None`` re-initializes (rollback path); otherwise
@@ -1109,17 +1135,8 @@ class Trainer:
         the new mesh retraces — against the persistent XLA compilation
         cache that is a cache replay, and it is charged to the
         ``compile`` goodput bucket either way."""
-        from dlrover_tpu.parallel.accelerate import auto_accelerate
-
-        self._accel = auto_accelerate(
-            self.loss_fn,
-            self.init_fn,
-            self.optimizer,
-            self.param_logical_axes,
-            strategy=self._accel.strategy,
-            devices=devices,
-            seed=self.args.seed,
-            reuse_state=state,
+        self._accel = self._accelerate(
+            self._accel.strategy, devices=devices, reuse_state=state
         )
         self.state = self._accel.state if state is None else state
         self._compiled_once = False

@@ -249,6 +249,14 @@ ATTENTION_NAMES = {
         (1, 32, 8192, 64), (1, 8, 8192, 64),
         {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "flash_bwd_delta"},
     ),
+    # zaya1-8b's attention: 8 heads of 128 over 2 KV heads (4 query
+    # heads a KV head), 2 x 8192, q and k normalised and rotated
+    # outside the kernel
+    "zaya1-8b": (
+        functools.partial(flash_attention, block_q=1024, block_k=1024),
+        (2, 8, 8192, 128), (2, 2, 8192, 128),
+        {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "flash_bwd_delta"},
+    ),
 }
 
 
@@ -370,6 +378,46 @@ def test_mamba_layer_forward_backward(one_chip, on_tpu):
                           "ssd_scan_fwd", "ssd_scan_bwd"}, names
     assert names.count("causal_conv_bwd") == 1
     assert names.count("ssd_scan_bwd") == 1
+    assert _device_bytes(compiled) < 0.5 * V5E_HBM_BYTES
+
+
+def test_zaya_layer_forward_backward(one_chip, on_tpu):
+    """One layer of zaya1-8b at its published widths, 8 of 16 experts
+    held, the cell's 2 x 8192 tokens, forward and backward: it compiles
+    for the chip, every part under its scope; the attention kernels
+    and the compiler's own grouped matmuls (``ragged-dot-*``, which the
+    benchmark's ``moe_expert_ms`` finds by that name) are its only
+    kernels; the layer keeps its input alone, so the forward kernel
+    runs twice."""
+    from dlrover_tpu.models import zaya
+
+    config = zaya.ZayaConfig(vocab_size=32784, n_layers=1, held_experts=8)
+    loss = zaya.zaya_loss_fn(config)
+    params = jax.eval_shape(lambda: zaya.zaya_init(config, jax.random.key(0)))
+    compiled = _compile(
+        jax.value_and_grad(lambda p, b: loss(
+            jax.tree.map(lambda x: x.astype(jnp.bfloat16), p), b, None
+        )),
+        *_shaped(
+            (params,
+             {"tokens": jax.ShapeDtypeStruct((2, 8192 + 1), jnp.int32)}),
+            one_chip,
+        ),
+    )
+    text = compiled.as_text()
+    for scope in ("cca_proj", "cca_conv", "cca_qk_norm", "attn",
+                  "cca_out_proj", "router", "moe_dispatch", "moe_experts",
+                  "moe_combine", "residual_scale", "head"):
+        assert f"/{scope}/" in text or f"({scope})" in text, scope
+    names = _kernel_names(compiled)
+    assert len(names) == _kernels(compiled)
+    assert set(names) == {
+        "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "flash_bwd_delta",
+        "ragged-dot-metadata", "ragged-dot-none"}, names
+    assert names.count("flash_fwd") == 2
+    # gate | up and down: forward, the recomputed forward, and both
+    # transposes of each backward
+    assert names.count("ragged-dot-none") == 8
     assert _device_bytes(compiled) < 0.5 * V5E_HBM_BYTES
 
 
@@ -498,6 +546,86 @@ def test_train_step_fsdp_over_four_chips(topo, on_tpu):
     one_chip_state = 12 * CONFIG.param_count()  # bytes, unsharded
     m = compiled.memory_analysis()
     assert m.argument_size_in_bytes < one_chip_state / 4 * 1.05
+
+
+# The train step of each accepted benchmark cell, as the Trainer builds
+# it (``Trainer._accelerate``, the workload's own training arguments) and
+# lowers it for one described v5e: the first 16 hex digits of the
+# SHA-256 of its StableHLO, every Pallas kernel's serialized body cut
+# out (it holds its source file's path). A cell's ``setup_s`` is mostly
+# this program's compile, or its load from the compile cache, whose key
+# the program is: a PR that does not mean to touch a cell's step leaves
+# these as they are, and one that does says so by changing its line.
+# The first three read at the parent of PR 34 and on PR 34's tree,
+# equal.
+STEP_PROGRAMS = {
+    "mistral-7b": "00266e80d14bddf4",
+    "gpt2-xl": "48eb149feb8c79c2",
+    "granite-4.0-h-micro": "a8109781ea830cc9",
+    "zaya1-8b": "0cec888e74b79249",         # new in PR 34
+}
+
+
+@pytest.mark.parametrize("name", sorted(STEP_PROGRAMS))
+def test_accepted_cells_step_programs_are_what_they_were(
+        topo, on_tpu, name):
+    import hashlib
+    import sys
+    import types
+
+    from dlrover_tpu.trainer.trainer import (
+        Trainer,
+        TrainingArgs,
+        _build_optimizer,
+    )
+
+    bench = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmark")
+    sys.path.insert(0, bench)
+    try:
+        import families
+        import lookup
+
+        sizes = lookup.data("configs", name)
+        workload = lookup.data("workloads", name + ".steady")
+        family = families.build(sizes)
+    finally:
+        sys.path.remove(bench)
+    args = TrainingArgs(output_dir="unused", **workload["training_args"])
+    optimizer = _build_optimizer(args)
+
+    def init_state():
+        params = family.init(jax.random.key(0))
+        return TrainState(
+            step=jnp.zeros((), jnp.int32), params=params,
+            opt_state=optimizer.init(params),
+        )
+
+    state = jax.eval_shape(init_state)
+    # the Trainer's own way to its step, without a Trainer's state
+    accel = Trainer._accelerate(
+        types.SimpleNamespace(
+            loss_fn=family.loss_fn, init_fn=family.init,
+            optimizer=optimizer, param_logical_axes=family.logical_axes,
+            args=args),
+        Strategy(mesh=MeshConfig(**sizes["mesh"])),
+        devices=topo.devices[:1], reuse_state=state,
+    )
+    replicated = NamedSharding(accel.mesh, PartitionSpec())
+    text = jax.jit(accel.train_step, donate_argnums=(0,)).lower(
+        _shaped(state, accel.state_shardings),
+        _shaped({"tokens": jax.ShapeDtypeStruct(
+            (sizes["batch"], sizes["sequence"] + 1), jnp.int32)},
+            replicated),
+        _shaped(_abstract_key(), replicated),
+    ).as_text()
+    text, kernels = re.subn(
+        r'\\22body\\22: \\22[A-Za-z0-9+/=]*\\22',
+        r'\\22body\\22: \\22\\22', text)
+    assert kernels >= 2         # the attention kernels, at the least
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == STEP_PROGRAMS[name]
 
 
 @pytest.mark.parametrize("program", ["prefill-1024", "decode"])
