@@ -56,6 +56,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from dlrover_tpu.common import telemetry
 from dlrover_tpu.models.llama import (
@@ -324,7 +325,10 @@ def _mamba_mixer(config, y, p):
     inner, heads = config.mamba_inner, config.mamba_heads
     groups, state = config.mamba_groups, config.mamba_state
     with jax.named_scope("mamba_in_proj"):
-        zxbcdt = qdot(y, p["in_proj"].astype(dtype), site="mamba_proj")
+        # named for the layer's own checkpoint (_stage_fn's policy)
+        zxbcdt = checkpoint_name(
+            qdot(y, p["in_proj"].astype(dtype), site="mamba_proj"),
+            "mamba_in_proj")
         z = zxbcdt[..., :inner]
         dt = zxbcdt[..., inner + config.mamba_conv_dim:]
     with jax.named_scope("mamba_conv"):
@@ -395,18 +399,20 @@ def _layer_fn(config, kind):
 def _stage_fn(config: GraniteHybridConfig):
     """The whole stack: each run through the shared layer scan, the
     runs chained in their declared order."""
-    from dlrover_tpu.parallel.pipeline import LAYER_INPUT, stage_run_scan
+    from dlrover_tpu.parallel.pipeline import layer_input, stage_run_scan
 
     return stage_run_scan(
         {kind: _layer_fn(config, kind) for kind in KINDS},
         [(name, kind) for name, kind, _ in config.runs()],
         remat=config.remat,
-        # a Mamba layer keeps its input alone: what the default policy
-        # keeps of it, the projections' outputs, is 0.44 GiB a layer at
-        # 8192 tokens, and nine of them do not fit beside the train
+        # a Mamba layer keeps its input and its first projection's
+        # output (0.13 GiB a layer at 8192 tokens: the one matmul in
+        # five its recomputation then leaves out): what the default
+        # policy keeps of it, every projection's output, is 0.44 GiB a
+        # layer there, and nine of them do not fit beside the train
         # state. The attention layers keep the default (dots and the
         # kernel's output), so the forward kernel runs once
-        policy={"mamba": LAYER_INPUT},
+        policy={"mamba": layer_input(keep=("mamba_in_proj",))},
         # one layer's logical axes a kind (sans the leading "layer"
         # dim): opts each run into the fsdp-gather overlap
         layer_axes={

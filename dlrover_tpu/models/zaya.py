@@ -100,7 +100,7 @@ class ZayaConfig:
     norm_eps: float = 1e-5
     init_range: float = 0.02
     dtype: str = "bfloat16"
-    remat: bool = True                   # a layer keeps its input alone
+    remat: bool = True                   # a layer keeps its input and o
     ce_chunks: int = 8                   # the head, a chunk of rows a time
     # attention dispatch shared with the llama family (the toy twins
     # set the implementation and the forward blocks)
@@ -498,7 +498,7 @@ def _hidden(config: ZayaConfig, params, tokens, choices=False):
     """tokens [B, S] -> (the last layer's x [B, S, D], counts [4]) and,
     with ``choices``, the expert every layer chose for every token in
     this pass, int32 [layers, B, S]."""
-    from dlrover_tpu.parallel.pipeline import LAYER_INPUT, stage_layer_scan
+    from dlrover_tpu.parallel.pipeline import layer_input, stage_layer_scan
 
     carry, (cos, sin) = _embed(config, params, tokens)
     if choices:
@@ -506,11 +506,13 @@ def _hidden(config: ZayaConfig, params, tokens, choices=False):
                    jnp.zeros((), jnp.int32)),)
     stage = stage_layer_scan(
         _layer_fn(config), remat=config.remat,
-        # a layer keeps its input alone, (x, r): what the default
-        # policy keeps of it, every weight matmul's output, is 0.5 GiB
-        # a layer at 2 x 8192 tokens (the configuration's file has the
-        # compiler's counts)
-        policy=LAYER_INPUT,
+        # a layer keeps its input, (x, r), and the attention kernel's
+        # output and row statistic (34 MB a layer at 2 x 8192 tokens),
+        # so its recomputation does not run the forward kernel again.
+        # What the default policy keeps of it, every weight matmul's
+        # output, is 0.5 GiB a layer there (the configuration's file
+        # has the compiler's counts)
+        policy=layer_input(keep=("attn_out",)), kind="hybrid",
         layer_axes={k: tuple(v[1:]) for k, v in _LAYER_AXES.items()},
     )
     (x, _r, counts, *chosen), _aux = stage(params["layers"], carry, cos, sin)

@@ -24,6 +24,7 @@ and ZeRO sharding compose with pipelining without any model changes.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import itertools
 from typing import Callable
@@ -31,7 +32,9 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
+from dlrover_tpu.common import telemetry
 from dlrover_tpu.common.log import get_logger
 from dlrover_tpu.parallel.mesh import get_mesh
 
@@ -1132,18 +1135,24 @@ def policy_or_names(policy, names):
 
 def minimal_save_policy(offload: bool = False):
     """What remat level "minimal" keeps from forward to backward: the
-    dots without batch dimensions (the weight matmuls) and the
-    attention kernel's output and row statistics (``o`` and ``lse``,
-    tagged "attn_out" in ops/attention.py), the costliest thing a layer
-    could recompute. ``offload`` sends the dots to pinned host memory
-    and keeps "attn_out" in HBM.
+    dots without batch dimensions (the weight matmuls), the attention
+    kernel's output and row statistics (``o`` and ``lse``, tagged
+    "attn_out" in ops/attention.py), the costliest thing a layer could
+    recompute, and whatever a layer that keeps its input names beside
+    it (``layer_input(keep=...)``, handed over under ``KEPT``).
+    ``offload`` sends the dots to pinned host memory and keeps the
+    named values in HBM.
 
     The single home of that set: every checkpoint level of the step
     program reads it (accelerate._remat_wrap round the whole loss,
     stage_layer_scan's default round each layer, llama's "dots_attn" /
     "dots_attn_offload"). A level that leaves the names out drops
     ``o``/``lse`` in the forward pass, and the level inside it then
-    runs the forward kernel a second time to have them."""
+    runs the forward kernel a second time to have them. The outermost
+    level also rules what the first forward pass hands to a
+    ``layer_input`` layer's backward pass: that layer decides what it
+    reads (its input and its names), this set whether the named values
+    are there or made again by running the layer's forward pass."""
     dots = (
         jax.checkpoint_policies.offload_dot_with_no_batch_dims(
             "device", "pinned_host")
@@ -1151,7 +1160,7 @@ def minimal_save_policy(offload: bool = False):
         else jax.checkpoint_policies.dots_with_no_batch_dims_saveable
     )
     return policy_or_names(
-        dots, jax.checkpoint_policies.save_only_these_names("attn_out")
+        dots, jax.checkpoint_policies.save_only_these_names("attn_out", KEPT)
     )
 
 
@@ -1190,36 +1199,99 @@ def quant_aware_policy(policy):
     return p
 
 
-# ``stage_layer_scan``'s ``policy`` for a layer that keeps its input
-# and nothing else from forward to backward
-LAYER_INPUT = "layer_input"
+@dataclasses.dataclass(frozen=True)
+class layer_input:
+    """``stage_layer_scan``'s ``policy`` for a layer that keeps, from
+    forward to backward, its input and the values its model names in
+    ``keep`` (tagged with ``jax.ad_checkpoint.checkpoint_name`` in the
+    layer), and recomputes the rest (:func:`_recomputed_from_inputs`).
+    A model states ``keep`` from what it knows of its own layer: a
+    value that is costly to compute again and small beside what the
+    chip has spare, times the layers."""
+
+    keep: tuple = ()
 
 
-def _recomputed_from_inputs(body):
-    """``body`` with its whole forward pass recomputed in the backward
-    pass from its inputs, whatever checkpoint encloses it. A
-    ``jax.checkpoint`` cannot promise that: an enclosing checkpoint's
-    policy (accelerate._remat_wrap round the whole loss) rules the
-    first forward pass right through it and keeps what *it* names, the
-    weight matmuls' outputs. Those are 0.44 GiB a layer at 8192 tokens
-    and hidden 2048 with a 4x feed-forward and a 2x state-space mixer:
-    the difference between ten such layers fitting one chip and not.
-    The residuals of a ``custom_vjp`` are what its forward rule
-    returns, here the inputs alone."""
+# a layer that keeps its input and nothing else
+LAYER_INPUT = layer_input()
+# the name under which a ``layer_input`` layer hands its input and what
+# it keeps to the checkpoint that encloses it
+KEPT = "layer_kept"
+
+
+def _handed_over(value):
+    return checkpoint_name(value, KEPT)
+
+
+def _recomputed_from_inputs(body, keep=(), kind=""):
+    """``body`` with its forward pass recomputed in the backward pass
+    from its inputs, whatever checkpoint encloses it, but for the
+    values named in ``keep``, which are kept beside the inputs, so that
+    what produced them is not run again. A ``jax.checkpoint`` cannot
+    promise that: an enclosing checkpoint's policy
+    (accelerate._remat_wrap round the whole loss) rules the first
+    forward pass right through it and keeps what *it* names, the weight
+    matmuls' outputs. Those are 0.44 GiB a layer at 8192 tokens and
+    hidden 2048 with a 4x feed-forward and a 2x state-space mixer: the
+    difference between ten such layers fitting one chip and not. The
+    residuals of a ``custom_vjp`` are what its forward rule returns:
+    here the pullback of the layer under a checkpoint of its own that
+    keeps the names, whose leaves are the inputs and the kept values.
+
+    Which level keeps what: this one decides what the backward pass of
+    a layer *reads*; the enclosing checkpoint still decides what of it
+    the first forward pass *hands over*, and makes the rest again by
+    replaying the chain of layers in the backward pass. So the forward
+    rule gives what the backward pass reads, the layer's input (the
+    scan's carry) and the kept values, the name ``KEPT``, which
+    :func:`minimal_save_policy` keeps ("minimal" and "offload"): they
+    go from the first forward pass to the layer's backward pass as they
+    are, stacked once over the layers, and no layer is replayed to make
+    its successor's input (without the name a carry is no residual: the
+    enclosing level keeps the weight matmuls' outputs of every layer
+    and runs whatever lies between them again, a routed expert's
+    grouped matmuls for one; a kept value under a name it does not
+    know is stacked a second time by that replay).
+    :func:`stage_layer_scan` names the last layer's output likewise,
+    for whatever reads it next. Under ``Strategy.remat="full"`` the
+    enclosing level keeps nothing and replays the layers' forward pass
+    once, as it did before there were names: naming saves nothing
+    there.
+
+    The gauge ``model.remat.kept`` (labels ``kind``, ``name``) says, as
+    the forward rule's trace finds it, how many bytes a layer keeps
+    beside its inputs."""
+    kept = jax.checkpoint(
+        body, policy=jax.checkpoint_policies.save_only_these_names(*keep))
 
     @jax.custom_vjp
     def run(*args):
         return body(*args)
 
-    def forward(*args):
-        return body(*args), args
+    def forward(carry, *rest):
+        args = (jax.tree.map(_handed_over, carry), *rest)
+        out, pullback = jax.vjp(kept, *args)
+        if keep:
+            # the pullback's leaves are the inputs, as they came, and
+            # the kept values
+            inputs = {id(leaf) for leaf in jax.tree.leaves(args)}
+            telemetry.gauge_set(
+                "model.remat.kept",
+                sum(leaf.size * leaf.dtype.itemsize
+                    for leaf in jax.tree.leaves(pullback)
+                    if id(leaf) not in inputs),
+                kind=kind, name="+".join(keep))
+            pullback = jax.tree.map(
+                lambda leaf: leaf if id(leaf) in inputs
+                else _handed_over(leaf), pullback)
+        return out, pullback
 
-    def backward(args, cotangent):
-        # the barrier ties the recomputation to the cotangent's
-        # arrival: without it the compiler may merge it with the first
-        # forward pass (a run of one layer is no loop) and keep it all
-        args, cotangent = jax.lax.optimization_barrier((args, cotangent))
-        return jax.vjp(body, *args)[1](cotangent)
+    def backward(pullback, cotangent):
+        # the checkpoint's own barrier (prevent_cse) ties the
+        # recomputation to the cotangent's arrival: without one the
+        # compiler may merge it with the first forward pass (a run of
+        # one layer is no loop) and keep it all
+        return pullback(cotangent)
 
     run.defvjp(forward, backward)
     return run
@@ -1230,6 +1302,7 @@ def stage_layer_scan(
     remat: bool = True,
     policy=None,
     layer_axes=None,
+    kind: str = "",
 ):
     """Build a ``stage_fn`` that scans ``layer_fn`` over this stage's
     local stacked layers (the in-stage analogue of the model's full-depth
@@ -1239,7 +1312,10 @@ def stage_layer_scan(
     save policy applies (passed, or :func:`minimal_save_policy` by
     default) is adapted to the int8 quantized path via
     :func:`quant_aware_policy`. ``policy=LAYER_INPUT`` keeps a layer's
-    input alone (:func:`_recomputed_from_inputs`).
+    input alone, ``policy=layer_input(keep=names)`` also what the layer
+    tags with those names (:func:`_recomputed_from_inputs`; ``kind``
+    labels its gauge): the level that decides what a layer's backward
+    pass reads, under whatever checkpoint encloses the scan.
 
     ``layer_axes`` (a pytree matching ONE layer's params whose leaves
     are logical-axis tuples) opts the scan into collective–compute
@@ -1262,21 +1338,28 @@ def stage_layer_scan(
         # the strategy's remat="none" wins over the model config: a
         # no-remat trace must emit no checkpoint at any layer
         do_remat = remat and not remat_disabled()
-        layer_input = policy == LAYER_INPUT
-        # LAYER_INPUT wraps the layer itself, operands as arguments (a
+        from_inputs = isinstance(policy, layer_input)
+        # layer_input wraps the layer itself, operands as arguments (a
         # custom_vjp must not close over what is differentiated); any
         # other policy is a jax.checkpoint round the scan's body
         step = (
-            _recomputed_from_inputs(body) if do_remat and layer_input
-            else body
+            _recomputed_from_inputs(body, policy.keep, kind)
+            if do_remat and from_inputs else body
         )
 
         def checkpointed(scan_body):
-            if not do_remat or layer_input:
+            if not do_remat or from_inputs:
                 return scan_body
             return jax.checkpoint(scan_body, policy=quant_aware_policy(
                 policy or minimal_save_policy()
             ))
+
+        def last(h):
+            # what reads the last layer's output in the backward pass
+            # (the head) finds it kept, not made again from layer 0 on
+            if do_remat and from_inputs:
+                return jax.tree.map(_handed_over, h)
+            return h
 
         gather = layer_gather_fn(layer_axes)
         if gather is not None:
@@ -1310,7 +1393,7 @@ def stage_layer_scan(
             ((h, aux_sum), _), _ = jax.lax.scan(
                 overlap_body, carry0, jnp.arange(L, dtype=jnp.int32)
             )
-            return h, aux_sum
+            return last(h), aux_sum
 
         def scan_body(carry, layer_params):
             return step(carry, layer_params, *extras)
@@ -1319,7 +1402,7 @@ def stage_layer_scan(
         (h, aux_sum), _ = jax.lax.scan(
             scan_body, (h, jnp.zeros((), jnp.float32)), local_params
         )
-        return h, aux_sum
+        return last(h), aux_sum
 
     return stage_fn
 
@@ -1353,7 +1436,7 @@ def stage_run_scan(
     stages = {
         kind: stage_layer_scan(
             fn, remat=remat, policy=(policy or {}).get(kind),
-            layer_axes=(layer_axes or {}).get(kind),
+            layer_axes=(layer_axes or {}).get(kind), kind=kind,
         )
         for kind, fn in layer_fns.items()
     }

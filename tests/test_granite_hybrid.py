@@ -25,7 +25,7 @@ import pytest
 from dlrover_tpu.models import granite_hybrid as gh
 from dlrover_tpu.ops import ssd
 from dlrover_tpu.ops.fp8 import quant_autocast
-from dlrover_tpu.parallel import pipeline
+from dlrover_tpu.parallel import accelerate, pipeline
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "benchmark")
@@ -234,6 +234,80 @@ def test_layer_input_keeps_nothing_of_a_layer_under_an_outer_checkpoint():
 
     assert inner_wide(None)                      # [layers, tokens, inner]
     assert not inner_wide(pipeline.LAYER_INPUT)
+
+
+@pytest.mark.parametrize("name,of_a_matmul", [
+    ("attn_out", False), ("up_out", True), ("up_out", False),
+], ids=["a_name_every_level_knows", "a_weight_matmuls_output",
+        "a_name_of_the_layers_own"])
+def test_layer_input_keeps_what_the_layer_names(name, of_a_matmul):
+    """``layer_input(keep=names)``: under the whole-loss checkpoint the
+    saved residuals hold the named value stacked over the layers, once,
+    and still no other residual as wide as the layer's inner matmuls
+    (the default policy keeps both of them); a whole-loss checkpoint
+    that keeps nothing keeps none; the value and the gradients are the
+    un-checkpointed scan's and the bare LAYER_INPUT's to the last bit;
+    the gauge says how many bytes a layer keeps."""
+    from jax._src.ad_checkpoint import saved_residuals
+    from jax.ad_checkpoint import checkpoint_name
+
+    from dlrover_tpu.common import telemetry
+
+    width, inner, tokens, layers = 8, 48, 16, 3
+
+    def layer(h, p):
+        up = h @ p["up"]
+        up = checkpoint_name(up if of_a_matmul else jnp.sin(up), name)
+        return (h + (jnp.tanh(h @ p["gate"]) * up) @ p["down"],
+                jnp.zeros(()))
+
+    rs = np.random.RandomState(0)
+    params = {
+        "up": jnp.asarray(rs.randn(layers, width, inner) * 0.3),
+        "gate": jnp.asarray(rs.randn(layers, width, inner) * 0.3),
+        "down": jnp.asarray(rs.randn(layers, inner, width) * 0.1),
+    }
+    h0 = jnp.asarray(rs.randn(tokens, width))
+    keeping = pipeline.layer_input(keep=(name,))
+
+    def loss_of(policy, remat=True):
+        stage = pipeline.stage_layer_scan(
+            layer, remat=remat, policy=policy, kind="toy")
+        return lambda p, h: jnp.sum(stage(p, h)[0] ** 2)
+
+    def inner_wide(policy, outer=None):
+        loss = jax.checkpoint(
+            loss_of(policy), policy=outer or pipeline.minimal_save_policy())
+        return [aval.shape for aval, _ in saved_residuals(loss, params, h0)
+                if aval.shape and aval.shape[-1] == inner
+                and tokens in aval.shape]
+
+    telemetry.enable("test")
+    try:
+        assert len(inner_wide(None)) >= 2
+        assert inner_wide(pipeline.LAYER_INPUT) == []
+        assert inner_wide(keeping) == [(layers, tokens, inner)]
+        assert inner_wide(
+            keeping, jax.checkpoint_policies.nothing_saveable) == []
+        gauges = {tuple(sorted(g["labels"].items())): g["value"]
+                  for g in telemetry.snapshot()["gauges"]
+                  if g["name"] == "model.remat.kept"}
+    finally:
+        telemetry.install_from_env()
+    assert gauges == {(("kind", "toy"), ("name", name)): tokens * inner * 4}
+
+    want = jax.value_and_grad(loss_of(None, remat=False), (0, 1))(params, h0)
+    for outer in (None, "minimal", "full"):
+        for policy in (pipeline.LAYER_INPUT, keeping):
+            loss = loss_of(policy)
+            if outer:
+                loss = accelerate._remat_wrap(
+                    lambda p, h, _rng, inner_loss=loss: inner_loss(p, h),
+                    outer)
+                got = jax.value_and_grad(loss, (0, 1))(params, h0, None)
+            else:
+                got = jax.value_and_grad(loss, (0, 1))(params, h0)
+            jax.tree.map(np.testing.assert_array_equal, got, want)
 
 
 # ----------------------------------------------------------------- model
@@ -457,6 +531,30 @@ def test_pipeline_stages_of_unlike_layers_are_refused(family):
                     config, p, jnp.zeros((4, 128), jnp.int32)), params)
     finally:
         mesh_lib._global_mesh = before
+
+
+def test_a_traced_backward_pass_publishes_what_a_layer_keeps(toy):
+    """``model.remat.kept``: a Mamba layer keeps, beside its input, its
+    first projection's output [B, S, 2 inner + 2 groups x state +
+    heads]; the attention layer is under the default policy and
+    publishes nothing."""
+    from dlrover_tpu.common import telemetry
+
+    cfg = toy["family"].model_config
+    batch, seq = toy["tokens"].shape[0], toy["tokens"].shape[1] - 1
+    telemetry.enable("test")
+    try:
+        jax.eval_shape(jax.grad(gh.granite_hybrid_loss_fn(cfg)),
+                       toy["params"], {"tokens": toy["tokens"]}, None)
+        gauges = {(g["labels"]["kind"], g["labels"]["name"]): g["value"]
+                  for g in telemetry.snapshot()["gauges"]
+                  if g["name"] == "model.remat.kept"}
+    finally:
+        telemetry.install_from_env()
+    width = 2 * cfg.mamba_inner + 2 * cfg.mamba_groups * cfg.mamba_state \
+        + cfg.mamba_heads
+    assert gauges == {("mamba", "mamba_in_proj"):
+                      batch * seq * width * jnp.dtype(cfg.dtype).itemsize}
 
 
 def test_build_publishes_its_shape():

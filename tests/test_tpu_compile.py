@@ -387,8 +387,9 @@ def test_zaya_layer_forward_backward(one_chip, on_tpu):
     for the chip, every part under its scope; the attention kernels
     and the compiler's own grouped matmuls (``ragged-dot-*``, which the
     benchmark's ``moe_expert_ms`` finds by that name) are its only
-    kernels; the layer keeps its input alone, so the forward kernel
-    runs twice."""
+    kernels; the layer keeps its input and the forward kernel's
+    outputs, so that kernel runs once (twice while the layer kept its
+    input alone, before PR 35)."""
     from dlrover_tpu.models import zaya
 
     config = zaya.ZayaConfig(vocab_size=32784, n_layers=1, held_experts=8)
@@ -414,7 +415,7 @@ def test_zaya_layer_forward_backward(one_chip, on_tpu):
     assert set(names) == {
         "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "flash_bwd_delta",
         "ragged-dot-metadata", "ragged-dot-none"}, names
-    assert names.count("flash_fwd") == 2
+    assert names.count("flash_fwd") == 1
     # gate | up and down: forward, the recomputed forward, and both
     # transposes of each backward
     assert names.count("ragged-dot-none") == 8
@@ -561,15 +562,14 @@ def test_train_step_fsdp_over_four_chips(topo, on_tpu):
 STEP_PROGRAMS = {
     "mistral-7b": "00266e80d14bddf4",
     "gpt2-xl": "48eb149feb8c79c2",
-    "granite-4.0-h-micro": "a8109781ea830cc9",
-    "zaya1-8b": "0cec888e74b79249",         # new in PR 34
+    "granite-4.0-h-micro": "b3bd6dbcb7768cd6",  # PR 35: keeps in_proj
+    "zaya1-8b": "16082d6ad790c866",         # PR 35: keeps attn_out
 }
 
 
-@pytest.mark.parametrize("name", sorted(STEP_PROGRAMS))
-def test_accepted_cells_step_programs_are_what_they_were(
-        topo, on_tpu, name):
-    import hashlib
+def _lowered_cell_step(topo, name):
+    """The step of the accepted cell ``<name>.steady``, lowered for one
+    described v5e from shapes alone."""
     import sys
     import types
 
@@ -613,19 +613,40 @@ def test_accepted_cells_step_programs_are_what_they_were(
         devices=topo.devices[:1], reuse_state=state,
     )
     replicated = NamedSharding(accel.mesh, PartitionSpec())
-    text = jax.jit(accel.train_step, donate_argnums=(0,)).lower(
+    return jax.jit(accel.train_step, donate_argnums=(0,)).lower(
         _shaped(state, accel.state_shardings),
         _shaped({"tokens": jax.ShapeDtypeStruct(
             (sizes["batch"], sizes["sequence"] + 1), jnp.int32)},
             replicated),
         _shaped(_abstract_key(), replicated),
-    ).as_text()
+    )
+
+
+@pytest.mark.parametrize("name", sorted(STEP_PROGRAMS))
+def test_accepted_cells_step_programs_are_what_they_were(
+        topo, on_tpu, name):
+    import hashlib
+
+    text = _lowered_cell_step(topo, name).as_text()
     text, kernels = re.subn(
         r'\\22body\\22: \\22[A-Za-z0-9+/=]*\\22',
         r'\\22body\\22: \\22\\22', text)
     assert kernels >= 2         # the attention kernels, at the least
     assert hashlib.sha256(text.encode()).hexdigest()[:16] \
         == STEP_PROGRAMS[name]
+
+
+# The cells whose layers keep their input and what they name beside it
+# (pipeline.layer_input): the kernel that made a kept value runs once a
+# layer, and the compiler, whose limit for a program on this chip is
+# 15.75 GiB, takes the step (it refuses one it counts above that, and
+# its count is the buffer assignment's: ``memory_analysis()`` reads
+# every stack a scan writes a second time, PERF.md, PR 35).
+@pytest.mark.parametrize("name", ["granite-4.0-h-micro", "zaya1-8b"])
+def test_cells_that_keep_named_values_compile_for_the_chip(
+        topo, on_tpu, name):
+    compiled = _lowered_cell_step(topo, name).compile()
+    assert _kernel_names(compiled).count("flash_fwd") == 1
 
 
 @pytest.mark.parametrize("program", ["prefill-1024", "decode"])

@@ -554,6 +554,30 @@ def test_build_publishes_its_shape():
     assert gauges["model.moe.experts", "published"] == 16
 
 
+def test_a_traced_backward_pass_publishes_what_a_layer_keeps(toy):
+    """``model.remat.kept``: the bytes a layer keeps beside its input,
+    as the forward rule's trace finds them: the attention kernel's
+    output [B, S, Hq, d] in the compute dtype and one float32 row
+    statistic a query."""
+    from dlrover_tpu.common import telemetry
+
+    cfg = dataclasses.replace(toy["family"].model_config, attn_impl="flash")
+    batch, seq = toy["tokens"].shape[0], toy["tokens"].shape[1] - 1
+    telemetry.enable("test")
+    try:
+        jax.eval_shape(jax.grad(zaya.zaya_loss_fn(cfg)), toy["params"],
+                       {"tokens": toy["tokens"]}, None)
+        gauges = {(g["labels"]["kind"], g["labels"]["name"]): g["value"]
+                  for g in telemetry.snapshot()["gauges"]
+                  if g["name"] == "model.remat.kept"}
+    finally:
+        telemetry.install_from_env()
+    rows = batch * seq * cfg.n_heads
+    assert gauges == {("hybrid", "attn_out"):
+                      rows * cfg.head_dim * jnp.dtype(cfg.dtype).itemsize
+                      + rows * 4}
+
+
 # ------------------------------------------------- trainer and checkpoint
 
 
