@@ -42,3 +42,11 @@ from dlrover_tpu.models.granite_hybrid import (  # noqa: F401
     granite_hybrid_apply,
     granite_hybrid_loss_fn,
 )
+
+from dlrover_tpu.models.olmo_hybrid import (  # noqa: F401
+    OlmoHybridConfig,
+    olmo_hybrid_logical_axes,
+    olmo_hybrid_init,
+    olmo_hybrid_apply,
+    olmo_hybrid_loss_fn,
+)

@@ -348,9 +348,13 @@ def _mamba_mixer(config, y, p):
         return qdot(gated, p["out_proj"].astype(dtype), site="mamba_proj")
 
 
-def _attention_mixer(config, y, p):
+def _attention_mixer(config, y, p, qk_norm=None):
     """y [B, S, D] (normed) -> attention output [B, S, D]; no position
-    embedding, the published multiplier as the softmax scale."""
+    embedding, the published multiplier as the softmax scale (None: the
+    kernels' ``1 / sqrt(head_dim)``). ``qk_norm(q, k, heads)`` stands
+    between the projections and the kernel where a model norms its
+    queries and keys: both arrive with their heads on axis ``heads``
+    and a head's channels last."""
     dtype = y.dtype
     B, S, D = y.shape
     h, kvh, hd = config.n_heads, config.n_kv_heads, config.head_dim
@@ -364,18 +368,22 @@ def _attention_mixer(config, y, p):
                  p["wk"].astype(dtype).reshape(D, kvh, hd),
                  p["wv"].astype(dtype).reshape(D, kvh, hd)], axis=1)
             qkv = qeinsum("bsd,dhk->bhsk", y, w_qkv, site="attn_qkv")
+            q, k = qkv[:, :h], qkv[:, h:h + kvh]
+            if qk_norm is not None:
+                q, k = qk_norm(q, k, 1)
             out = bhsd_flash_attention(
-                config, qkv[:, :h], qkv[:, h:h + kvh], qkv[:, h + kvh:],
-                sm_scale=scale)
+                config, q, k, qkv[:, h + kvh:], sm_scale=scale)
             return qeinsum("bhsk,hkd->bsd", out,
                            p["wo"].astype(dtype).reshape(h, hd, D),
                            site="attn_out")
         q = qdot(y, p["wq"].astype(dtype), site="attn_qkv")
         k = qdot(y, p["wk"].astype(dtype), site="attn_qkv")
         v = qdot(y, p["wv"].astype(dtype), site="attn_qkv")
+        q, k = q.reshape(B, S, h, hd), k.reshape(B, S, kvh, hd)
+        if qk_norm is not None:
+            q, k = qk_norm(q, k, 2)
         out = _attention(
-            config, q.reshape(B, S, h, hd), k.reshape(B, S, kvh, hd),
-            v.reshape(B, S, kvh, hd), sm_scale=scale)
+            config, q, k, v.reshape(B, S, kvh, hd), sm_scale=scale)
         return qdot(out.reshape(B, S, h * hd), p["wo"].astype(dtype),
                     site="attn_out")
 

@@ -44,15 +44,21 @@ def fresh_telemetry():
 
 
 class LateLoss:
-    """A loss whose readiness comes late, as a device array's does."""
+    """A loss whose readiness comes late, as a device array's does. The
+    first look at it leaves in ``late`` how long after its readiness
+    the host woke to see it."""
 
-    def __init__(self, ready_at, value):
-        self.ready_at, self.value = ready_at, value
+    def __init__(self, ready_at, value, late):
+        self.ready_at, self.value, self.late = ready_at, value, late
+        self.looked = False
 
     def block_until_ready(self):
         wait = self.ready_at - time.monotonic()
         if wait > 0:
             time.sleep(wait)
+        if not self.looked:
+            self.looked = True
+            self.late.append(max(time.monotonic() - self.ready_at, 0.0))
         return self
 
     def __float__(self):
@@ -63,19 +69,45 @@ class LateLoss:
 class SlowDevice:
     """Stands in for ``_accel.train_step``: returns at once, the step
     completes ``STEP_S`` after the later of its dispatch and the
-    completion of the step before it."""
+    completion of the step before it. ``done_at`` is when each step
+    completed, ``late`` how late the host woke to each completion."""
 
     def __init__(self, step_s=STEP_S):
         self.step_s = step_s
         self.free_at = 0.0
         self.dispatch_s = []
+        self.done_at = []
+        self.late = []
 
     def __call__(self, state, batch, rng):
         t0 = time.monotonic()
         self.free_at = max(self.free_at, t0) + self.step_s
-        loss = LateLoss(self.free_at, 1.0 / (1 + len(self.dispatch_s)))
+        self.done_at.append(self.free_at)
+        loss = LateLoss(self.free_at, 1.0 / (1 + len(self.dispatch_s)),
+                        self.late)
         self.dispatch_s.append(time.monotonic() - t0)
         return state, {"loss": loss}
+
+    def measured(self, durs):
+        """Whether ``durs`` are the steady steps as this device saw
+        them, one by one and in their sum: each between the step's own
+        length and the time from the completion before it to its own
+        (longer where the device waited for a late dispatch). The
+        tolerance is what the device measured too: a reader sees a
+        completion when the host wakes to it, so a step may read longer
+        by that wake's lateness and shorter by the one before it (a
+        loaded test host wakes tens of ms late from a sleep; a constant
+        share of the step cannot know). A dispatch time among them, or
+        in place of them, fails the lower bounds."""
+        durs, true = list(durs), np.diff(self.done_at)
+        slack = 2 * max(self.late) + 2e-3
+        steady = len(true) * self.step_s
+        return (
+            len(durs) == len(true) and slack < steady / 2
+            and steady - slack <= sum(durs) <= true.sum() + slack
+            and all(self.step_s - slack <= d <= t + slack
+                    for d, t in zip(durs, true))
+        )
 
 
 class RingRecorder:
@@ -150,22 +182,22 @@ def test_every_reader_gets_the_completion_not_the_dispatch(
     wall = time.time() - t0
     trainer.close()
     # the stand-in's dispatch really is nothing beside its step
-    assert max(device.dispatch_s) < STEP_S / 10
+    assert statistics.median(device.dispatch_s) < STEP_S / 10
 
     # one step.end per steady step, in order; the first step compiles
     events = step_events()
     assert [e["step"] for e in events] == list(range(2, steps + 1))
     assert [e["step"] for e in step_events("compile")] == [1]
-    assert real_steps(e["dur"] for e in events)
-    # durations tile the wall clock: nothing is counted twice or lost
+    # each is the step the device took, and they tile its run: nothing
+    # is counted twice or lost
+    assert device.measured(e["dur"] for e in events)
     booked = sum(e["dur"] for e in events)
-    assert booked == pytest.approx((steps - 1) * STEP_S, rel=TOLERANCE)
     assert booked + step_events("compile")[0]["dur"] <= wall
 
     snap = telemetry.snapshot()
     series = {s["name"]: s["points"] for s in snap["series"]}
     assert len(series["train.step.last_s"]) == steps - 1
-    assert real_steps(p[3] for p in series["train.step.last_s"])
+    assert device.measured(p[3] for p in series["train.step.last_s"])
     # the same number, not a second measurement
     assert [p[3] for p in series["train.step.last_s"]] == \
         [e["dur"] for e in events]
@@ -173,7 +205,7 @@ def test_every_reader_gets_the_completion_not_the_dispatch(
     ring = [r for r in trainer._timer.records
             if r[0] in (Tag.STEP, Tag.COMPILE)]
     assert [r[0] for r in ring] == [Tag.COMPILE] + [Tag.STEP] * (steps - 1)
-    assert real_steps(dur / 1e9 for _tag, _start, dur in ring[1:])
+    assert device.measured(dur / 1e9 for _tag, _start, dur in ring[1:])
     # a record's start is where the step before it ended
     for (_t, start, dur), (_t2, start2, _d2) in zip(ring, ring[1:]):
         assert start2 >= start + dur
@@ -182,7 +214,7 @@ def test_every_reader_gets_the_completion_not_the_dispatch(
     # (pre-increment) numbering
     assert [s for s, _dur in trainer._prof.ends] == list(range(steps))
     assert trainer._prof.ends[0][1] == 0.0   # the compiling step: none
-    assert real_steps(dur for _s, dur in trainer._prof.ends[1:])
+    assert device.measured(dur for _s, dur in trainer._prof.ends[1:])
 
     # nothing the Trainer publishes carries a dispatch time
     names = {g["name"] for g in snap["gauges"]} \

@@ -564,12 +564,15 @@ STEP_PROGRAMS = {
     "gpt2-xl": "48eb149feb8c79c2",
     "granite-4.0-h-micro": "b3bd6dbcb7768cd6",  # PR 35: keeps in_proj
     "zaya1-8b": "16082d6ad790c866",         # PR 35: keeps attn_out
+    "olmo-hybrid-7b": "a21aed4543a6c099",     # PR 37: over four chips
 }
 
 
 def _lowered_cell_step(topo, name):
-    """The step of the accepted cell ``<name>.steady``, lowered for one
-    described v5e from shapes alone."""
+    """The step of the accepted cell ``<name>.steady``, lowered for
+    the described v5e chips its configuration's mesh takes, from shapes
+    alone."""
+    import math
     import sys
     import types
 
@@ -610,7 +613,8 @@ def _lowered_cell_step(topo, name):
             optimizer=optimizer, param_logical_axes=family.logical_axes,
             args=args),
         Strategy(mesh=MeshConfig(**sizes["mesh"])),
-        devices=topo.devices[:1], reuse_state=state,
+        devices=topo.devices[:math.prod(sizes["mesh"].values())],
+        reuse_state=state,
     )
     replicated = NamedSharding(accel.mesh, PartitionSpec())
     return jax.jit(accel.train_step, donate_argnums=(0,)).lower(
@@ -647,6 +651,31 @@ def test_cells_that_keep_named_values_compile_for_the_chip(
         topo, on_tpu, name):
     compiled = _lowered_cell_step(topo, name).compile()
     assert _kernel_names(compiled).count("flash_fwd") == 1
+
+
+def test_four_chip_cell_compiles_and_fits(topo, on_tpu):
+    """``olmo-hybrid-7b.steady`` under fsdp=4 on the four chips of a
+    described v5e:2x2: the compiler takes the step (its limit is 15.75
+    GiB a chip, by its buffer assignment: 15.84e9 bytes when the cell
+    was built, of which the quarter of the train state 7.31e9), every
+    chip holds a quarter of the state, the collectives are in, and the
+    kernels are there under the names the per-layer metrics read: the
+    flash pair of the two full-attention layers, run once a layer, and
+    the convolution pair of the six linear ones."""
+    compiled = _lowered_cell_step(topo, "olmo-hybrid-7b").compile()
+    names = _kernel_names(compiled)
+    assert len(names) == _kernels(compiled)
+    # one body a run of like layers and a pass: two runs of each kind
+    assert names.count("flash_fwd") == 2, names
+    assert {n for n in names if n.startswith("flash_bwd")}
+    assert names.count("causal_conv_fwd") == 4        # + recomputation
+    assert names.count("causal_conv_bwd") == 2
+    text = compiled.as_text()
+    assert "all-gather" in text and "all-reduce" in text
+    m = compiled.memory_analysis()
+    state = 12 * 2_435_748_072                  # bytes, unsharded
+    assert m.argument_size_in_bytes < state / 4 * 1.01
+    assert m.peak_memory_in_bytes < 15.75 * 1024 ** 3
 
 
 @pytest.mark.parametrize("program", ["prefill-1024", "decode"])
