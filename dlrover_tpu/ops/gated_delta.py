@@ -47,14 +47,28 @@ operands in the activations' dtype (bf16 in a bf16 model) and
 accumulate in float32. Every exponent is a difference ``gamma_i -
 gamma_j`` with ``i >= j``, so no ``exp`` exceeds 1.
 
-:func:`gated_delta_rule` is the entry and picks the form from the
-shapes and the mesh alone, as ``ssd_scan`` does: the chunked form where
-the sequence is whole chunks and no ``seq`` mesh axis is active (no
-state is handed across sequence shards), the recurrence itself
-(:func:`gated_delta_rule_plain`, one position at a time: the tests'
-yardstick) everywhere else. The gauge ``model.gdn.impl`` says which
-was traced. Both are plain ``jax.numpy``, differentiated by JAX, and
-partition over the batch as the compiler partitions any array program.
+:func:`gated_delta_rule` is the entry: one algorithm in three forms,
+picked from the shapes and the mesh alone by the rule ``ssd_scan``
+applies (``ssd._kernel_mesh``). Where the kernels' blocks tile the input
+(the sequence in chunks of 128, head sizes in whole sublane tiles, keys
+of 64 channels at the least: ``gated_delta_kernel.kernel_takes``), no
+``seq`` mesh axis is active and the batch divides over ``data`` /
+``fsdp``: the Pallas kernel pair of ``ops/gated_delta_kernel.py``
+(``gdn_chunk_fwd``, ``gdn_chunk_bwd``), mapped over the mesh's batch
+axes. It is the chunked form above at the same precision on chunks of
+128 positions (the chunk is the algorithm's tile, not the model's
+mathematics), keeps every [C, C] block of a chunk in fast memory, and
+hands its backward pass the inputs and each chunk's entering state.
+Everywhere else the forms of this file, plain ``jax.numpy``
+differentiated by JAX, which partition over the batch as the compiler
+partitions any array program and are the kernels' reference: the chunked
+form (:func:`gated_delta_rule_chunked`) where the sequence is whole
+chunks and no ``seq`` axis is active (no state is handed across sequence
+shards: the toy widths and short sequences of the CPU tests), the
+recurrence itself (:func:`gated_delta_rule_plain`, one position at a
+time: the tests' yardstick) for a ragged tail or a sequence-sharded
+mesh. The gauge ``model.gdn.impl`` says which was traced: ``kernel``,
+``chunked`` or ``plain``.
 """
 
 from __future__ import annotations
@@ -73,16 +87,25 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int):
     scaled by the caller), ``v`` [B, S, H, dv], ``g`` [B, S, H] the log
     of the decay (<= 0, float32), ``beta`` [B, S, H] -> ``o`` [B, S, H,
     dv] in ``v``'s dtype. The state starts at zero."""
+    from dlrover_tpu.ops import gated_delta_kernel
+    from dlrover_tpu.ops.ssd import _kernel_mesh, _over_batch
     from dlrover_tpu.parallel.mesh import axis_size
 
+    (batch, seq, heads, dk), dv = q.shape, v.shape[-1]
     try:
         seq_shards = axis_size("seq")
     except RuntimeError:            # no mesh at all
         seq_shards = 1
-    chunked = q.shape[1] % chunk == 0 and seq_shards == 1
-    telemetry.gauge_set(
-        "model.gdn.impl", 1, impl="chunked" if chunked else "plain")
+    chunked = seq % chunk == 0 and seq_shards == 1
+    mesh, batch_axes = _kernel_mesh(
+        "model.gdn.impl", batch,
+        gated_delta_kernel.kernel_takes(seq, heads, dk, dv),
+        otherwise="chunked" if chunked else "plain")
     with jax.named_scope("gdn_rule"):
+        if batch_axes is not None:
+            return _over_batch(
+                gated_delta_kernel.gated_delta_rule_kernel, mesh,
+                batch_axes, "xxxxx")(q, k, v, g, beta)
         if chunked:
             return gated_delta_rule_chunked(q, k, v, g, beta, chunk)
         return gated_delta_rule_plain(q, k, v, g, beta)

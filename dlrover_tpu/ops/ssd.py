@@ -86,13 +86,14 @@ def causal_conv_silu(x, weight, bias, first: int = 0):
     return _over_batch(run, mesh, batch_axes, "x..")(x, weight, bias)
 
 
-def _kernel_mesh(gauge: str, batch: int, tiled: bool):
+def _kernel_mesh(gauge: str, batch: int, tiled: bool,
+                 otherwise: str = "plain"):
     """Where a kernel pair may run: ``(mesh, its batch axes)`` if the
     kernels' blocks tile the input (``tiled``), no ``seq`` mesh axis is
     active and the batch divides over the ``data`` / ``fsdp`` axes;
     ``(mesh, None)`` for the plain form. Sets ``gauge`` under the label
-    ``impl`` = ``kernel`` or ``plain``: the choice is made at trace
-    time, here."""
+    ``impl`` = ``kernel`` or ``otherwise``, the plain form's name: the
+    choice is made at trace time, here."""
     from dlrover_tpu.parallel.mesh import get_mesh
 
     try:
@@ -105,7 +106,7 @@ def _kernel_mesh(gauge: str, batch: int, tiled: bool):
         tiled and axes.get("seq", 1) == 1
         and batch % math.prod(axes[a] for a in batch_axes) == 0
     )
-    telemetry.gauge_set(gauge, 1, impl="kernel" if kernel else "plain")
+    telemetry.gauge_set(gauge, 1, impl="kernel" if kernel else otherwise)
     return mesh, batch_axes if kernel else None
 
 

@@ -564,7 +564,9 @@ STEP_PROGRAMS = {
     "gpt2-xl": "48eb149feb8c79c2",
     "granite-4.0-h-micro": "b3bd6dbcb7768cd6",  # PR 35: keeps in_proj
     "zaya1-8b": "16082d6ad790c866",         # PR 35: keeps attn_out
-    "olmo-hybrid-7b": "a21aed4543a6c099",     # PR 37: over four chips
+    # PR 38: the gated delta rule by its kernel pair (gdn_chunk_fwd /
+    # gdn_chunk_bwd) where PR 37's a21aed4543a6c099 ran the chunked form
+    "olmo-hybrid-7b": "a242b4f22b927984",
 }
 
 
@@ -661,7 +663,10 @@ def test_four_chip_cell_compiles_and_fits(topo, on_tpu):
     chip holds a quarter of the state, the collectives are in, and the
     kernels are there under the names the per-layer metrics read: the
     flash pair of the two full-attention layers, run once a layer, and
-    the convolution pair of the six linear ones."""
+    the convolution pair and (PR 38) the gated delta rule's pair of the
+    six linear ones. Since that pair the rule's float32 [C, C] stacks
+    are gone from a linear layer's backward pass, where the peak lies:
+    14.3e9 bytes."""
     compiled = _lowered_cell_step(topo, "olmo-hybrid-7b").compile()
     names = _kernel_names(compiled)
     assert len(names) == _kernels(compiled)
@@ -670,6 +675,9 @@ def test_four_chip_cell_compiles_and_fits(topo, on_tpu):
     assert {n for n in names if n.startswith("flash_bwd")}
     assert names.count("causal_conv_fwd") == 4        # + recomputation
     assert names.count("causal_conv_bwd") == 2
+    assert names.count("gdn_chunk_fwd") == 4          # + recomputation
+    assert names.count("gdn_chunk_bwd") == 2
+    assert sum(n.startswith("gdn_chunk_") for n in names) == 6
     text = compiled.as_text()
     assert "all-gather" in text and "all-reduce" in text
     m = compiled.memory_analysis()
