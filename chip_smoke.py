@@ -1030,6 +1030,9 @@ class Run:
             "phase": self.phase,
             "kill_to_first_resumed_step_s": round(
                 rsteps[0]["wall"] - t_kill, 2),
+            # on the clock of the telemetry's ``t``: the ``resume``
+            # trace's root runs from ``worker.exit``'s ``died_t``
+            "t_kill": t_kill, "t_first_resumed_step": rsteps[0]["wall"],
             "restore_s": done.get("restore_s"),
             "restore_h2d_s": done.get("h2d_s"),
             "restore_mb": done.get("mb"),
@@ -1179,6 +1182,10 @@ def parent(opts, sizes):
         else [run.phase_kernels, run.phase_train_kill_resume,
               run.phase_serve]
     )
+    if opts.phases:
+        # a measurement of one phase (never the contract line below)
+        phases = [p for p in phases
+                  if p.__name__[len("phase_"):] in opts.phases.split(",")]
     code = EXIT_OK
     try:
         for phase in phases:
@@ -1202,9 +1209,10 @@ def parent(opts, sizes):
         run.cleanup()
     if code != EXIT_OK:
         return code
-    if opts.rehearsal:
-        print(json.dumps({"rehearsal": True, "ok": False,
-                          "device": run.device}))
+    if opts.rehearsal or opts.phases:
+        print(json.dumps({"rehearsal": opts.rehearsal, "ok": False,
+                          "phases": opts.phases, "device": run.device,
+                          "telemetry": run.env["DLROVER_TELEMETRY_DIR"]}))
         return EXIT_OK
     print(json.dumps({"ok": True, "device": run.device}))
     return EXIT_OK
@@ -1219,6 +1227,12 @@ def main(argv=None):
     parser.add_argument("--run-dir")
     parser.add_argument("--master")
     parser.add_argument("--child-timeout", type=float, default=300)
+    parser.add_argument(
+        "--phases", default="",
+        help="run only these one-chip phases (kernels, "
+             "train_kill_resume, serve; comma-separated): a measurement, "
+             "so the last line never says ok",
+    )
     opts = parser.parse_args(argv)
     sizes = TOY if opts.rehearsal else REAL
     if opts.child:

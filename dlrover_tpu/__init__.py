@@ -20,4 +20,10 @@ Layering (mirrors SURVEY.md section 1):
   ops/        Pallas TPU kernels + optimizers (flash attn, fused CE, AGD/WSAM)
 """
 
+import time as _time
+
+# the package's first import line, on the wall clock: where a process's
+# ``start.exec`` leg ends and ``start.imports`` begins (common/tracing)
+IMPORT_T = _time.time()
+
 __version__ = "0.1.0"

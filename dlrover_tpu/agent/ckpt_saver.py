@@ -961,7 +961,6 @@ class AsyncCheckpointSaver:
             "ckpt.persist", step=event.step, dur=elapsed,
             shard=local_rank,
         )
-        telemetry.observe("ckpt.persist.seconds", elapsed)
         logger.info(
             "persisted step %s shard %d in %.2fs",
             event.step,

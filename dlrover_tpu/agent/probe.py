@@ -51,6 +51,7 @@ import sys
 import time
 
 from dlrover_tpu.common import backend as backend_mod
+from dlrover_tpu.common import tracing
 from dlrover_tpu.common.chaos import chaos_point
 from dlrover_tpu.common.constants import NodeEnv
 from dlrover_tpu.common.log import get_logger
@@ -349,6 +350,9 @@ def run_json_child(
         [pkg_root]
         + [p for p in env.get("PYTHONPATH", "").split(os.pathsep) if p]
     )
+    # the child's start (interpreter, imports, backend) shows under
+    # the span that waits for it (``rdzv.probe``)
+    tracing.export_ambient(env)
     # spawn seam (dlint DL003): agent.spawn covers workers; this is the
     # payload-child counterpart
     chaos_point("probe.spawn", module=module)

@@ -18,10 +18,12 @@ XLA/libtpu error patterns to hardware-vs-software errors.
 from __future__ import annotations
 
 import dataclasses
+import json
 import os
 import signal
 import subprocess
 import sys
+import threading
 import time
 
 from dlrover_tpu.agent.master_client import MasterClient
@@ -198,11 +200,16 @@ class MasterRendezvousHandler:
 
     def _next_rendezvous(self):
         t0 = time.monotonic()
-        verified_steps = self._local_verified_steps()
+        # (spans of their own: both run before the join RPC and can
+        # take seconds — the scan verifies persisted steps, the first
+        # probe starts a child that brings the chip up)
+        with tracing.span("rdzv.local_steps"):
+            verified_steps = self._local_verified_steps()
         newest = verified_steps[0] if verified_steps else -1
         # probe BEFORE the join: the master's health gate judges these
         # per-leg timings against the fleet and this host's own history
-        probe_report = self._probe_report()
+        with tracing.span("rdzv.probe"):
+            probe_report = self._probe_report()
         joined = self._client.join_rendezvous(
             self._node_rank, self._local_world_size, self._name,
             verified_ckpt_step=newest,
@@ -301,10 +308,31 @@ class WorkerProcess:
         self.proc = proc
         self.local_rank = local_rank
         self.global_rank = global_rank
+        self.spawn_t = time.time()
+        # wall-clock instant the kernel reported the child dead: the
+        # agent's poll finds it up to ``monitor_interval`` later
+        self.died_t: float | None = None
 
     @property
     def returncode(self):
         return self.proc.poll()
+
+    def watch_death(self):
+        """Stamp ``died_t`` where the child dies. The waiter leaves the
+        child waitable (``WNOWAIT``): ``Popen`` reaps it as before."""
+
+        def wait():
+            try:
+                os.waitid(
+                    os.P_PID, self.proc.pid, os.WEXITED | os.WNOWAIT
+                )
+            except OSError:
+                return  # reaped before the waiter looked
+            self.died_t = time.time()
+
+        threading.Thread(
+            target=wait, name=f"death-watch-{self.proc.pid}", daemon=True
+        ).start()
 
 
 # XLA/libtpu stderr patterns that indicate a device (hardware) problem
@@ -366,10 +394,17 @@ class ElasticTrainingAgent:
         config: ElasticLaunchConfig,
         spec: WorkerSpec,
         client: MasterClient,
+        trace: tracing.Legs | None = None,
     ):
         self._config = config
         self._spec = spec
         self._client = client
+        # the trace of the launch or resume under way, from its root
+        # (the launcher's start, a worker's death) to the spawn; the
+        # spawned worker carries it on to its first completed step.
+        # ``trace``: the launcher's own (``trainer/run.py``), so that
+        # a first launch is rooted at the launcher's start
+        self._trace = trace
         self._workers: list[WorkerProcess] = []
         self._restart_count = 0
         self._remaining_restarts = config.max_restarts
@@ -451,9 +486,15 @@ class ElasticTrainingAgent:
         return sorted(steps, reverse=True)
 
     def _initialize_workers(self):
-        rdzv_round, world, rank_offset, total, coordinator = (
-            self._rdzv_handler.next_rendezvous()
-        )
+        if self._trace is None or self._trace.closed:
+            self._trace = tracing.Legs("launch")
+        trace = self._trace
+        # ``rdzv.round`` / ``rdzv.wait`` nest under the leg, and the
+        # master's join/form spans under them through the RPC context
+        with trace.leg(trace.name + ".rendezvous"):
+            rdzv_round, world, rank_offset, total, coordinator = (
+                self._rdzv_handler.next_rendezvous()
+            )
         logger.info(
             "rendezvous round %s: world=%s rank_offset=%s total=%s "
             "restore_step=%s",
@@ -464,7 +505,8 @@ class ElasticTrainingAgent:
             self._rdzv_handler.last_restore_step,
         )
         self._last_round = rdzv_round
-        self._start_worker_processes(rank_offset, total, coordinator)
+        with trace.leg(trace.name + ".spawn"):
+            self._start_worker_processes(rank_offset, total, coordinator)
 
     def _worker_env(self, local_rank: int, global_rank: int, total: int, coordinator: str):
         env = dict(os.environ)
@@ -508,6 +550,14 @@ class ElasticTrainingAgent:
         # master-brokered consensus restore step rides the env so the
         # engine restores exactly the agreed step.
         env[telemetry.ENV_ROLE] = "worker"
+        # the trace crosses the Popen: the worker's start-up legs are
+        # children of the same root, which local rank 0 closes at its
+        # first completed step
+        env.pop(telemetry.ENV_TRACE, None)
+        if self._trace is not None:
+            env[telemetry.ENV_TRACE] = self._trace.export(
+                closes_root=local_rank == 0
+            )
         if self._config.reshape_in_process:
             # per-worker reshape channel: a fresh incarnation must not
             # see the previous incarnation's request/ack/ready files
@@ -585,9 +635,9 @@ class ElasticTrainingAgent:
             )
             log_f.close()
             self._log_files.append(log_path)
-            self._workers.append(
-                WorkerProcess(proc, local_rank, global_rank)
-            )
+            worker = WorkerProcess(proc, local_rank, global_rank)
+            worker.watch_death()
+            self._workers.append(worker)
         logger.info(
             "started %d worker process(es), restart=%d",
             len(self._workers),
@@ -613,10 +663,46 @@ class ElasticTrainingAgent:
         finally:
             self._stopping = False
 
-    def _restart_workers(self):
+    def _restart_workers(self, trace: tracing.Legs | None = None):
+        """``trace``: the ``resume`` a worker's death rooted; a restart
+        nobody died for (a membership change, the master's word) roots
+        its own here."""
         self._restart_count += 1
+        self._trace = trace or tracing.Legs(
+            "resume", labels={
+                "restart": self._restart_count, "exit_kind": "requested",
+            },
+        )
+        self._trace.advance("resume.stop")
         self._stop_workers()
         self._initialize_workers()
+
+    def _resume_trace(self, worker, acted_t, code, kind) -> tracing.Legs:
+        """Root a ``resume`` where ``worker`` died: ``resume.detect``
+        from the death to ``acted_t``, the instant the poll found it,
+        and ``resume.report`` open from there. The labels say what was
+        lost besides: the last step the dead worker published, and
+        when."""
+        died_t = min(worker.died_t or acted_t, acted_t)
+        labels = {
+            "restart": self._restart_count + 1, "exit_kind": kind,
+            "rc": code,
+        }
+        try:
+            with open(os.environ.get(
+                ConfigPath.ENV_RUNTIME_METRICS, ConfigPath.RUNTIME_METRICS
+            )) as f:
+                last = json.load(f)
+            if worker.spawn_t <= last["timestamp"] <= acted_t:
+                labels.update(
+                    last_step=last["step"], last_step_t=last["timestamp"]
+                )
+        except (OSError, ValueError, KeyError, TypeError):
+            pass  # the worker died before its first publish
+        trace = tracing.Legs("resume", died_t, labels=labels)
+        trace.advance("resume.detect")
+        trace.advance("resume.report", t=acted_t)
+        return trace
 
     def _log_tail(self, idx: int, nbytes: int = 4096) -> str:
         try:
@@ -738,6 +824,7 @@ class ElasticTrainingAgent:
             ]
             if failed:
                 idx, code = failed[0]
+                acted_t = time.time()
                 tail = self._log_tail(idx)
                 # NOTE draining never reaches this classify: the drain
                 # path stops its workers synchronously and returns from
@@ -747,9 +834,13 @@ class ElasticTrainingAgent:
                 kind = classify_exit(code, tail, stopping=self._stopping)
                 if kind == "stopped":
                     continue  # our own SIGTERM; the stop path finishes it
+                trace = self._resume_trace(
+                    self._workers[idx], acted_t, code, kind
+                )
                 telemetry.event(
                     "worker.exit", local_rank=idx, rc=code,
                     exit_kind=kind, restart=self._restart_count,
+                    died_t=trace.t0,
                 )
                 logger.warning(
                     "worker %d exited rc=%s (%s)", idx, code, kind
@@ -768,18 +859,21 @@ class ElasticTrainingAgent:
                         "unreachable)"
                     )
                 if self._config.save_at_breakpoint:
+                    trace.advance("resume.breakpoint_save")
                     self._save_ckpt_at_breakpoint()
                 if kind in ("software", "oom") and self._remaining_restarts <= 0:
                     logger.error("restarts exhausted; failing node")
+                    trace.close(status="error")
                     self._client.report_job_end(False, "restarts exhausted")
                     return 1
                 if kind == "hardware":
                     # A device-level fault: exit with the hardware code so
                     # the master relaunches this node elsewhere.
                     logger.error("hardware-level fault; exiting agent")
+                    trace.close(status="error")
                     return ExitCode.DEVICE_ERROR
                 self._remaining_restarts -= 1
-                self._restart_workers()
+                self._restart_workers(trace)
                 continue
             # workers healthy: probe the master cheaply (single-attempt
             # ping) so a coordinator outage is detected and attributed
@@ -1333,8 +1427,10 @@ def launch_agent(
     entrypoint: str,
     args: tuple,
     master_addr: str,
+    trace: tracing.Legs | None = None,
 ) -> int:
-    """Build the client + agent and run (reference launch_agent :673)."""
+    """Build the client + agent and run (reference launch_agent :673).
+    ``trace``: the launcher's own ``launch`` (``trainer/run.py``)."""
     config.auto_configure_params()
     client = MasterClient(
         master_addr, config.node_rank, "worker"
@@ -1354,7 +1450,7 @@ def launch_agent(
             logger.error("node check failed; aborting this node")
             return ExitCode.NETWORK_CHECK_FAILED
     agent = ElasticTrainingAgent(
-        config, WorkerSpec(entrypoint, args, config), client
+        config, WorkerSpec(entrypoint, args, config), client, trace
     )
     try:
         return agent.run()

@@ -35,7 +35,9 @@ IO.
 
 Reserved event fields: ``seq``, ``t`` (wall clock, merge ordering),
 ``mono`` (monotonic, in-process durations), ``kind``, ``dur`` (seconds;
-makes the event an attributable interval ``[t - dur, t]``).
+makes the event an attributable interval ``[t - dur, t]``). An emitter
+may give ``t`` itself for an interval that ended before it was emitted
+(``tracing.Legs``: a leg whose end is learned in retrospect).
 """
 
 from __future__ import annotations
@@ -48,6 +50,7 @@ import threading
 import time
 from collections import deque
 
+from dlrover_tpu import IMPORT_T
 from dlrover_tpu.common.log import get_logger
 
 logger = get_logger(__name__)
@@ -55,6 +58,9 @@ logger = get_logger(__name__)
 ENV_VAR = "DLROVER_TELEMETRY"        # "0"/"false"/"off" disables
 ENV_DIR = "DLROVER_TELEMETRY_DIR"    # set => flush() writes snapshots here
 ENV_ROLE = "DLROVER_TELEMETRY_ROLE"  # worker | agent | master (labeling)
+# the trace a launcher hands the process it spawns: the JSON of
+# ``tracing.Legs.export`` (root ids, name, start, the spawn's instant)
+ENV_TRACE = "DLROVER_TELEMETRY_TRACE"
 
 SNAPSHOT_FORMAT = 1
 MAX_EVENTS = 4096
@@ -295,6 +301,8 @@ class TelemetryRegistry:
             self._seq += 1
             if len(self._events) == MAX_EVENTS:
                 self._dropped += 1
+            # (``t`` among the fields wins: an interval that ended
+            # before it was emitted)
             self._events.append({
                 "seq": self._seq,
                 "t": time.time(),
@@ -482,15 +490,54 @@ def disable():
     _REGISTRY = None
 
 
+# what ``install_from_env`` found of this process's launch: the trace
+# its launcher exported (``ENV_TRACE``; None when nobody did, or with
+# telemetry off). ``tracing`` roots the process's start-up legs on it.
+INHERITED_TRACE: dict | None = None
+
+
+def process_start_time() -> float:
+    """Wall-clock instant the kernel started this process: its
+    ``starttime`` (``/proc/self/stat``, ticks since boot) against the
+    boot clock. The import instant where ``/proc`` cannot say."""
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        age = time.clock_gettime(time.CLOCK_BOOTTIME) - (
+            ticks / os.sysconf("SC_CLK_TCK")
+        )
+    except (OSError, ValueError, IndexError, AttributeError):
+        return IMPORT_T
+    # a sandbox whose /proc counts from another boot than its clock
+    return time.time() - age if 0.0 <= age < 86400.0 else IMPORT_T
+
+
+def _inherited_trace() -> dict | None:
+    raw = os.environ.get(ENV_TRACE, "")
+    if not raw:
+        return None
+    try:
+        ctx = json.loads(raw)
+    except ValueError:
+        return None
+    if not (isinstance(ctx, dict) and ctx.get("trace") and ctx.get("span")):
+        return None
+    return ctx
+
+
 def install_from_env() -> TelemetryRegistry | None:
     """One env read, at import time — never in the hot path. Telemetry is
     ON by default (pure in-memory, bounded); ``DLROVER_TELEMETRY=0``
-    turns every hook into a global-load + is-None branch."""
+    turns every hook into a global-load + is-None branch. Also adopts
+    the launcher's trace (``ENV_TRACE``) for ``tracing``'s start-up
+    legs."""
+    global INHERITED_TRACE
     if os.environ.get(ENV_VAR, "1").strip().lower() in (
         "0", "false", "off", "no",
     ):
         disable()
         return None
+    INHERITED_TRACE = _inherited_trace()
     return enable()
 
 
@@ -987,6 +1034,22 @@ def format_report(report: dict, timeline_tail: int = 40) -> str:
         secs = ledger.get("categories", {}).get(cat, 0.0)
         pct = (secs / total * 100) if total > 0 else 0.0
         lines.append(f"{secs:10.3f}s  {pct:5.1f}%  {cat}")
+        if cat == "restart":
+            # the one number, by leg: each ``resume`` trace from the
+            # worker's death to the new worker's first completed step
+            for resume in report.get("resume_legs") or ():
+                root = resume["root"]
+                lines.append(
+                    f"{'':21}resume {root.get('dur', 0.0):.3f}s "
+                    f"(restart={root.get('restart')} "
+                    f"exit_kind={root.get('exit_kind')} "
+                    f"last_step={root.get('last_step')})"
+                )
+                for leg in resume["legs"]:
+                    lines.append(
+                        f"{'':21}{leg.get('dur', 0.0):10.3f}s  "
+                        f"{leg.get('name')}  <{leg.get('source', '?')}>"
+                    )
     timeline = report.get("timeline", [])
     lines.append("")
     lines.append(f"=== event timeline (last {min(timeline_tail, len(timeline))}"

@@ -10,6 +10,7 @@ import argparse
 import os
 import sys
 
+from dlrover_tpu.common import tracing
 from dlrover_tpu.common.chaos import chaos_point
 from dlrover_tpu.common.constants import PlatformType
 from dlrover_tpu.common.log import get_logger
@@ -140,6 +141,9 @@ def run(args) -> int:
         os.replace(tmp, args.addr_file)
     # Print the bound address so a parent (tpu-run) can discover the port.
     print(f"DLROVER_MASTER_ADDR={addr}", flush=True)
+    # the master serves: its start (``start.exec``, ``start.imports``)
+    # is over, under the launcher's trace where ``tpu-run`` handed one
+    tracing.start_done()
     return master.run()
 
 

@@ -544,7 +544,6 @@ class CheckpointEngine:
         telemetry.event(
             "ckpt.save", step=step, dur=elapsed, mb=offset / 1e6
         )
-        telemetry.observe("ckpt.save.seconds", elapsed)
         # fault site AFTER the shm save committed: a kill here is the
         # canonical "worker dies right after checkpointing step N" —
         # the agent-held shm segment must carry the restore
@@ -816,7 +815,6 @@ class CheckpointEngine:
         if consensus is not None:
             fields["consensus"] = consensus
         telemetry.event("ckpt.restore", **fields)
-        telemetry.observe("ckpt.restore.seconds", fields["dur"])
         _publish_restore_stats(self.last_restore_stats)
 
     def _load_from_memory(self, target=None, zero_copy: bool = False):

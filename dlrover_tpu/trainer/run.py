@@ -25,6 +25,7 @@ from dlrover_tpu.agent.training_agent import (
     ElasticLaunchConfig,
     launch_agent,
 )
+from dlrover_tpu.common import telemetry, tracing
 from dlrover_tpu.common.chaos import chaos_point
 from dlrover_tpu.common.constants import NodeEnv
 from dlrover_tpu.common.log import get_logger
@@ -83,10 +84,16 @@ def _parse_nnodes(nnodes: str) -> tuple[int, int]:
     return n, n
 
 
-def _launch_local_master(node_num: int) -> tuple[subprocess.Popen, str]:
+def _launch_local_master(
+    node_num: int, trace: tracing.Legs | None = None,
+) -> tuple[subprocess.Popen, str]:
     """Spawn a local master subprocess (reference
-    _launch_dlrover_local_master :230)."""
+    _launch_dlrover_local_master :230). ``trace`` crosses the Popen as
+    it does to a worker: the master's own start is part of the launch."""
     port = find_free_port()
+    env = dict(os.environ)
+    if trace is not None:
+        env[telemetry.ENV_TRACE] = trace.export(closes_root=False)
     # spawn seam (dlint DL003): agent.spawn covers workers; this is
     # the master-process counterpart
     chaos_point("master.spawn", port=port)
@@ -102,6 +109,7 @@ def _launch_local_master(node_num: int) -> tuple[subprocess.Popen, str]:
             "--node_num",
             str(node_num),
         ],
+        env=env,
         stdout=subprocess.DEVNULL,
         stderr=None,
     )
@@ -119,6 +127,13 @@ def _launch_local_master(node_num: int) -> tuple[subprocess.Popen, str]:
 
 
 def run(args) -> int:
+    # this process is the launch's root: its own start-up legs
+    # (``start.exec``, ``start.imports`` up to here) and then the
+    # launcher's and the agent's, up to the spawn
+    # (telemetry off: legs of their own that emit nothing)
+    trace = tracing.startup() or tracing.Legs("launch")
+    trace.filler = None  # the agent's legs follow one another
+    trace.advance("launch.agent")
     min_nodes, max_nodes = _parse_nnodes(args.nnodes)
     node_rank = (
         args.node_rank
@@ -133,7 +148,11 @@ def run(args) -> int:
                 "master %s not reachable; starting a local one", master_addr
             )
         if node_rank == 0:
-            master_proc, master_addr = _launch_local_master(min_nodes)
+            trace.advance("launch.master")
+            master_proc, master_addr = _launch_local_master(
+                min_nodes, trace
+            )
+            trace.advance("launch.agent")
             os.environ[NodeEnv.DLROVER_MASTER_ADDR] = master_addr
         else:
             raise RuntimeError(
@@ -163,7 +182,8 @@ def run(args) -> int:
         script_args = script_args[1:]
     try:
         return launch_agent(
-            config, args.training_script, tuple(script_args), master_addr
+            config, args.training_script, tuple(script_args), master_addr,
+            trace,
         )
     finally:
         if master_proc is not None and master_proc.poll() is None:

@@ -19,7 +19,7 @@ import os
 import time
 from typing import Any, Callable, Iterable, Optional
 
-from dlrover_tpu.common import flight, telemetry, tracing
+from dlrover_tpu.common import backend, flight, telemetry, tracing
 from dlrover_tpu.common.chaos import chaos_point
 from dlrover_tpu.common.log import get_logger
 from dlrover_tpu.parallel.accelerate import auto_accelerate
@@ -107,6 +107,7 @@ class Trainer:
     empty id -> slot mapper and silently scramble the embeddings.
     """
 
+    @tracing.start_leg("start.trainer_init")
     def __init__(
         self,
         loss_fn: Callable,
@@ -384,7 +385,15 @@ class Trainer:
         # post-mortem coverage for the worker: a SIGTERM (preemption,
         # agent stop) dumps the last spans/events + thread stacks
         flight.install()
-        resumed = self.maybe_resume()
+        with tracing.start_leg("start.restore") as leg:
+            resumed = self.maybe_resume()
+            if leg is not None and self._engine is not None:
+                stats = self._engine.last_restore_stats
+                leg.annotate(**{
+                    k: stats[k] for k in ("read_s", "verify_s", "h2d_s")
+                    if k in stats
+                })
+        backend.begin_compile_leg(self)
         metrics = {}
         shm_saves = 0
         # a job resumed at/after max_steps is already done: don't train
@@ -591,6 +600,7 @@ class Trainer:
             # wall time is no step-time/MFU sample, and one giant first
             # point would poison the SLO watchdog's rolling baselines
             telemetry.event("compile", step=number, dur=dur_s)
+            backend.first_step_done()
             return
         telemetry.event("step.end", step=number, dur=dur_s)
         if dur_s <= 0:
@@ -721,10 +731,6 @@ class Trainer:
         except Exception:  # noqa: BLE001 - a non-standard state tree
             # just loses the MFU gauge, never the training loop
             self._flops_per_token = 0.0
-        # compile-cache stats ride the same once-per-(re)shape cadence:
-        # a reshape's re-jit is a cache replay, and the gauge pair
-        # shows whether the persistent cache is actually being reused
-        self._emit_compile_cache_gauges()
 
     def _refresh_prof_context(self):
         """The op-cost baseline key (model fingerprint + mesh shape),
@@ -741,24 +747,6 @@ class Trainer:
         except Exception:  # noqa: BLE001 - a non-standard state tree
             # only loses baseline keying, never the training loop
             self._prof.set_context("unfingerprinted", "devices=?")
-
-    def _emit_compile_cache_gauges(self):
-        import jax
-
-        cache_dir = jax.config.jax_compilation_cache_dir or ""
-        if not os.path.isdir(cache_dir):
-            return
-        entries = size = 0
-        try:
-            with os.scandir(cache_dir) as it:
-                for de in it:
-                    if de.is_file():
-                        entries += 1
-                        size += de.stat().st_size
-        except OSError:
-            return
-        telemetry.gauge_set("compile.cache.entries", entries)
-        telemetry.gauge_set("compile.cache.bytes", size)
 
     def _emit_device_gauges(self):
         """Per-device HBM gauges from ``device.memory_stats()`` where

@@ -171,3 +171,17 @@ def profiled_spans(tmp_path):
         return tracing.spans_from_xplane(path)
 
     return run
+
+
+@pytest.fixture(scope="session", autouse=True)
+def _test_runner_is_no_launch():
+    """A process roots a ``launch`` trace at its own start and cuts it
+    into ``start.*`` legs up to its first completed train step
+    (``tracing.startup``). A test runner is no launch: the first
+    Trainer of whichever test came first would close a root as old as
+    the session. Tests of the legs arm their own
+    (``tracing.reset_startup``)."""
+    from dlrover_tpu.common import tracing
+
+    tracing.reset_startup(None)
+    yield

@@ -156,3 +156,113 @@ class TestRestartRecompileFromCache:
             "second run recompiled (new cache entries) instead of "
             "hitting the cache"
         )
+
+
+# -------------------------------------------------------------------------
+# the runtime's own report of its compiles (jax.monitoring, heard in
+# common/backend): counters, start.compile's labels, compile.late
+# -------------------------------------------------------------------------
+
+
+class _Batches:
+    """``steps`` batches of 4 rows; from step ``wider_from`` on, of 8."""
+
+    def __init__(self, steps, wider_from=None):
+        self.steps, self.wider_from = steps, wider_from
+
+    def __iter__(self):
+        import numpy as np
+
+        for step in range(1, self.steps + 1):
+            rows = 8 if self.wider_from and step >= self.wider_from else 4
+            yield np.ones((rows, 4), np.float32)
+
+
+@pytest.fixture
+def start_legs():
+    """A process's start-up legs, armed as at its import (the test
+    runner dropped its own: conftest), and a fresh registry."""
+    from dlrover_tpu.common import telemetry, tracing
+
+    prev_registry = telemetry.active_registry()
+    registry = telemetry.enable("compile-report")
+    legs = tracing.Legs(
+        "launch", order=tracing.START_LEGS, filler=tracing.START_FILLER
+    )
+    legs.advance("start.imports")
+    prev_legs = tracing.reset_startup(legs)
+    yield registry
+    tracing.reset_startup(prev_legs)
+    telemetry._REGISTRY = prev_registry
+
+
+def _train(tmp_path, steps, wider_from=None):
+    import jax.numpy as jnp
+
+    from dlrover_tpu.trainer.trainer import Trainer, TrainingArgs
+
+    backend.require_backend()
+    trainer = Trainer(
+        lambda params, batch, rng: jnp.mean((batch @ params["w"]) ** 2),
+        lambda rng: {"w": jnp.ones((4, 1))},
+        {"w": (None, None)},
+        TrainingArgs(
+            output_dir=str(tmp_path / "out"), max_steps=steps,
+            flash_checkpoint=False, log_steps=0,
+        ),
+        train_data=_Batches(steps, wider_from),
+    )
+    trainer.train()
+    trainer.close()
+
+
+class TestCompileReport:
+    def test_a_late_compile_names_its_step(self, tmp_path, start_legs):
+        _train(tmp_path, steps=6, wider_from=4)
+        events = start_legs.snapshot()["events"]
+        late = [e for e in events if e["kind"] == "compile.late"]
+        # the second batch shape recompiles the step program at step 4
+        # (and whatever small program the wider batch brings with it)
+        assert late and {e["step"] for e in late} == {4}
+        step = [e for e in late if e["program"] == "jit(train_step)"]
+        assert len(step) == 1 and step[0]["dur"] > 0
+        # the incarnation's first step keeps its own kind, once
+        assert [e["step"] for e in events if e["kind"] == "compile"] == [1]
+
+    def test_a_run_of_one_shape_leaves_none(self, tmp_path, start_legs):
+        _train(tmp_path, steps=6)
+        snap = start_legs.snapshot()
+        assert not [e for e in snap["events"] if e["kind"] == "compile.late"]
+        spans = {e["name"]: e for e in snap["events"] if e["kind"] == "span"}
+        # the legs closed at the first completed step, compile before
+        # first_step, with what the counters gained as labels
+        compile_leg, first = spans["start.compile"], spans["start.first_step"]
+        assert compile_leg["program"] == "jit(train_step)"
+        assert compile_leg["hit"] in (0, 1)
+        assert compile_leg["backend_s"] > 0 and compile_leg["trace_s"] > 0
+        assert first["t"] - first["dur"] == pytest.approx(compile_leg["t"])
+        assert spans["launch"]["t"] == pytest.approx(first["t"])
+        counters = {c["name"]: c["value"] for c in snap["counters"]}
+        assert counters["compile.backend_s"] >= compile_leg["backend_s"]
+        assert counters["compile.trace_s"] > 0
+        assert counters["compile.lower_s"] > 0
+        assert counters["start.compile_s"] == pytest.approx(
+            compile_leg["dur"]
+        )
+
+    def test_no_legs_no_late_events(self, tmp_path):
+        """A process that is no launch (the test runner): a compile
+        after a first step is nobody's ``compile.late``."""
+        from dlrover_tpu.common import telemetry, tracing
+
+        assert tracing.startup() is None
+        prev = telemetry.active_registry()
+        registry = telemetry.enable("no-legs")
+        try:
+            _train(tmp_path, steps=5, wider_from=3)
+        finally:
+            telemetry._REGISTRY = prev
+        kinds = {e["kind"] for e in registry.snapshot()["events"]}
+        assert "compile.late" not in kinds and "compile" in kinds
+        assert not [e for e in registry.snapshot()["events"]
+                    if e.get("name", "").startswith("start.")]

@@ -35,6 +35,182 @@ def _span_events(snap):
 
 
 # -------------------------------------------------------------------------
+# legs: a root cut into consecutive children, across a Popen
+# -------------------------------------------------------------------------
+
+_CHILD = """
+import json, sys
+from dlrover_tpu.common import telemetry, tracing
+
+with tracing.span("child.work"):
+    pass
+legs = tracing.startup()
+json.dump({
+    "events": telemetry.snapshot()["events"],
+    "root": [legs.name, legs.trace, legs.span, legs.t0, legs.closes_root],
+    "open": legs.open_name, "import_t": telemetry.IMPORT_T,
+}, sys.stdout)
+"""
+
+
+def _run_child(env_trace=None):
+    env = dict(os.environ)
+    env.pop(telemetry.ENV_TRACE, None)
+    env.pop(telemetry.ENV_DIR, None)
+    if env_trace is not None:
+        env[telemetry.ENV_TRACE] = env_trace
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env["PYTHONPATH"] = repo
+    spawn_t = time.time()
+    out = subprocess.run(
+        [sys.executable, "-c", _CHILD], env=env, capture_output=True,
+        text=True, timeout=120,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    return spawn_t, json.loads(out.stdout)
+
+
+class TestLegs:
+    def test_legs_tile_the_root_and_each_is_a_counter(
+        self, fresh_telemetry
+    ):
+        t0 = time.time() - 5.0
+        legs = tracing.Legs("resume", t0, labels={"restart": 2})
+        legs.advance("resume.detect")
+        legs.advance("resume.report", t=t0 + 4.0)  # in retrospect
+        with legs.leg("resume.rendezvous"):
+            with tracing.span("rdzv.round"):
+                pass
+            inside = tracing.current()
+        assert tracing.current() is None
+        legs.advance("resume.spawn")
+        legs.close(outcome="ok")
+        legs.close()  # closed is closed
+        assert not legs.advance("resume.more")
+        snap = fresh_telemetry.snapshot()
+        spans = {e["name"]: e for e in _span_events(snap)}
+        root = spans["resume"]
+        assert root["parent"] == "" and root["restart"] == 2
+        assert root["t"] - root["dur"] == pytest.approx(t0)
+        order = ["resume.detect", "resume.report", "resume.rendezvous",
+                 "resume.spawn"]
+        children = [spans[n] for n in order]
+        assert all(c["parent"] == root["span"] for c in children)
+        assert all(c["trace"] == root["trace"] for c in children)
+        assert children[0]["dur"] == pytest.approx(4.0)
+        # each begins where the one before it ended; together the root
+        for before, after in zip(children, children[1:]):
+            assert after["t"] - after["dur"] == pytest.approx(before["t"])
+        assert sum(c["dur"] for c in children) == pytest.approx(root["dur"])
+        # a block leg is the ambient parent of what it holds
+        assert inside == {"trace": legs.trace,
+                          "span": spans["resume.rendezvous"]["span"]}
+        assert spans["rdzv.round"]["parent"] == inside["span"]
+        counters = {c["name"]: c["value"] for c in snap["counters"]}
+        for name in (*order, "resume"):
+            assert counters[name + "_s"] == pytest.approx(spans[name]["dur"])
+
+    def test_order_is_kept_and_the_filler_takes_the_gaps(
+        self, fresh_telemetry
+    ):
+        legs = tracing.Legs(
+            "launch", order=tracing.START_LEGS, filler=tracing.START_FILLER,
+        )
+        assert legs.advance("start.exec")
+        assert legs.advance("start.backend")       # may skip ahead
+        assert not legs.advance("start.imports")   # never back
+        assert not legs.advance("start.backend")   # nor twice
+        with legs.leg("start.trainer_init"):
+            pass
+        assert legs.open_name == tracing.START_FILLER
+        with legs.leg("start.trainer_init") as again:
+            assert again is None                   # a second Trainer
+        legs.close()
+        names = [e["name"] for e in _span_events(fresh_telemetry.snapshot())]
+        assert names == ["start.exec", "start.backend",
+                         "start.trainer_init", "start.script", "launch"]
+
+    def test_context_survives_a_popen(self, fresh_telemetry):
+        legs = tracing.Legs("resume", labels={"restart": 1})
+        with legs.leg("resume.spawn"):
+            spawn_t, child = _run_child(legs.export(closes_root=True))
+        name, trace, span, t0, closes_root = child["root"]
+        assert (name, trace, span) == ("resume", legs.trace, legs.span)
+        assert t0 == pytest.approx(legs.t0) and closes_root
+        spans = {e["name"]: e for e in child["events"]
+                 if e["kind"] == tracing.SPAN_EVENT}
+        # its first leg: a child of the SAME root, from the spawn's
+        # instant to the package's first import line
+        exec_leg = spans["start.exec"]
+        assert exec_leg["trace"] == legs.trace
+        assert exec_leg["parent"] == legs.span
+        assert exec_leg["t"] == pytest.approx(child["import_t"])
+        assert exec_leg["t"] - exec_leg["dur"] == pytest.approx(
+            spawn_t, abs=0.05
+        )
+        # and a span of its own lies in the parent's trace, under the
+        # leg that was open (the adopted context is ambient)
+        work = spans["child.work"]
+        assert work["trace"] == legs.trace and work["parent"]
+        assert child["open"] == "start.imports"
+        assert work["parent"] not in (legs.span, exec_leg["span"])
+
+    def test_a_payload_child_starts_under_the_span_that_waits_for_it(
+        self, fresh_telemetry
+    ):
+        with tracing.span("rdzv.probe") as waiting:
+            env = tracing.export_ambient({telemetry.ENV_TRACE: "stale"})
+            _t, child = _run_child(env[telemetry.ENV_TRACE])
+        (exec_leg,) = [e for e in child["events"]
+                       if e.get("name") == "start.exec"]
+        assert exec_leg["trace"] == waiting.trace
+        assert exec_leg["parent"] == waiting.span
+        assert not child["root"][4]  # the child closes no root
+        # outside any span nothing is handed on, not even what this
+        # process was handed itself
+        assert tracing.export_ambient({telemetry.ENV_TRACE: "stale"}) == {}
+
+    def test_a_process_nobody_launched_roots_its_own_at_its_start(self):
+        spawn_t, child = _run_child()
+        name, _trace, _span, t0, closes_root = child["root"]
+        assert name == "launch" and closes_root
+        # /proc/self/stat's start time: after the spawn, before the import
+        assert spawn_t - 0.05 <= t0 <= child["import_t"]
+        (exec_leg,) = [e for e in child["events"]
+                       if e.get("name") == "start.exec"]
+        assert exec_leg["t"] - exec_leg["dur"] == pytest.approx(t0)
+        # nothing is ambient there: its spans root their own traces
+        (work,) = [e for e in child["events"]
+                   if e.get("name") == "child.work"]
+        assert work["parent"] == "" and work["trace"] != exec_leg["trace"]
+
+    def test_a_malformed_trace_variable_is_no_trace(self):
+        _t, child = _run_child("{not json")
+        assert child["root"][0] == "launch"
+
+    def test_root_legs_lists_each_resume_by_leg(self, fresh_telemetry):
+        for restart in (1, 2):
+            legs = tracing.Legs("resume", labels={"restart": restart})
+            legs.advance("resume.detect")
+            legs.advance("resume.stop")
+            legs.close()
+        tracing.Legs("launch").close()
+        jt = JobTelemetry()
+        jt.update(fresh_telemetry.snapshot())
+        found = tracing.root_legs(jt.merged_events())
+        assert [r["root"]["restart"] for r in found] == [1, 2]
+        assert [[e["name"] for e in r["legs"]] for r in found] == [
+            ["resume.detect", "resume.stop"]
+        ] * 2
+        text = telemetry.format_report(
+            {**jt.report(), "resume_legs": found}
+        )
+        ledger = text.split("=== event timeline")[0].splitlines()
+        at = next(i for i, l in enumerate(ledger) if l.endswith("restart"))
+        assert "resume.detect" in ledger[at + 2]
+
+
+# -------------------------------------------------------------------------
 # span semantics
 # -------------------------------------------------------------------------
 

@@ -82,6 +82,9 @@ def build_report(
         report.get("metrics", {}), report.get("timeline", [])
     )
     report["health"] = _health_summary(report.get("timeline", []))
+    from dlrover_tpu.common.tracing import root_legs
+
+    report["resume_legs"] = root_legs(report.get("timeline", []))
     if trace_dir:
         try:
             from dlrover_tpu.common.trace_summary import summarize
