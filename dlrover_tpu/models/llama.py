@@ -295,7 +295,7 @@ def _maybe_full_rope(config, cos, sin):
 
 
 def _sharded_flash(config: LlamaConfig, qt, kt, vt, layout: str = "bhsd",
-                   rope_cos=None, rope_sin=None, sm_scale=None):
+                   rope_cos=None, rope_sin=None, sm_scale=None, window=None):
     """pallas_call does not auto-partition under GSPMD: without an explicit
     shard_map, jit would all-gather q/k/v to run the kernel replicated.
     Map the kernel over the mesh's batch/head axes (seq stays local here —
@@ -319,7 +319,7 @@ def _sharded_flash(config: LlamaConfig, qt, kt, vt, layout: str = "bhsd",
             q, k, v, causal=True, sm_scale=sm_scale,
             block_q=config.attn_block_q, block_k=config.attn_block_k,
             bwd_block_q=config.attn_bwd_block_q,
-            bwd_block_k=config.attn_bwd_block_k,
+            bwd_block_k=config.attn_bwd_block_k, window=window,
             **extra,
         )
 
@@ -375,9 +375,10 @@ def flash_einsum_path(config) -> bool:
 
 
 def bhsd_flash_attention(config, qt, kt, vt, rope_cos=None, rope_sin=None,
-                         sm_scale=None):
+                         sm_scale=None, window=None):
     """Shard + run the Pallas flash kernel on [B,H,S,Dh] operands.
-    ``sm_scale`` None is the kernels' ``1 / sqrt(head_dim)``.
+    ``sm_scale`` None is the kernels' ``1 / sqrt(head_dim)``; ``window``
+    a sliding window (``flash_attention``'s).
 
     With ``rope_cos``/``rope_sin`` (full-width [B,S,Dh] tables), rope is
     fused into the kernels (q/k passed raw, dq/dk un-roped on the way
@@ -386,7 +387,7 @@ def bhsd_flash_attention(config, qt, kt, vt, rope_cos=None, rope_sin=None,
     kt = shard_logical(kt, ("batch", "kv_heads", "seq", "head_dim"))
     vt = shard_logical(vt, ("batch", "kv_heads", "seq", "head_dim"))
     return _sharded_flash(config, qt, kt, vt, rope_cos=rope_cos,
-                          rope_sin=rope_sin, sm_scale=sm_scale)
+                          rope_sin=rope_sin, sm_scale=sm_scale, window=window)
 
 
 def _seq_axis_active() -> bool:

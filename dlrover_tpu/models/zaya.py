@@ -74,12 +74,9 @@ from dlrover_tpu.models.llama import (
 )
 from dlrover_tpu.ops.cross_entropy import fused_linear_cross_entropy
 from dlrover_tpu.ops.fp8 import qdot
-from dlrover_tpu.parallel.moe import routed_experts
+from dlrover_tpu.parallel.moe import COUNTS, routed_counts, routed_experts
 from dlrover_tpu.parallel.sharding import shard_logical
 
-# the counts a step hands back beside its loss, in the carry's order
-COUNTS = ("moe.tokens_routed", "moe.tokens_held",
-          "moe.expert_load_max", "moe.expert_load_mean")
 
 @dataclasses.dataclass(frozen=True)
 class ZayaConfig:
@@ -432,19 +429,6 @@ def _choose(probs, p):
     return choice, weight
 
 
-def _counts(config, choice):
-    """This layer's (tokens routed, tokens held here, the largest and
-    the mean load of a held expert), float32 [4]."""
-    first, count = config.held
-    experts = first + jnp.arange(count, dtype=jnp.int32)
-    load = jnp.sum(choice[..., None] == experts,
-                   axis=tuple(range(choice.ndim)), dtype=jnp.float32)
-    return jnp.stack([
-        jnp.asarray(choice.size, jnp.float32), jnp.sum(load),
-        jnp.max(load), jnp.mean(load),
-    ])
-
-
 def _layer_fn(config: ZayaConfig):
     """``stage_layer_scan``'s layer body over the carry ``(x, r,
     counts)``. A forward pass that wants its choices of expert back
@@ -467,7 +451,7 @@ def _layer_fn(config: ZayaConfig):
             (rows, at), = chosen
             chosen = [(jax.lax.dynamic_update_index_in_dim(
                 rows, choice, at, 0), at + 1)]
-        carry = (x, r, counts + _counts(config, choice), *chosen)
+        carry = (x, r, counts + routed_counts(choice, config.held), *chosen)
         return carry, jnp.zeros((), jnp.float32)
 
     return layer

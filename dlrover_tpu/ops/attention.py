@@ -217,6 +217,18 @@ def _unrope_tile(g, cos_ref, sin_ref):
     return g * c - rot
 
 
+def _flash_call(name, kernel, **kwargs):
+    """``named_pallas_call`` for a flash kernel. Where a sliding window
+    is set its name takes ``_window`` after ``flash_fwd`` /
+    ``flash_bwd`` (``flash_fwd_window``, ``flash_bwd_window_dq``): what
+    reads ``^flash_fwd`` / ``^flash_bwd`` still sees all of attention,
+    and the window's kernels can be read alone."""
+    if _MaskCtx.window is not None:
+        stem = len("flash_fwd")
+        name = f"{name[:stem]}_window{name[stem:]}"
+    return named_pallas_call(name, kernel, **kwargs)
+
+
 def _compiler_params(dims):
     return pltpu.CompilerParams(dimension_semantics=dims)
 
@@ -605,7 +617,7 @@ def _fwd(q, k, v, layout, heads, kv_heads, sm_scale, causal, block_q,
         out_specs=(q_spec, row_spec),
         scratch_shapes=scratch_shapes,
     )
-    o, lse = named_pallas_call(
+    o, lse = _flash_call(
         "flash_fwd",
         kernel,
         grid_spec=grid_spec,
@@ -858,7 +870,7 @@ def _fwd_fused(q, k, v, heads, kv_heads, sm_scale, causal, block_q,
         (1, block_k, k.shape[2]), lambda b, t, m: (b, m[1, t], 0))
     lse_spec = pl.BlockSpec(
         (1, heads, block_q, STATS_W), lambda b, t, m: (b, 0, m[0, t], 0))
-    o, lse = named_pallas_call(
+    o, lse = _flash_call(
         "flash_fwd",
         functools.partial(
             _fwdf_kernel, sm_scale=sm_scale, causal=causal,
@@ -911,7 +923,7 @@ def _bwd_fused(heads, kv_heads, sm_scale, causal, block_q, block_k,
 
     meta_q = jnp.asarray(_tile_meta(
         nq, nk, block_q, block_k, q_len, kv_len, causal, False))
-    dq = named_pallas_call(
+    dq = _flash_call(
         "flash_bwd_dq",
         functools.partial(
             _bwdf_dq_kernel, sm_scale=sm_scale, causal=causal,
@@ -933,7 +945,7 @@ def _bwd_fused(heads, kv_heads, sm_scale, causal, block_q, block_k,
 
     meta_kv = jnp.asarray(_tile_meta(
         nq, nk, block_q, block_k, q_len, kv_len, causal, True))
-    dk, dv = named_pallas_call(
+    dk, dv = _flash_call(
         "flash_bwd_dkv",
         functools.partial(
             _bwdf_dkv_kernel, sm_scale=sm_scale, causal=causal,
@@ -1154,7 +1166,7 @@ def _delta_bhsd(do, o, block_q, interpret):
         (1, 1, block_q, head_dim), lambda b, h, i: (b, h, i, 0))
     out_spec = pl.BlockSpec(
         (1, 1, block_q, STATS_W), lambda b, h, i: (b, h, i, 0))
-    return named_pallas_call(
+    return _flash_call(
         "flash_bwd_delta",
         _delta_kernel,
         grid=(batch, H, pl.cdiv(q_len, block_q)),
@@ -1320,7 +1332,7 @@ def _bwd_onepass(layout, H, KVH, q_len, kv_len, head_dim, sm_scale,
         in_specs += _rope_specs(block_q, block_k, head_dim)
         operands += [rope_cos, rope_sin, rope_cos, rope_sin]
         scratch.append(pltpu.VMEM((block_k, head_dim), k.dtype))
-    dq, dk_full, dv_full = named_pallas_call(
+    dq, dk_full, dv_full = _flash_call(
         "flash_bwd_fused",
         functools.partial(
             _bwd_onepass_kernel, sm_scale=sm_scale, causal=causal,
@@ -1414,7 +1426,7 @@ def _bwd(layout, heads, kv_heads, sm_scale, causal, block_q, block_k,
 
     meta_q = jnp.asarray(_tile_meta(
         nq, nk, block_q, block_k, q_len, kv_len, causal, False))
-    dq = named_pallas_call(
+    dq = _flash_call(
         "flash_bwd_dq",
         functools.partial(
             _bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
@@ -1450,7 +1462,7 @@ def _bwd(layout, heads, kv_heads, sm_scale, causal, block_q, block_k,
     else:
         kv_out_shape = (batch, kv_len, H * head_dim)
     kv_out_spec = _kv_out(layout, block_k=block_k, head_dim=head_dim)
-    dk_full, dv_full = named_pallas_call(
+    dk_full, dv_full = _flash_call(
         "flash_bwd_dkv",
         functools.partial(
             _bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
@@ -1922,8 +1934,11 @@ def flash_attention_bshd(
     return o3.reshape(B, S, H, hd)
 
 
-def mha_reference(q, k, v, causal: bool = True, sm_scale: float | None = None):
-    """Plain-XLA reference attention (testing + tiny shapes)."""
+def mha_reference(q, k, v, causal: bool = True,
+                  sm_scale: float | None = None, window: int | None = None):
+    """Plain-XLA reference attention (testing + tiny shapes); ``window``
+    as :func:`flash_attention`'s."""
+    _check_mask_extras(causal, window, None)
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     group = q.shape[1] // k.shape[1]
@@ -1936,6 +1951,8 @@ def mha_reference(q, k, v, causal: bool = True, sm_scale: float | None = None):
     if causal:
         q_len, k_len = s.shape[-2], s.shape[-1]
         mask = jnp.tril(jnp.ones((q_len, k_len), bool), k_len - q_len)
+        if window is not None:
+            mask &= ~jnp.tril(mask, k_len - q_len - window)
         s = jnp.where(mask, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32)).astype(q.dtype)

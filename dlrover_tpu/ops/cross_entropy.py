@@ -135,13 +135,13 @@ def _chunked_ce_fwd_scan(cfg, h, w, norm_scale, labels, pinned=False):
     return loss_sum, valid_sum
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _chunked_ce(cfg, h, w, norm_scale, labels):
-    return _chunked_ce_fwd_scan(cfg, h, w, norm_scale, labels)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _chunked_ce(cfg, pinned, h, w, norm_scale, labels):
+    return _chunked_ce_fwd_scan(cfg, h, w, norm_scale, labels, pinned)
 
 
-def _chunked_ce_fwd(cfg, h, w, norm_scale, labels):
-    out = _chunked_ce_fwd_scan(cfg, h, w, norm_scale, labels)
+def _chunked_ce_fwd(cfg, pinned, h, w, norm_scale, labels):
+    out = _chunked_ce_fwd_scan(cfg, h, w, norm_scale, labels, pinned)
     # residuals are the INPUTS only — exactly what the old
     # nothing-saveable jax.checkpoint kept, minus its custom-call
     return out, (h, w, norm_scale, labels)
@@ -183,7 +183,7 @@ def _no_cotangent(labels):
     return np.zeros(labels.shape, dtype=jax.dtypes.float0)
 
 
-def _chunked_ce_bwd(cfg, res, cts):
+def _chunked_ce_bwd(cfg, pinned, res, cts):
     h, w, norm_scale, labels = res
     g_loss, _g_valid = cts  # valid_sum is integer: float0 cotangent
     grads = _chunk_grads(cfg, h, w, norm_scale, labels)
@@ -260,6 +260,7 @@ _chunked_ce_per_chip.defvjp(_chunked_ce_per_chip_fwd, _chunked_ce_per_chip_bwd)
 def fused_linear_cross_entropy(
     h, w, labels, n_chunks: int = 8, norm_fn=None,
     ignore_index: int = -100, norm_scale=None, norm_eps: float = 1e-5,
+    pinned: bool = False,
 ):
     """CE of ``softmax(norm(h) @ w)`` without materialising the full
     [B, S, V] logits.
@@ -299,6 +300,11 @@ def fused_linear_cross_entropy(
     every chunk of both loops and all-reduced each chunk's gradient
     (``olmo-hybrid-7b.steady``: 24 crossings of 771 MB a step, 175 ms
     of 1285; PERF.md, PR 40).
+
+    ``pinned``: the plain form's forward pins each chunk's bf16 logits
+    as the per-chip form always does, so that the label's pick and the
+    log-sum-exp read one rounding (a model whose cell's step program
+    is held to a fingerprint from before the pin leaves it off).
 
     Returns ``(loss_sum, valid_count)`` over all tokens.
     """
@@ -350,7 +356,7 @@ def fused_linear_cross_entropy(
     cfg = (n, int(ignore_index), float(norm_eps), use_norm)
     split = _head_mesh(B)
     if split[1] is None:
-        return _chunked_ce(cfg, h, w, norm_scale, labels)
+        return _chunked_ce(cfg, pinned, h, w, norm_scale, labels)
     return _chunked_ce_per_chip(cfg, split, h, w, norm_scale, labels)
 
 

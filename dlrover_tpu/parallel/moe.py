@@ -19,18 +19,20 @@ einsum formulation instead of a translated all-to-all:
 Everything is differentiable jnp; no process groups, no custom autograd.
 
 :func:`routed_experts` is the other form: no capacity and no dropped
-token. Tokens are sorted by their expert, the experts run as grouped
-matmuls over groups of unequal size, and the results go back to the
-tokens' places times the router's weight. The layer is told which
-experts of the model's it holds (``held = (first, count)``): it takes
-choices over all of them and computes its own experts' part of the
-result. The exchange over an ``expert`` mesh axis is not here yet, and
-nothing stands in for it.
+token. A token's ``k`` choices are ``k`` token-expert pairs; the pairs
+are sorted by their expert, the experts run as grouped matmuls over
+groups of unequal size, and the results go back to the pairs' places
+times the router's weight, a token's pairs summed into its row. The
+layer is told which experts of the model's it holds (``held = (first,
+count)``): it takes choices over all of them and computes its own
+experts' part of the result. The exchange over an ``expert`` mesh axis
+is not here yet, and nothing stands in for it.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
 import jax
@@ -39,7 +41,7 @@ import jax.numpy as jnp
 from dlrover_tpu.parallel.sharding import shard_logical
 
 __all__ = ["MoEConfig", "top_k_gating", "moe_ffn", "moe_init",
-           "routed_experts"]
+           "routed_experts", "routed_counts", "COUNTS"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -185,13 +187,37 @@ def _permute_bwd(res, g):
 _permute.defvjp(_permute_fwd, _permute_bwd)
 
 
-def _routed_rows(h, choice, weight, w_in, w_out, first):
-    """The op on one device's rows: h [N, D], choice [N], weight [N]."""
-    n, count = h.shape[0], w_in.shape[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _pairs(h, order, inverse, k):
+    """The rows of the pairs in ``order``: pair ``i`` is token ``i //
+    k``'s. The cotangent goes back as a gather to the pairs' own order
+    and a sum over a token's ``k``, where the transpose of the gather
+    would be a scatter-add."""
+    return jnp.take(h, order // k, axis=0)
+
+
+def _pairs_fwd(h, order, inverse, k):
+    return _pairs(h, order, inverse, k), inverse
+
+
+def _pairs_bwd(k, inverse, g):
+    back = jnp.take(g, inverse, axis=0).reshape(-1, k, g.shape[1])
+    return jnp.sum(back, axis=1, dtype=jnp.float32).astype(g.dtype), \
+        None, None
+
+
+_pairs.defvjp(_pairs_fwd, _pairs_bwd)
+
+
+def _routed_rows(h, choice, weight, w_in, w_out, first, k=1):
+    """The op on one device's rows: h [T, D]; choice and weight [T k],
+    a token's ``k`` pairs side by side (``k == 1``: a pair is a
+    token)."""
+    n, count = choice.shape[0], w_in.shape[0]
     mid_dim = w_out.shape[1]
     local = choice.astype(jnp.int32) - first
     here = (local >= 0) & (local < count)
-    # tokens of absent experts sort last and belong to no group: the
+    # pairs of absent experts sort last and belong to no group: the
     # grouped matmuls do not visit their rows
     key = jnp.where(here, local, count)
     with jax.named_scope("moe_dispatch"):
@@ -204,7 +230,9 @@ def _routed_rows(h, choice, weight, w_in, w_out, first):
         held = jnp.arange(n, dtype=jnp.int32) < jnp.sum(sizes)
         # the mask is for the way back: what the matmuls leave in the
         # unvisited rows of a cotangent must not reach ``h``
-        rows = jnp.where(held[:, None], _permute(h, order, inverse), 0)
+        rows = jnp.where(
+            held[:, None], _permute(h, order, inverse) if k == 1
+            else _pairs(h, order, inverse, k), 0)
     with jax.named_scope("moe_experts"):
         gate_up = jax.lax.ragged_dot(
             rows, w_in, sizes, preferred_element_type=h.dtype)
@@ -213,28 +241,46 @@ def _routed_rows(h, choice, weight, w_in, w_out, first):
             mid, w_out, sizes, preferred_element_type=h.dtype)
     with jax.named_scope("moe_combine"):
         scale = jnp.take(weight.astype(jnp.float32), order)
-        out = jnp.where(
-            held[:, None], out.astype(jnp.float32) * scale[:, None], 0
-        ).astype(h.dtype)
-        return _permute(out, inverse, order)
+        if k == 1:
+            out = jnp.where(
+                held[:, None], out.astype(jnp.float32) * scale[:, None], 0
+            ).astype(h.dtype)
+            return _permute(out, inverse, order)
+        # the rows the matmuls did not visit hold whatever their buffer
+        # held, NaN among it on the chip: they are masked before the
+        # weight multiplies them, or the weight's gradient reads NaN x 0
+        # (top-1 keeps the order it had, which its cell's step program
+        # is held to)
+        out = (jnp.where(held[:, None], out, 0).astype(jnp.float32)
+               * scale[:, None]).astype(h.dtype)
+        out = _permute(out, inverse, order)
+        # a token's k weighted rows, each rounded as a top-1 token's
+        # is, summed in float32 into one: no float32 copy of the pairs
+        return jnp.sum(out.reshape(-1, k, out.shape[1]), axis=1,
+                       dtype=jnp.float32).astype(h.dtype)
 
 
 def routed_experts(h, choice, weight, experts, held):
-    """``weight * FFN_choice(h)`` a token, for the experts held here,
-    and 0 for a token whose expert is not. No token is dropped: there
-    is no capacity.
+    """``sum_j weight_j * FFN_choice_j(h)`` a token over its ``k``
+    choices, for the experts held here; a pair whose expert is not held
+    adds 0. No pair is dropped: there is no capacity.
 
-    h [B, S, D]; choice [B, S] int, an expert of the model's a token;
-    weight [B, S], the router's; experts ``{"w_in": [count, D, 2 M]
-    (gate | up), "w_out": [count, M, D]}``, ``FFN(h) = (silu(h Wg) * (h
-    Wu)) Wd``; ``held = (first, count)``: the experts ``first .. first
-    + count - 1`` of the model's are the ``count`` stacked here.
+    h [B, S, D]; choice [B, S, k] int, the model's experts a token
+    chose (or [B, S], one a token); weight of choice's shape, the
+    router's; experts ``{"w_in": [count, D, 2 M] (gate | up), "w_out":
+    [count, M, D]}``, ``FFN(h) = (silu(h Wg) * (h Wu)) Wd``; ``held =
+    (first, count)``: the experts ``first .. first + count - 1`` of the
+    model's are the ``count`` stacked here. The router's scoring, the
+    normalisation of its weights and any shared expert are the model's.
 
     Differentiable in ``h``, ``weight`` and the experts. On a mesh
     whose ``data`` / ``fsdp`` axes divide the batch, each device sorts
     and computes its own rows. An ``expert`` mesh axis larger than 1 is
     refused: the exchange of tokens between the chips that share a
-    layer is not written, and nothing here stands in for it."""
+    layer is not written, and nothing here stands in for it. The two
+    other MoE forms, :func:`moe_ffn` (capacity, dropped tokens) and
+    ``rl/generation.py:moe_mixture`` (every expert run and masked), are
+    still to fold into this one."""
     from dlrover_tpu.parallel.mesh import get_mesh
     from dlrover_tpu.parallel.sharding import logical_to_mesh_axes
 
@@ -252,16 +298,18 @@ def routed_experts(h, choice, weight, experts, held):
     if mesh is not None and mesh.shape.get("expert", 1) > 1:
         raise NotImplementedError(
             "routed_experts on an expert mesh axis of "
-            f"{mesh.shape['expert']}: the exchange of tokens between "
-            "the chips that share a layer is not implemented; use a "
-            "mesh with expert=1 and tell the layer what it holds"
+            f"{mesh.shape['expert']}: the exchange of tokens (their "
+            "token-expert pairs) between the chips that share a layer "
+            "is not implemented; use a mesh with expert=1 and tell the "
+            "layer what it holds"
         )
     batch, seq, dim = h.shape
+    k = choice.shape[-1] if choice.ndim == h.ndim else 1
 
     def rows(h, choice, weight, w_in, w_out):
         out = _routed_rows(
             h.reshape(-1, dim), choice.reshape(-1), weight.reshape(-1),
-            w_in, w_out, first)
+            w_in, w_out, first, k)
         return out.reshape(h.shape)
 
     if mesh is None or all(
@@ -277,3 +325,23 @@ def routed_experts(h, choice, weight, experts, held):
         in_specs=(P(batch_axes), P(batch_axes), P(batch_axes), P(), P()),
         out_specs=P(batch_axes), check_vma=False,
     )(h, choice, weight, w_in, w_out)
+
+
+# the counts a routed layer hands back beside the loss, in this order
+COUNTS = ("moe.tokens_routed", "moe.tokens_held",
+          "moe.expert_load_max", "moe.expert_load_mean")
+
+
+def routed_counts(choice, held):
+    """One layer's ``COUNTS`` of ``choice`` (int, any shape: a token's
+    choices on the last axis where it makes several), float32 [4]:
+    token-expert pairs routed, pairs whose expert is held here, and the
+    largest and the mean load of a held expert in pairs."""
+    first, count = held
+    experts = first + jnp.arange(count, dtype=jnp.int32)
+    load = jnp.sum(choice[..., None] == experts,
+                   axis=tuple(range(choice.ndim)), dtype=jnp.float32)
+    return jnp.stack([
+        jnp.asarray(choice.size, jnp.float32), jnp.sum(load),
+        jnp.max(load), jnp.mean(load),
+    ])

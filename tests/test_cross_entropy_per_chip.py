@@ -200,3 +200,35 @@ def test_a_manual_axis_keeps_the_plain_head(no_mesh, manual):
     assert _traced_impls(
         lambda: jax.make_jaxpr(mapped)(h, labels)) == {"plain"}
     assert (seen["gather_mesh"] is m) == (manual != "fsdp")
+
+
+def test_the_plain_form_pins_its_logits_where_asked(no_mesh):
+    """``pinned`` puts one barrier on each chunk's logits in the plain
+    form's forward and changes no number; without it there is none."""
+    h, w, scale, labels = _operands(seed=7)
+
+    def loss(pinned):
+        def fn(h, w, scale):
+            total, valid = fused_linear_cross_entropy(
+                h, w, labels, n_chunks=CHUNKS, norm_scale=scale,
+                pinned=pinned)
+            return total / valid
+        return fn
+
+    def count(jaxpr):
+        n = 0
+        for e in jaxpr.eqns:
+            n += e.primitive.name == "optimization_barrier"
+            for j in _subjaxprs(list(e.params.values())):
+                n += count(j)
+        return n
+
+    def barriers(fn):
+        return count(jax.make_jaxpr(jax.value_and_grad(
+            fn, argnums=(0, 1, 2)))(h, w, scale).jaxpr)
+
+    assert barriers(loss(True)) >= 1 and barriers(loss(False)) == 0
+    got = jax.value_and_grad(loss(True), argnums=(0, 1, 2))(h, w, scale)
+    want = jax.value_and_grad(loss(False), argnums=(0, 1, 2))(h, w, scale)
+    for g, x in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(x))

@@ -206,3 +206,109 @@ def test_under_layer_input_recomputation():
     got = jax.grad(loss(pipeline.LAYER_INPUT, True), (0, 1))(stacked, h)
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(g, w, rtol=2e-5, atol=1e-5)
+
+
+def _pairs(seed=8, batch=2, seq=24, k=2):
+    """A token's k distinct experts of E and its k weights."""
+    rs = np.random.RandomState(seed)
+    h, _, _, experts = _operands(seed, batch, seq)
+    choice = np.stack([rs.permutation(E)[:k]
+                       for _ in range(batch * seq)]).reshape(batch, seq, k)
+    weight = jnp.asarray(rs.rand(batch, seq, k), jnp.float32)
+    return h, jnp.asarray(choice, jnp.int32), weight, experts
+
+
+def _per_token(h, choice, weight, experts, held):
+    """Each of a token's k pairs in turn, over the held experts: the
+    expert's output times the pair's weight where the pair chose it."""
+    first, count = held
+    y = jnp.zeros_like(h)
+    for j in range(choice.shape[-1]):
+        for e in range(first, first + count):
+            w_in, w_out = experts["w_in"][e], experts["w_out"][e]
+            out = (jax.nn.silu(h @ w_in[:, :M]) * (h @ w_in[:, M:])) @ w_out
+            y = y + jnp.where(choice[..., j] == e, weight[..., j], 0)[
+                ..., None] * out
+    return y
+
+
+@pytest.mark.parametrize("k", [1, 2, 8])
+@pytest.mark.parametrize("held", [(0, E), (2, 3)])
+def test_top_k_is_the_per_token_loop(k, held):
+    """A token's k pairs sorted with every other token's, grouped by
+    held expert and summed back into its row: the per-token loop over
+    its held experts, in the output and in the gradient of the rows,
+    the weights and the experts; pairs of absent experts add 0."""
+    h, choice, weight, experts = _pairs(seed=8 + k, k=k)
+    if held != (0, E):
+        assert (np.asarray(choice) < held[0]).any()     # absent pairs
+    got = _routed(h, choice, weight, experts, held)
+    want = _per_token(h, choice, weight, experts, held)
+    np.testing.assert_allclose(got, want, atol=1e-5 * float(
+        jnp.max(jnp.abs(want))))
+    probe = jnp.asarray(np.random.RandomState(5).randn(*h.shape), jnp.float32)
+
+    def grads(fn):
+        return jax.grad(
+            lambda h, w, ex: jnp.sum(fn(h, choice, w, ex, held) * probe),
+            argnums=(0, 1, 2))(h, weight, experts)
+
+    got_g, want_g = grads(_routed), grads(_per_token)
+    got_g = (got_g[0], got_g[1], _share(got_g[2], held))
+    want_g = (want_g[0], want_g[1], _share(want_g[2], held))
+    for g, w in zip(jax.tree.leaves(got_g), jax.tree.leaves(want_g)):
+        assert bool(jnp.all(jnp.isfinite(g)))
+        np.testing.assert_allclose(
+            g, w, atol=1e-5 * max(float(jnp.max(jnp.abs(w))), 1e-6))
+
+
+@pytest.mark.parametrize("k", [2, 8])
+def test_what_the_matmuls_leave_unvisited_reaches_no_gradient(
+        k, monkeypatch):
+    """On the chip the grouped matmuls leave the rows of absent pairs as
+    their buffer held them, NaN among it. Planted here as NaN, it must
+    reach neither the output nor any gradient."""
+    grouped = jax.lax.ragged_dot
+
+    def leaves_nan(lhs, rhs, group_sizes, **kwargs):
+        out = grouped(lhs, rhs, group_sizes, **kwargs)
+        visited = jnp.arange(out.shape[0]) < jnp.sum(group_sizes)
+        return jnp.where(visited[:, None], out, jnp.nan)
+
+    monkeypatch.setattr(jax.lax, "ragged_dot", leaves_nan)
+    h, choice, weight, experts = _pairs(seed=11, k=k)
+    held = (2, 3)
+    out, grads = jax.value_and_grad(
+        lambda h, w, ex: jnp.sum(_routed(h, choice, w, ex, held)),
+        argnums=(0, 1, 2))(h, weight, experts)
+    assert bool(jnp.isfinite(out))
+    for g in jax.tree.leaves(grads):
+        assert bool(jnp.all(jnp.isfinite(g)))
+
+
+def test_top_1_as_pairs_is_top_1():
+    """[B, S, 1] choices are the [B, S] form's, bit for bit."""
+    h, choice, weight, experts = _operands(seed=9)
+    held = (0, E // 2)
+    flat = _routed(h, choice, weight, experts, held)
+    pairs = _routed(h, choice[..., None], weight[..., None], experts, held)
+    assert np.array_equal(np.asarray(flat), np.asarray(pairs))
+
+
+def test_top_k_shares_add_up_to_the_whole_layer():
+    """Four chips share a layer, two experts each, 4 pairs a token: the
+    four parts add up to the layer with every expert held."""
+    h, choice, weight, experts = _pairs(seed=10, k=4)
+    parts = [_routed(h, choice, weight, experts, (first, 2))
+             for first in range(0, E, 2)]
+    whole = _per_token(h, choice, weight, experts, (0, E))
+    np.testing.assert_allclose(sum(parts), whole, atol=2e-5)
+
+
+def test_routed_counts_count_pairs():
+    """``routed_counts``: pairs routed, pairs held here, and the largest
+    and the mean load of a held expert in pairs."""
+    choice = jnp.asarray([[[0, 3], [3, 5]], [[1, 3], [7, 6]]], jnp.int32)
+    counts = np.asarray(moe.routed_counts(choice, (2, 4)))
+    # held experts 2-5: loads 0, 3, 0, 1
+    np.testing.assert_array_equal(counts, [8, 4, 3, 1])

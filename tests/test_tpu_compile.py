@@ -257,6 +257,16 @@ ATTENTION_NAMES = {
         (2, 8, 8192, 128), (2, 2, 8192, 128),
         {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "flash_bwd_delta"},
     ),
+    # laguna-xs.2's window layers: 64 heads of 128 over 8 KV heads, 2 x
+    # 8192, a window of 512: the kernels take the window's names, which
+    # ``^flash_fwd`` / ``^flash_bwd`` still match
+    "laguna-xs.2-window": (
+        functools.partial(flash_attention, block_q=1024, block_k=1024,
+                          window=512),
+        (2, 64, 8192, 128), (2, 8, 8192, 128),
+        {"flash_fwd_window", "flash_bwd_window_dq", "flash_bwd_window_dkv",
+         "flash_bwd_window_delta"},
+    ),
 }
 
 
@@ -570,6 +580,8 @@ STEP_PROGRAMS = {
     # chunk (PR 38: the rule's kernel pair where PR 37's
     # a21aed4543a6c099 ran the chunked form)
     "olmo-hybrid-7b": "4722ec3f4eebaa2d",
+    # top-8 routed pairs, window and full attention, a dense layer 0
+    "laguna-xs.2": "86dc0727812e7eab",
 }
 
 
@@ -656,6 +668,31 @@ def test_cells_that_keep_named_values_compile_for_the_chip(
         topo, on_tpu, name):
     compiled = _lowered_cell_step(topo, name).compile()
     assert _kernel_names(compiled).count("flash_fwd") == 1
+
+
+def test_laguna_cell_compiles_and_fits(topo, on_tpu):
+    """``laguna-xs.2.steady`` on one described v5e: the compiler takes
+    the step (its limit is 15.75 GiB by its buffer assignment: 14.60e9
+    bytes when the cell was built, 8.30e9 of it the train state), and
+    the kernels are there under the names the per-layer metrics read:
+    the full layers' flash pair once a run of one (the layer keeps the
+    forward kernel's outputs), the window layers' under the window's
+    names, forward twice (the recomputation runs it again), and the
+    compiler's grouped matmuls of the two routed runs."""
+    compiled = _lowered_cell_step(topo, "laguna-xs.2").compile()
+    names = _kernel_names(compiled)
+    assert len(names) == _kernels(compiled)
+    assert names.count("flash_fwd") == 2, names
+    assert names.count("flash_fwd_window") == 2, names
+    assert {n for n in names if n.startswith("flash_bwd_window")} == {
+        "flash_bwd_window_dq", "flash_bwd_window_dkv",
+        "flash_bwd_window_delta"}
+    # gate | up and down: forward, the recomputed forward, and both
+    # transposes of each backward, in each of the two routed runs
+    assert names.count("ragged-dot-none") == 16
+    m = compiled.memory_analysis()
+    assert m.argument_size_in_bytes < 12 * 691_623_936 * 1.01
+    assert m.peak_memory_in_bytes < 15.75 * 1024 ** 3
 
 
 def _computations(hlo: str) -> dict:
