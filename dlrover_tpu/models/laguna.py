@@ -64,8 +64,9 @@ from dlrover_tpu.models.llama import (
     _rms_norm,
     _seq_axis_active,
     bhsd_flash_attention,
+    flash_blocks,
 )
-from dlrover_tpu.ops.attention import mha_reference
+from dlrover_tpu.ops.attention import mha_reference, window_computed_per_kept
 from dlrover_tpu.ops.cross_entropy import fused_linear_cross_entropy
 from dlrover_tpu.ops.fp8 import qdot, qeinsum
 from dlrover_tpu.parallel.moe import COUNTS, routed_counts, routed_experts
@@ -403,6 +404,13 @@ def _attention(config, attention, y, p, cos, sin):
         k = _rotary(qkv[:, heads:heads + kv], cos, sin).astype(dtype)
         v = qkv[:, heads + kv:]
         if config.attn_impl == "flash":
+            if window is not None:
+                blocks = flash_blocks(config, window)
+                telemetry.gauge_set(
+                    "model.attn.window_computed_per_kept",
+                    window_computed_per_kept(q.shape[2], window,
+                                             blocks["block_q"],
+                                             blocks["block_k"]))
             out = bhsd_flash_attention(config, q, k, v, window=window)
         else:
             out = mha_reference(q, k, v, causal=True, window=window)

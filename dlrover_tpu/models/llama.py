@@ -30,6 +30,7 @@ from dlrover_tpu.ops.attention import (
     flash_attention,
     flash_attention_bshd,
     mha_reference,
+    window_block,
 )
 from dlrover_tpu.ops.cross_entropy import softmax_cross_entropy
 from dlrover_tpu.ops.fp8 import qdot, qeinsum, quant_mode
@@ -294,6 +295,25 @@ def _maybe_full_rope(config, cos, sin):
     return cos, sin
 
 
+def flash_blocks(config, window=None) -> dict:
+    """The kernels' tiles: the configured blocks, or where a sliding
+    ``window`` is set the window's own (``ops.attention.window_block``);
+    a backward block of 0 is the forward's."""
+    if window is None:
+        return dict(block_q=config.attn_block_q,
+                    block_k=config.attn_block_k,
+                    bwd_block_q=config.attn_bwd_block_q,
+                    bwd_block_k=config.attn_bwd_block_k)
+    return dict(
+        block_q=window_block(config.attn_block_q, window),
+        block_k=window_block(config.attn_block_k, window),
+        bwd_block_q=window_block(
+            config.attn_bwd_block_q or config.attn_block_q, window),
+        bwd_block_k=window_block(
+            config.attn_bwd_block_k or config.attn_block_k, window),
+    )
+
+
 def _sharded_flash(config: LlamaConfig, qt, kt, vt, layout: str = "bhsd",
                    rope_cos=None, rope_sin=None, sm_scale=None, window=None):
     """pallas_call does not auto-partition under GSPMD: without an explicit
@@ -316,11 +336,8 @@ def _sharded_flash(config: LlamaConfig, qt, kt, vt, layout: str = "bhsd",
             {"rope_cos": tables[0], "rope_sin": tables[1]} if rope else {}
         )
         return fa(
-            q, k, v, causal=True, sm_scale=sm_scale,
-            block_q=config.attn_block_q, block_k=config.attn_block_k,
-            bwd_block_q=config.attn_bwd_block_q,
-            bwd_block_k=config.attn_bwd_block_k, window=window,
-            **extra,
+            q, k, v, causal=True, sm_scale=sm_scale, window=window,
+            **flash_blocks(config, window), **extra,
         )
 
     tables = (rope_cos, rope_sin) if rope else ()

@@ -321,6 +321,39 @@ def _tile_meta_impl(nq, nk, block_q, block_k, q_len, kv_len, causal,
     )
 
 
+def window_block(block, window):
+    """The tile edge a sliding window's kernels take where ``block`` is
+    configured: the window rounded up to a multiple of 128 (at least
+    128), no larger than ``block`` and a divisor of it, so a length that
+    ``block`` tiles evenly this tiles evenly too. ``window`` None leaves
+    ``block`` as it is.
+
+    A band of width W over square tiles of B keeps about two of them a
+    row of tiles, diagonal and the one before, while B > W: (2B x B)
+    entries computed for B x W kept. At W 512, B 1024 computes about 4
+    for each kept, B 512 about 2; below 256 a tile is less MXU work
+    than its grid step costs."""
+    if window is None:
+        return block
+    edge = max(128, -(-int(window) // 128) * 128)
+    while edge < block and block % edge:
+        edge += 128
+    return min(edge, block)
+
+
+def window_computed_per_kept(seq, window, block_q, block_k):
+    """Score entries the live tiles of causal self-attention over
+    ``seq`` positions compute for each one the sliding ``window``
+    keeps: the tile table the kernels walk (``_tile_meta_impl``) times
+    the tile's area, over the entries in the band."""
+    block_q, block_k = min(block_q, seq), min(block_k, seq)
+    tiles = _tile_meta_impl(
+        pl.cdiv(seq, block_q), pl.cdiv(seq, block_k), block_q, block_k,
+        seq, seq, True, False, window, None).shape[1]
+    w = min(window, seq)
+    return tiles * block_q * block_k / (w * (w + 1) // 2 + (seq - w) * w)
+
+
 def _needs_p_zero(causal, block_q, block_k, q_len, kv_len):
     """Whether exp(s_masked) can be nonzero garbage, requiring an explicit
     p-zeroing select.

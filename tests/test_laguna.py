@@ -570,6 +570,34 @@ def test_build_publishes_its_shape(bench):
     assert gauges["model.attn.window", ] == 512
 
 
+def test_window_layers_trace_the_tiles_they_compute(bench):
+    """The cell's step, traced: its window layers take the window's
+    512 tiles where the full layers keep the configured 1024, and
+    publish the score entries those tiles compute for each one the
+    window keeps, 2.00 at 8192 positions (3.87 at 1024 tiles)."""
+    from dlrover_tpu.common import telemetry
+    from dlrover_tpu.models.llama import flash_blocks
+
+    sizes = bench["lookup"].data("configs", "laguna-xs.2")
+    family = bench["families"].build(sizes)
+    config = family.model_config
+    assert flash_blocks(config) == dict(
+        block_q=1024, block_k=1024, bwd_block_q=0, bwd_block_k=0)
+    assert flash_blocks(config, config.window) == dict(
+        block_q=512, block_k=512, bwd_block_q=512, bwd_block_k=512)
+    params = jax.eval_shape(family.init, jax.random.key(0))
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        (sizes["batch"], sizes["sequence"] + 1), jnp.int32)}
+    telemetry.enable("test")
+    try:
+        jax.eval_shape(family.loss_fn, params, batch, jax.random.key(0))
+        gauges = {g["name"]: g["value"]
+                  for g in telemetry.snapshot()["gauges"]}
+    finally:
+        telemetry.install_from_env()
+    assert 1.99 <= gauges["model.attn.window_computed_per_kept"] <= 2.01
+
+
 # ------------------------------------------------------------ trainer
 
 

@@ -12,6 +12,8 @@ from dlrover_tpu.ops.attention import (
     flash_attention,
     flash_attention_bshd,
     mha_reference,
+    window_block,
+    window_computed_per_kept,
 )
 from dlrover_tpu.ops.cross_entropy import (
     softmax_cross_entropy,
@@ -348,30 +350,38 @@ class TestWindowPrefixMasks:
     the kernels)."""
 
     CASES = [
-        # (q_len, kv_len, window, prefix) — aligned, unaligned,
-        # cross-lengths, window=1 (hazard path), composition
-        (128, 128, 64, None),
-        (128, 128, 1, None),
-        (100, 100, 48, None),
-        (96, 200, 64, None),
-        (128, 128, None, 32),
-        (100, 100, None, 17),
-        (96, 200, None, 40),
-        (128, 128, 48, 32),
-        (100, 100, 33, 17),
-        (96, 200, 48, 40),
-        (128, 128, 1, 1),
+        # (q_len, kv_len, window, prefix, configured block) — aligned,
+        # unaligned, cross-lengths, window=1 (hazard path), composition;
+        # the kernels take window_block's tiles, which at a configured
+        # 64 are 64 and at 1024 with a window of 256 the window's own
+        (128, 128, 64, None, 64),
+        (128, 128, 1, None, 64),
+        (100, 100, 48, None, 64),
+        (96, 200, 64, None, 64),
+        (128, 128, None, 32, 64),
+        (100, 100, None, 17, 64),
+        (96, 200, None, 40, 64),
+        (128, 128, 48, 32, 64),
+        (100, 100, 33, 17, 64),
+        (96, 200, 48, 40, 64),
+        (128, 128, 1, 1, 64),
+        (1024, 1024, 256, None, 1024),
     ]
 
-    @pytest.mark.parametrize("q_len,kv_len,window,prefix", CASES)
+    # the first four entries name a case, as before the fifth
+    @pytest.mark.parametrize(
+        "q_len,kv_len,window,prefix,block", CASES,
+        ids=["-".join(map(str, c[:4])) for c in CASES],
+    )
     def test_forward_matches_dense_mask(self, q_len, kv_len, window,
-                                        prefix):
+                                        prefix, block):
         rng = np.random.RandomState(7)
         q = jnp.asarray(rng.randn(1, 4, q_len, 64), jnp.float32)
         k = jnp.asarray(rng.randn(1, 2, kv_len, 64), jnp.float32)
         v = jnp.asarray(rng.randn(1, 2, kv_len, 64), jnp.float32)
+        block = window_block(block, window)
         out = flash_attention(
-            q, k, v, causal=True, block_q=64, block_k=64,
+            q, k, v, causal=True, block_q=block, block_k=block,
             window=window, prefix_len=prefix,
         )
         ref = _masked_reference(q, k, v, window=window, prefix=prefix)
@@ -379,26 +389,31 @@ class TestWindowPrefixMasks:
             np.asarray(out), np.asarray(ref), atol=2e-2
         )
 
+    GRAD_CASES = [
+        (128, 128, 64, None, 64),
+        (128, 128, 1, None, 64),
+        (100, 100, 48, None, 64),
+        (96, 200, 64, None, 64),
+        (128, 128, None, 32, 64),
+        (100, 100, 33, 17, 64),
+        (1024, 1024, 256, None, 1024),
+    ]
+
     @pytest.mark.parametrize(
-        "q_len,kv_len,window,prefix",
-        [
-            (128, 128, 64, None),
-            (128, 128, 1, None),
-            (100, 100, 48, None),
-            (96, 200, 64, None),
-            (128, 128, None, 32),
-            (100, 100, 33, 17),
-        ],
+        "q_len,kv_len,window,prefix,block", GRAD_CASES,
+        ids=["-".join(map(str, c[:4])) for c in GRAD_CASES],
     )
-    def test_grads_match_dense_mask(self, q_len, kv_len, window, prefix):
+    def test_grads_match_dense_mask(self, q_len, kv_len, window, prefix,
+                                    block):
         rng = np.random.RandomState(8)
         q = jnp.asarray(rng.randn(1, 2, q_len, 64), jnp.float32)
         k = jnp.asarray(rng.randn(1, 2, kv_len, 64), jnp.float32)
         v = jnp.asarray(rng.randn(1, 2, kv_len, 64), jnp.float32)
+        block = window_block(block, window)
 
         g = jax.grad(
             lambda *a: jnp.sum(flash_attention(
-                *a, causal=True, block_q=64, block_k=64,
+                *a, causal=True, block_q=block, block_k=block,
                 window=window, prefix_len=prefix,
             ) ** 2),
             argnums=(0, 1, 2),
@@ -432,3 +447,24 @@ class TestWindowPrefixMasks:
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(ref), atol=2e-2
         )
+
+
+@pytest.mark.parametrize("window,block,want", [
+    (512, 1024, 512),      # laguna-xs.2's window layers
+    (4096, 1024, 1024),    # a window as wide as the block keeps it
+    (48, 1024, 128),       # never below 128
+    (300, 1024, 512),      # 384 does not divide 1024
+    (None, 1024, 1024),    # no window: the configured block
+    (48, 64, 64),          # never above the configured block
+])
+def test_window_block(window, block, want):
+    assert window_block(block, window) == want
+
+
+@pytest.mark.parametrize("block,low,high", [
+    (1024, 3.87, 3.871),   # the configured tiles: the band's 15 tiles
+    (window_block(1024, 512), 1.99, 2.01),
+])
+def test_window_computed_per_kept_at_laguna_shape(block, low, high):
+    """laguna-xs.2's window layers: 8192 positions, a window of 512."""
+    assert low <= window_computed_per_kept(8192, 512, block, block) <= high

@@ -35,7 +35,11 @@ from dlrover_tpu.models.llama import (
     llama_logical_axes,
     llama_loss_fn,
 )
-from dlrover_tpu.ops.attention import flash_attention, flash_attention_bshd
+from dlrover_tpu.ops.attention import (
+    flash_attention,
+    flash_attention_bshd,
+    window_block,
+)
 from dlrover_tpu.ops.fused_optim import fused_adamw
 from dlrover_tpu.ops.quantization import (
     BLOCK,
@@ -258,11 +262,12 @@ ATTENTION_NAMES = {
         {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "flash_bwd_delta"},
     ),
     # laguna-xs.2's window layers: 64 heads of 128 over 8 KV heads, 2 x
-    # 8192, a window of 512: the kernels take the window's names, which
+    # 8192, a window of 512, at the tiles the window takes where 1024 is
+    # configured: the kernels take the window's names, which
     # ``^flash_fwd`` / ``^flash_bwd`` still match
     "laguna-xs.2-window": (
-        functools.partial(flash_attention, block_q=1024, block_k=1024,
-                          window=512),
+        functools.partial(flash_attention, block_q=window_block(1024, 512),
+                          block_k=window_block(1024, 512), window=512),
         (2, 64, 8192, 128), (2, 8, 8192, 128),
         {"flash_fwd_window", "flash_bwd_window_dq", "flash_bwd_window_dkv",
          "flash_bwd_window_delta"},
@@ -580,8 +585,10 @@ STEP_PROGRAMS = {
     # chunk (PR 38: the rule's kernel pair where PR 37's
     # a21aed4543a6c099 ran the chunked form)
     "olmo-hybrid-7b": "4722ec3f4eebaa2d",
-    # top-8 routed pairs, window and full attention, a dense layer 0
-    "laguna-xs.2": "86dc0727812e7eab",
+    # top-8 routed pairs, window and full attention, a dense layer 0;
+    # the window layers' kernels at the window's 512 tiles (was
+    # 86dc0727812e7eab at the configured 1024)
+    "laguna-xs.2": "ca739c4742b00312",
 }
 
 
