@@ -70,6 +70,7 @@ from dlrover_tpu.ops.attention import mha_reference, window_computed_per_kept
 from dlrover_tpu.ops.cross_entropy import fused_linear_cross_entropy
 from dlrover_tpu.ops.fp8 import qdot, qeinsum
 from dlrover_tpu.parallel.moe import COUNTS, routed_counts, routed_experts
+from dlrover_tpu.parallel.moe import route as moe_route
 from dlrover_tpu.parallel.sharding import shard_logical
 
 # bf16 operands, float32 products and sums: one pass on the chip, where
@@ -438,14 +439,9 @@ def _swiglu(y, w_in, w_out, width, scope):
 def route(config: LagunaConfig, y, router):
     """(choice [B, S, k] int32, weight [B, S, k] float32): the top-k of
     the softmax over all the model's experts, the weights renormalised
-    over the k and scaled by ``routed_scale``."""
-    with jax.named_scope("router"):
-        scores = jnp.matmul(y, router.astype(y.dtype), precision=_EXACT,
-                            preferred_element_type=jnp.float32)
-        probs = jax.nn.softmax(scores, axis=-1)
-        top, choice = jax.lax.top_k(probs, config.top_k)
-        weight = top / jnp.sum(top, -1, keepdims=True) * config.routed_scale
-    return choice.astype(jnp.int32), weight
+    over the k and scaled by ``routed_scale`` (``moe.route``)."""
+    return moe_route(y, router, scoring="softmax", top_k=config.top_k,
+                     scale=config.routed_scale)
 
 
 def _layer_fn(config: LagunaConfig, kind: str):

@@ -1,4 +1,4 @@
-"""Mixture-of-Experts with expert parallelism over the ``expert`` mesh axis.
+"""Mixture-of-Experts: the router and two forms of the routed layer.
 
 Equivalent capability: the reference's MOELayer
 (atorch/atorch/modules/moe/moe_layer.py:161) with its explicit ``_AllToAll``
@@ -13,10 +13,16 @@ einsum formulation instead of a translated all-to-all:
   the Switch/GShard load-balancing auxiliary loss + router z-loss;
 - :func:`moe_ffn` dispatches with one einsum to ``[E, G, C, D]``, runs
   the stacked expert FFN (a single batched matmul on the MXU — E is a
-  leading einsum dim, sharded on the ``expert`` mesh axis so GSPMD
-  inserts the all-to-alls over ICI), and combines back.
+  leading einsum dim) and combines back. E carries the logical axis
+  ``expert``, so on an ``expert`` mesh axis GSPMD makes the two einsums
+  all-to-alls; the one-hot tensors keep this form at toy sizes.
 
 Everything is differentiable jnp; no process groups, no custom autograd.
+
+:func:`route` is the router of the routed layer: a token's top-k of
+the scores over all the model's experts, softmax or sigmoid (with a
+correction bias that takes part in the choice and not in the weights),
+the weights normalised over the k and scaled.
 
 :func:`routed_experts` is the other form: no capacity and no dropped
 token. A token's ``k`` choices are ``k`` token-expert pairs; the pairs
@@ -26,7 +32,8 @@ times the router's weight, a token's pairs summed into its row. The
 layer is told which experts of the model's it holds (``held = (first,
 count)``): it takes choices over all of them and computes its own
 experts' part of the result. The exchange over an ``expert`` mesh axis
-is not here yet, and nothing stands in for it.
+is not here yet, and nothing stands in for it: an ``expert`` axis
+larger than 1 is refused.
 """
 
 from __future__ import annotations
@@ -40,7 +47,7 @@ import jax.numpy as jnp
 
 from dlrover_tpu.parallel.sharding import shard_logical
 
-__all__ = ["MoEConfig", "top_k_gating", "moe_ffn", "moe_init",
+__all__ = ["MoEConfig", "top_k_gating", "moe_ffn", "moe_init", "route",
            "routed_experts", "routed_counts", "COUNTS"]
 
 
@@ -156,6 +163,43 @@ def moe_ffn(x, params, config: MoEConfig, rules=None):
 # ---------------------------------------------------------------------------
 # routed experts without capacity
 # ---------------------------------------------------------------------------
+
+# the router's scores: bf16 operands, float32 products and sums (one
+# pass on the chip; XLA:CPU takes no bf16 x bf16 -> float32 dot at the
+# default precision)
+_EXACT = jax.lax.Precision.HIGHEST
+
+
+def route(y, router, *, scoring: str, top_k: int, scale: float,
+          bias=None):
+    """(choice [..., k] int32, weight [..., k] float32) of the tokens
+    ``y [..., D]`` over all the model's experts, ``router [D, E]``,
+    in float32:
+
+    - ``scoring="softmax"``: ``p = softmax(y W_r)``, the top-k of ``p``;
+    - ``scoring="sigmoid"``: ``p = sigmoid(y W_r)``, the top-k of ``p +
+      bias`` (``bias [E]``, the correction bias of DeepSeek-V3's
+      ``noaux_tc`` selection: it takes part in the choice and not in
+      the weights, and no gradient reaches it).
+
+    ``weight = scale * p[choice] / sum(p[choice])`` either way."""
+    if scoring not in ("softmax", "sigmoid"):
+        raise ValueError(f"scoring {scoring!r}: softmax or sigmoid")
+    with jax.named_scope("router"):
+        scores = jnp.matmul(y, router.astype(y.dtype), precision=_EXACT,
+                            preferred_element_type=jnp.float32)
+        if scoring == "softmax":
+            probs = jax.nn.softmax(scores, axis=-1)
+            top, choice = jax.lax.top_k(probs, top_k)
+        else:
+            probs = jax.nn.sigmoid(scores)
+            select = probs if bias is None else probs + jax.lax.stop_gradient(
+                bias.astype(jnp.float32))
+            choice = jax.lax.top_k(select, top_k)[1]
+            top = jnp.take_along_axis(probs, choice, -1)
+        weight = top / jnp.sum(top, -1, keepdims=True) * scale
+    return choice.astype(jnp.int32), weight
+
 
 # The grouped matmul: ``jax.lax.ragged_dot``, which XLA:TPU compiles to
 # its own kernels; their device events are named ``ragged-dot-*``

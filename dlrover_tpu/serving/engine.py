@@ -70,6 +70,11 @@ class SlotKVCache(NamedTuple):
 def init_slot_cache(
     config: LlamaConfig, slots: int, capacity: int, dtype=None
 ) -> SlotKVCache:
+    if not isinstance(config, LlamaConfig):
+        raise NotImplementedError(
+            f"decode of a {type(config).__name__}: the slotted cache and "
+            "its programs serve models/llama.py's family alone"
+        )
     dtype = dtype or jnp.dtype(config.dtype)
     shape = (
         config.n_layers, slots, capacity, config.n_kv_heads,

@@ -100,6 +100,30 @@ def test_flash_attention_gqa_heads():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-2)
 
 
+# latent attention's shape (models/glm_lite.py): 20 heads of 256 over as
+# many key/value heads, causal, the backward at half the forward's rows
+# as the model runs it; 256 positions take the one-pass backward
+# (flash_bwd_fused), 1280 the dq / dkv pair that the cell's 8192 take
+@pytest.mark.parametrize("seq,block,bwd_block", [(256, 128, 64),
+                                                 (1280, 256, 128)])
+def test_flash_attention_at_latent_attention_heads(seq, block, bwd_block):
+    q, k, v = _qkv(heads=20, kv_heads=20, seq=seq, dim=256, seed=3)
+    probe = jnp.asarray(np.random.RandomState(4).randn(*q.shape),
+                        jnp.float32)
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v) * probe)
+
+    kernel = loss(lambda q, k, v: flash_attention(
+        q, k, v, block_q=block, block_k=block, bwd_block_q=bwd_block))
+    plain = loss(lambda q, k, v: mha_reference(q, k, v))
+    got = jax.value_and_grad(kernel, argnums=(0, 1, 2))(q, k, v)
+    want = jax.value_and_grad(plain, argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-4)
+
+
 @pytest.mark.parametrize("fused", [True, False])
 @pytest.mark.parametrize("causal", [True, False])
 @pytest.mark.parametrize("heads,kv_heads", [(4, 4), (8, 2)])

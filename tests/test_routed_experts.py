@@ -312,3 +312,44 @@ def test_routed_counts_count_pairs():
     counts = np.asarray(moe.routed_counts(choice, (2, 4)))
     # held experts 2-5: loads 0, 3, 0, 1
     np.testing.assert_array_equal(counts, [8, 4, 3, 1])
+
+
+def _laguna_route_before(config, y, router):
+    """``models/laguna.route`` as it was written before the router moved
+    into ``moe.route``."""
+    with jax.named_scope("router"):
+        scores = jnp.matmul(y, router.astype(y.dtype),
+                            precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32)
+        probs = jax.nn.softmax(scores, axis=-1)
+        top, choice = jax.lax.top_k(probs, config.top_k)
+        weight = top / jnp.sum(top, -1, keepdims=True) * config.routed_scale
+    return choice.astype(jnp.int32), weight
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_laguna_routes_through_moe_route_as_it_did(dtype):
+    """Laguna's softmax router, now ``moe.route(scoring="softmax")``,
+    gives its former formula's choices and weights bit for bit at the
+    toy's widths (64 wide, 16 experts, 4 a token)."""
+    from dlrover_tpu.models import laguna
+
+    config = laguna.LagunaConfig(
+        layer_types=("full_attention",) * 2, mlp_layer_types=("sparse",) * 2,
+        heads=(8, 8), n_kv_heads=2, n_experts=16, held_experts=4, top_k=4,
+        dim=64, head_dim=16, routed_scale=2.5)
+    rs = np.random.RandomState(11)
+    y = jnp.asarray(rs.randn(2, 128, 64), dtype)
+    router = jnp.asarray(rs.randn(64, 16) * 0.125, jnp.float32)
+    for got, want in zip(
+        jax.jit(lambda y, r: laguna.route(config, y, r))(y, router),
+        jax.jit(lambda y, r: _laguna_route_before(config, y, r))(y, router),
+    ):
+        assert got.dtype == want.dtype
+        assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_route_refuses_what_it_does_not_score():
+    y, router = jnp.zeros((2, 8)), jnp.zeros((8, 4))
+    with pytest.raises(ValueError, match="softmax or sigmoid"):
+        moe.route(y, router, scoring="relu", top_k=2, scale=1.0)

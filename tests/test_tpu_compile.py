@@ -589,6 +589,9 @@ STEP_PROGRAMS = {
     # the window layers' kernels at the window's 512 tiles (was
     # 86dc0727812e7eab at the configured 1024)
     "laguna-xs.2": "ca739c4742b00312",
+    # latent attention in every layer, sigmoid top-4 routed pairs and a
+    # shared expert, one MTP depth; a second loss over the same head
+    "glm-4.7-flash": "8a208fc34d2b8c1d",
 }
 
 
@@ -699,6 +702,30 @@ def test_laguna_cell_compiles_and_fits(topo, on_tpu):
     assert names.count("ragged-dot-none") == 16
     m = compiled.memory_analysis()
     assert m.argument_size_in_bytes < 12 * 691_623_936 * 1.01
+    assert m.peak_memory_in_bytes < 15.75 * 1024 ** 3
+
+
+def test_glm_lite_cell_compiles_and_fits(topo, on_tpu):
+    """``glm-4.7-flash.steady`` on one described v5e: the compiler takes
+    the step (its limit is 15.75 GiB by its buffer assignment: 14.96e9
+    bytes when the cell was built, 8.48e9 of it the train state), and
+    the kernels are there under the names the per-layer metrics read:
+    the flash pair at 20 heads of 256 once a layer in each of the three
+    runs (the dense layer, the routed ones, the MTP depth's: a layer
+    keeps the forward kernel's outputs), the backward at 512-row blocks,
+    and the compiler's grouped matmuls of the two routed runs."""
+    compiled = _lowered_cell_step(topo, "glm-4.7-flash").compile()
+    names = _kernel_names(compiled)
+    assert len(names) == _kernels(compiled)
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+                 "flash_bwd_delta"):
+        assert names.count(name) == 3, names
+    assert not [n for n in names if "window" in n]
+    # gate | up and down: forward, the recomputed forward, and both
+    # transposes of each backward, in each of the two routed runs
+    assert names.count("ragged-dot-none") == 16
+    m = compiled.memory_analysis()
+    assert m.argument_size_in_bytes < 12 * 706_518_848 * 1.01
     assert m.peak_memory_in_bytes < 15.75 * 1024 ** 3
 
 
