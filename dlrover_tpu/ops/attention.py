@@ -32,8 +32,10 @@ Numerics: grid over (batch, head, packed-tile); VMEM scratch carries the
 running softmax statistics (m, l) and the fp32 output accumulator across
 a row's kv tiles; the MXU does the two matmuls per tile in the input
 dtype with fp32 accumulation. Backward recomputes scores blockwise from
-the saved logsumexp (no S x S materialisation) — the standard FA2 dq/dkv
-split, each with its own packed grid (dq walks q-major, dkv kv-major).
+the saved logsumexp (no S x S materialisation): one kv-major pass that
+makes dq, dk and dv together wherever what it holds in VMEM fits
+(``_bwd_onepass``), else the standard FA2 dq/dkv split, each with its
+own packed grid (dq walks q-major, dkv kv-major).
 
 GQA: the kv-head index is derived from the q-head grid index inside the
 BlockSpec index maps — grouped kv is never materialised in the forward;
@@ -57,10 +59,17 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from dlrover_tpu.common import telemetry
 from dlrover_tpu.common.backend import use_interpret
 from dlrover_tpu.ops.named import named_pallas_call
 
 NEG_INF = -1e30
+
+# The scoped VMEM Mosaic gives a v5e kernel that asks for none, and the
+# most the one-pass backward asks for, of the chip's 128 MiB (the gated
+# delta rule's kernels ask for as much: gated_delta_kernel._PARAMS)
+_VMEM_DEFAULT = 16 * 1024 * 1024
+_VMEM_CEILING = 64 * 1024 * 1024
 
 # Row-stats (lse/delta) lane width. 8 was the minimum legal block, but
 # an 8-wide trailing dim is physically padded to 128 lanes anyway
@@ -1237,9 +1246,10 @@ def _bwd_onepass_kernel(
     large matmuls and two softmax chains where 5 and one suffice. TPU
     grids execute SEQUENTIALLY, so dq can accumulate across the whole
     (b, h) walk in a full-length VMEM scratch ([q_len, Dh] f32 — 1 MB at
-    2048x128) written out once at the final tile; dk/dv accumulate per
-    kv column exactly like the split kernel. ~29% of backward MXU work
-    and one of the two exp(s - lse) chains disappear.
+    2048x128, 8 MB at 8192x256) written out once at the final tile;
+    dk/dv accumulate per kv column exactly like the split kernel. ~29%
+    of backward MXU work and one of the two exp(s - lse) chains
+    disappear. What it holds in VMEM is :func:`_onepass_vmem_bytes`.
     """
     if rope:
         (cq_ref, sq_ref, ck_ref, sk_ref,
@@ -1337,10 +1347,52 @@ def _bwd_onepass_kernel(
         _wr(dq_ref, dq_scr[:] * sm_scale)
 
 
+def _onepass_vmem_bytes(q_len, head_dim, block_q, block_k, dtype, rope):
+    """Bytes of VMEM :func:`_bwd_onepass` holds: the dq scratch
+    (``q_len x Dh`` f32) and the dk / dv scratch; two buffers of each
+    block the grid walks (q, do, o, k, v, lse, the full-length dq
+    output, dk, dv); the tile's temporaries, 1.5 f32 score tiles (what
+    the compiler keeps of s / p, dp / ds and their casts) and an f32
+    ``[rows, Dh]`` product a side (dsk; the dk / dv products); and with
+    rope its four table blocks and the roped-k scratch, once. A row
+    takes 128 lanes at the least.
+
+    Each term is what Mosaic keeps for a v5e: bisecting
+    ``vmem_limit_bytes`` for a described chip over tiles of 256 to 1024
+    rows and heads of 64 to 256 found every shape's need at or under
+    this count: by at most 1.5 MiB at heads of 128 and 256, by more at
+    64, whose rows it counts at 128 lanes (PERF.md, section 6)."""
+    lanes = max(head_dim, 128)
+    item = jnp.dtype(dtype).itemsize
+    scratch = (q_len + 2 * block_k) * lanes * 4
+    blocks = ((3 * block_q + 2 * block_k) * lanes * item
+              + block_q * STATS_W * 4)
+    outs = (q_len + 2 * block_k) * lanes * item
+    temps = (3 * block_q * block_k // 2 + (block_q + block_k) * lanes) * 4
+    total = scratch + 2 * (blocks + outs) + temps
+    if rope:
+        total += (2 * block_q + 3 * block_k) * lanes * item
+    return total
+
+
+def _onepass_params(vmem):
+    """The one-pass call's compiler params: the default scoped VMEM
+    where its count ``vmem`` fits it, else the count and a quarter,
+    in whole MiB, at most the ceiling."""
+    dims = ("parallel", "parallel", "arbitrary")
+    if vmem <= _VMEM_DEFAULT:
+        return _compiler_params(dims)
+    mib = 1024 * 1024
+    limit = -(-(vmem * 5 // 4) // mib) * mib
+    return pltpu.CompilerParams(dimension_semantics=dims,
+                                vmem_limit_bytes=min(limit, _VMEM_CEILING))
+
+
 def _bwd_onepass(layout, H, KVH, q_len, kv_len, head_dim, sm_scale,
                  causal, block_q, block_k, interpret, q, k, v, do, lse,
-                 o, rope_cos, rope_sin):
-    """Fused-backward pallas call (bhsd layout, kv-major packed grid)."""
+                 o, rope_cos, rope_sin, vmem):
+    """Fused-backward pallas call (bhsd layout, kv-major packed grid);
+    ``vmem`` is its :func:`_onepass_vmem_bytes`."""
     batch = q.shape[0]
     group = H // KVH
     nq = pl.cdiv(q_len, block_q)
@@ -1387,8 +1439,7 @@ def _bwd_onepass(layout, H, KVH, q_len, kv_len, head_dim, sm_scale,
             jax.ShapeDtypeStruct((batch, H, kv_len, head_dim), q.dtype),
             jax.ShapeDtypeStruct((batch, H, kv_len, head_dim), q.dtype),
         ),
-        compiler_params=_compiler_params(
-            ("parallel", "parallel", "arbitrary")),
+        compiler_params=_onepass_params(vmem),
         interpret=interpret,
     )(meta, *operands)
     if rope:
@@ -1420,24 +1471,28 @@ def _bwd(layout, heads, kv_heads, sm_scale, causal, block_q, block_k,
     nk = pl.cdiv(kv_len, block_k)
 
     # fused one-pass backward: dq accumulates in a full-length VMEM
-    # scratch — gated on the scratch fitting comfortably and on
-    # block-aligned lengths (a padded final tile's row slice would run
-    # past the exact-length scratch). Conservative: 2048x128 at 1024
-    # blocks measured ~1 MB under the 16 MB scoped-vmem cap; larger dq
-    # scratches / output blocks would tip Mosaic over with no fallback,
-    # so only shapes at or below the proven footprint take this path.
-    # delta is computed per visit INSIDE the kernel (from o), so the
-    # separate delta tensor never exists on this path.
-    if (layout == "bhsd" and q_len * head_dim <= 2048 * 128
-            and q_len % block_q == 0 and kv_len % block_k == 0):
-        dq, dk_full, dv_full = _bwd_onepass(
-            layout, H, KVH, q_len, kv_len, head_dim, sm_scale, causal,
-            block_q, block_k, interpret, q, k, v, do, lse, o,
-            rope_cos, rope_sin,
-        )
-        dk, dv = _group_kv(dk_full, dv_full, batch, KVH, group,
-                           kv_len, head_dim, k.dtype, v.dtype)
-        return dq, dk, dv
+    # scratch — taken wherever what it holds fits the ceiling, and
+    # the lengths are block-aligned (a padded final tile's row slice
+    # would run past the exact-length scratch); it asks for VMEM past
+    # the default only where its count needs it. delta is computed per
+    # visit INSIDE the kernel (from o), so the separate delta tensor
+    # never exists on this path.
+    if layout == "bhsd":
+        vmem = _onepass_vmem_bytes(q_len, head_dim, block_q, block_k,
+                                   q.dtype, rope_cos is not None)
+        onepass = (vmem <= _VMEM_CEILING and q_len % block_q == 0
+                   and kv_len % block_k == 0)
+        telemetry.gauge_set("model.attn.bwd_vmem_bytes", vmem,
+                            path="onepass" if onepass else "split")
+        if onepass:
+            dq, dk_full, dv_full = _bwd_onepass(
+                layout, H, KVH, q_len, kv_len, head_dim, sm_scale,
+                causal, block_q, block_k, interpret, q, k, v, do, lse, o,
+                rope_cos, rope_sin, vmem,
+            )
+            dk, dv = _group_kv(dk_full, dv_full, batch, KVH, group,
+                               kv_len, head_dim, k.dtype, v.dtype)
+            return dq, dk, dv
 
     # delta = rowsum(do * o) per head, dense [B, H, S, STATS_W]
     if layout == "bhsd":

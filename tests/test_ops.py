@@ -102,10 +102,12 @@ def test_flash_attention_gqa_heads():
 
 # latent attention's shape (models/glm_lite.py): 20 heads of 256 over as
 # many key/value heads, causal, the backward at half the forward's rows
-# as the model runs it; 256 positions take the one-pass backward
-# (flash_bwd_fused), 1280 the dq / dkv pair that the cell's 8192 take
+# as the model runs it; 256 and 1280 positions take the one-pass
+# backward (flash_bwd_fused), as the cell's 8192 do, 1200 the dq / dkv
+# pair (its last tile of 128 rows is padded)
 @pytest.mark.parametrize("seq,block,bwd_block", [(256, 128, 64),
-                                                 (1280, 256, 128)])
+                                                 (1280, 256, 128),
+                                                 (1200, 256, 128)])
 def test_flash_attention_at_latent_attention_heads(seq, block, bwd_block):
     q, k, v = _qkv(heads=20, kv_heads=20, seq=seq, dim=256, seed=3)
     probe = jnp.asarray(np.random.RandomState(4).randn(*q.shape),
@@ -492,3 +494,107 @@ def test_window_block(window, block, want):
 def test_window_computed_per_kept_at_laguna_shape(block, low, high):
     """laguna-xs.2's window layers: 8192 positions, a window of 512."""
     assert low <= window_computed_per_kept(8192, 512, block, block) <= high
+
+
+# Each cell's attention backward as the kernels see it: positions, head
+# size, a tile's rows and columns, rope inside the kernels; and whether
+# the one-pass kernel's count fits the scoped VMEM a kernel gets unasked
+ONEPASS_CELLS = {
+    "mistral-7b": ((2048, 128, 1024, 1024, True), True),
+    "gpt2-xl": ((1024, 64, 512, 512, False), True),
+    "granite-4.0-h-micro": ((8192, 64, 1024, 1024, False), False),
+    "zaya1-8b": ((8192, 128, 1024, 1024, False), False),
+    "olmo-hybrid-7b": ((8192, 128, 1024, 1024, False), False),
+    "laguna-xs.2-full": ((8192, 128, 1024, 1024, False), False),
+    "laguna-xs.2-window": ((8192, 128, 512, 512, False), True),
+    "glm-4.7-flash": ((8192, 256, 512, 1024, False), False),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(ONEPASS_CELLS))
+def test_onepass_vmem_at_the_cells(cell):
+    """Every cell's backward takes the one-pass kernel; where its count
+    fits the default the compiler params are the parent's, exactly
+    (mistral-7b and gpt2-xl keep their step programs), and elsewhere
+    the call asks for more than the count and at most the ceiling."""
+    from dlrover_tpu.ops import attention as A
+
+    shape, fits_default = ONEPASS_CELLS[cell]
+    vmem = A._onepass_vmem_bytes(*shape[:4], jnp.bfloat16, shape[4])
+    assert vmem <= A._VMEM_CEILING
+    params = A._onepass_params(vmem)
+    assert (vmem <= A._VMEM_DEFAULT) == fits_default
+    if fits_default:
+        assert params == A._compiler_params(
+            ("parallel", "parallel", "arbitrary"))
+    else:
+        assert vmem < params.vmem_limit_bytes <= A._VMEM_CEILING
+
+
+def _bwd_path(q_len, head_dim, block_q, block_k, heads=2, kv_heads=1,
+              dtype=jnp.bfloat16):
+    """The labels and value of the ``model.attn.bwd_vmem_bytes`` gauge
+    the backward sets as it is traced (nothing runs)."""
+    from dlrover_tpu.common import telemetry
+    from dlrover_tpu.ops import attention as A
+
+    q = jax.ShapeDtypeStruct((1, heads, q_len, head_dim), dtype)
+    kv = jax.ShapeDtypeStruct((1, kv_heads, q_len, head_dim), dtype)
+    lse = jax.ShapeDtypeStruct((1, heads, q_len, A.STATS_W), jnp.float32)
+    telemetry.enable("test")
+    try:
+        jax.eval_shape(
+            lambda q, k, v, lse: A._bwd(
+                "bhsd", heads, kv_heads, head_dim ** -0.5, True, block_q,
+                block_k, True, (q, k, v, q, lse), q),
+            q, kv, kv, lse)
+        gauges = [g for g in telemetry.snapshot()["gauges"]
+                  if g["name"] == "model.attn.bwd_vmem_bytes"]
+    finally:
+        telemetry.install_from_env()
+    assert len(gauges) == 1
+    return gauges[0]["labels"]["path"], gauges[0]["value"]
+
+
+@pytest.mark.parametrize("q_len,head_dim,block_q,block_k,path", [
+    (8192, 256, 512, 1024, "onepass"),      # glm-4.7-flash's
+    (16384, 256, 512, 1024, "onepass"),
+    (32768, 256, 512, 1024, "split"),       # the count past the ceiling
+    (65536, 128, 1024, 1024, "split"),
+    (1200, 256, 128, 256, "split"),         # a padded last tile
+])
+def test_backward_path_follows_the_count(q_len, head_dim, block_q, block_k,
+                                         path):
+    """The backward takes the one-pass kernel exactly where its count
+    lies under the ceiling (and the tiles divide the lengths), and the
+    gauge carries the path and the count."""
+    from dlrover_tpu.ops import attention as A
+
+    vmem = A._onepass_vmem_bytes(q_len, head_dim, block_q, block_k,
+                                 jnp.bfloat16, False)
+    assert _bwd_path(q_len, head_dim, block_q, block_k) == (path, vmem)
+    if q_len % block_q == 0:
+        assert (vmem <= A._VMEM_CEILING) == (path == "onepass")
+
+
+# shapes whose q_len x head size passed the 2048 x 128 the one-pass
+# backward was held to before it counted its VMEM: laguna-xs.2's window
+# of 512 in its tiles, zaya1-8b's four query heads a key/value head
+@pytest.mark.parametrize("window,block", [(512, 512), (None, 512)])
+def test_onepass_backward_past_2048_by_128(window, block):
+    q, k, v = _qkv(heads=8, kv_heads=2, seq=2560, dim=128, seed=11)
+    probe = jnp.asarray(np.random.RandomState(12).randn(*q.shape),
+                        jnp.float32)
+    assert _bwd_path(2560, 128, block, block, heads=8, kv_heads=2,
+                     dtype=jnp.float32)[0] == "onepass"
+    got = jax.value_and_grad(
+        lambda q, k, v: jnp.sum(probe * flash_attention(
+            q, k, v, block_q=block, block_k=block, window=window)),
+        argnums=(0, 1, 2))(q, k, v)
+    want = jax.value_and_grad(
+        lambda q, k, v: jnp.sum(
+            probe * _masked_reference(q, k, v, window=window)),
+        argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-4)

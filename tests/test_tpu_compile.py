@@ -246,12 +246,13 @@ ATTENTION_NAMES = {
     ),
     # granite-4.0-h-micro's attention layer: 32 heads of 64 over 8 KV
     # heads, 1 x 8192, the published multiplier as the softmax scale;
-    # at 8192 positions the dispatcher takes the split backward
+    # the one-pass backward at 8192 positions asks for more than the
+    # default scoped VMEM, and Mosaic takes it
     "granite-4.0-h-micro": (
         functools.partial(flash_attention, sm_scale=0.015625,
                           block_q=1024, block_k=1024),
         (1, 32, 8192, 64), (1, 8, 8192, 64),
-        {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "flash_bwd_delta"},
+        {"flash_fwd", "flash_bwd_fused"},
     ),
     # zaya1-8b's attention: 8 heads of 128 over 2 KV heads (4 query
     # heads a KV head), 2 x 8192, q and k normalised and rotated
@@ -259,7 +260,7 @@ ATTENTION_NAMES = {
     "zaya1-8b": (
         functools.partial(flash_attention, block_q=1024, block_k=1024),
         (2, 8, 8192, 128), (2, 2, 8192, 128),
-        {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "flash_bwd_delta"},
+        {"flash_fwd", "flash_bwd_fused"},
     ),
     # laguna-xs.2's window layers: 64 heads of 128 over 8 KV heads, 2 x
     # 8192, a window of 512, at the tiles the window takes where 1024 is
@@ -269,8 +270,16 @@ ATTENTION_NAMES = {
         functools.partial(flash_attention, block_q=window_block(1024, 512),
                           block_k=window_block(1024, 512), window=512),
         (2, 64, 8192, 128), (2, 8, 8192, 128),
-        {"flash_fwd_window", "flash_bwd_window_dq", "flash_bwd_window_dkv",
-         "flash_bwd_window_delta"},
+        {"flash_fwd_window", "flash_bwd_window_fused"},
+    ),
+    # glm-4.7-flash's latent attention: 20 heads of 256, 2 x 8192, the
+    # backward at 512-row blocks: the one-pass kernel's 28.5 MiB count,
+    # asked for, is what Mosaic has to take here
+    "glm-4.7-flash": (
+        functools.partial(flash_attention, block_q=1024, block_k=1024,
+                          bwd_block_q=512),
+        (2, 20, 8192, 256), (2, 20, 8192, 256),
+        {"flash_fwd", "flash_bwd_fused"},
     ),
 }
 
@@ -289,6 +298,48 @@ def test_attention_kernels_carry_their_names(one_chip, widths):
     for name in names:
         # in the operation's metadata too, under the scope of the call
         assert f"/{name}/pallas_call" in text
+
+
+# batch, heads, KV heads, positions, head size, backward tile rows and
+# columns, rope inside the kernels: glm-4.7-flash's, zaya1-8b's,
+# granite-4.0-h-micro's and mistral-7b's attention
+ONEPASS_COUNTED = {
+    "glm-4.7-flash": (2, 20, 20, 8192, 256, 512, 1024, False),
+    "zaya1-8b": (2, 8, 2, 8192, 128, 1024, 1024, False),
+    "granite-4.0-h-micro": (1, 32, 8, 8192, 64, 1024, 1024, False),
+    "mistral-7b": (2, 32, 8, 2048, 128, 1024, 1024, True),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(ONEPASS_COUNTED))
+def test_onepass_backward_fits_its_count(one_chip, monkeypatch, cell):
+    """Mosaic takes the one-pass backward with no more scoped VMEM than
+    ``_onepass_vmem_bytes`` counts: a kernel that comes to hold more than
+    its count fails here, before a cell that asks for the count + 25%
+    (or that stays on the 16 MiB default) fails on the chip."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from dlrover_tpu.ops import attention as A
+
+    b, h, kvh, s, d, bq, bk, rope = ONEPASS_COUNTED[cell]
+    monkeypatch.setattr(A, "_onepass_params", lambda vmem: (
+        pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=vmem)))
+    q = jax.ShapeDtypeStruct((b, h, s, d), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((b, kvh, s, d), jnp.bfloat16)
+    args = (q, kv, kv)
+    if rope:
+        args += (jax.ShapeDtypeStruct((b, s, d), jnp.bfloat16),) * 2
+
+    def fn(q, k, v, *tables):
+        return flash_attention(
+            q, k, v, block_q=1024, block_k=1024, bwd_block_q=bq,
+            bwd_block_k=bk, interpret=False,
+            **dict(zip(("rope_cos", "rope_sin"), tables)))
+
+    compiled = _compile(_sum_grad(fn), *_shaped(args, one_chip))
+    assert "flash_bwd_fused" in _kernel_names(compiled)
 
 
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
@@ -428,8 +479,8 @@ def test_zaya_layer_forward_backward(one_chip, on_tpu):
     names = _kernel_names(compiled)
     assert len(names) == _kernels(compiled)
     assert set(names) == {
-        "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "flash_bwd_delta",
-        "ragged-dot-metadata", "ragged-dot-none"}, names
+        "flash_fwd", "flash_bwd_fused", "ragged-dot-metadata",
+        "ragged-dot-none"}, names
     assert names.count("flash_fwd") == 1
     # gate | up and down: forward, the recomputed forward, and both
     # transposes of each backward
@@ -577,21 +628,25 @@ def test_train_step_fsdp_over_four_chips(topo, on_tpu):
 STEP_PROGRAMS = {
     "mistral-7b": "00266e80d14bddf4",
     "gpt2-xl": "48eb149feb8c79c2",
-    "granite-4.0-h-micro": "b3bd6dbcb7768cd6",  # PR 35: keeps in_proj
-    "zaya1-8b": "16082d6ad790c866",         # PR 35: keeps attn_out
+    # The five cells at 8192 positions: the one-pass attention backward
+    # in place of the split trio, asking for the scoped VMEM its count
+    # needs (were b3bd6dbcb7768cd6, 16082d6ad790c866, 4722ec3f4eebaa2d,
+    # ca739c4742b00312 and 8a208fc34d2b8c1d)
+    "granite-4.0-h-micro": "8252be6bfaeae149",  # keeps in_proj
+    "zaya1-8b": "907feeff88e6c8fa",         # keeps attn_out
     # PR 40: the head's loss per chip, its matrix gathered once a pass
     # and its gradient reduce-scattered once, where PR 38's
     # a242b4f22b927984 left the partitioner to cross the chips in every
     # chunk (PR 38: the rule's kernel pair where PR 37's
     # a21aed4543a6c099 ran the chunked form)
-    "olmo-hybrid-7b": "4722ec3f4eebaa2d",
+    "olmo-hybrid-7b": "987004624bf6f598",
     # top-8 routed pairs, window and full attention, a dense layer 0;
     # the window layers' kernels at the window's 512 tiles (was
     # 86dc0727812e7eab at the configured 1024)
-    "laguna-xs.2": "ca739c4742b00312",
+    "laguna-xs.2": "cce260f9f42776c7",
     # latent attention in every layer, sigmoid top-4 routed pairs and a
     # shared expert, one MTP depth; a second loss over the same head
-    "glm-4.7-flash": "8a208fc34d2b8c1d",
+    "glm-4.7-flash": "23a3c44d3d6f9198",
 }
 
 
@@ -687,16 +742,16 @@ def test_laguna_cell_compiles_and_fits(topo, on_tpu):
     the kernels are there under the names the per-layer metrics read:
     the full layers' flash pair once a run of one (the layer keeps the
     forward kernel's outputs), the window layers' under the window's
-    names, forward twice (the recomputation runs it again), and the
-    compiler's grouped matmuls of the two routed runs."""
+    names, forward twice (the recomputation runs it again), both
+    backwards the one-pass kernel, and the compiler's grouped matmuls of
+    the two routed runs."""
     compiled = _lowered_cell_step(topo, "laguna-xs.2").compile()
     names = _kernel_names(compiled)
     assert len(names) == _kernels(compiled)
     assert names.count("flash_fwd") == 2, names
     assert names.count("flash_fwd_window") == 2, names
-    assert {n for n in names if n.startswith("flash_bwd_window")} == {
-        "flash_bwd_window_dq", "flash_bwd_window_dkv",
-        "flash_bwd_window_delta"}
+    assert {n for n in names if n.startswith("flash_bwd")} == {
+        "flash_bwd_fused", "flash_bwd_window_fused"}
     # gate | up and down: forward, the recomputed forward, and both
     # transposes of each backward, in each of the two routed runs
     assert names.count("ragged-dot-none") == 16
@@ -712,14 +767,15 @@ def test_glm_lite_cell_compiles_and_fits(topo, on_tpu):
     the kernels are there under the names the per-layer metrics read:
     the flash pair at 20 heads of 256 once a layer in each of the three
     runs (the dense layer, the routed ones, the MTP depth's: a layer
-    keeps the forward kernel's outputs), the backward at 512-row blocks,
+    keeps the forward kernel's outputs), the backward the one-pass
+    kernel at 512-row blocks, with the scoped VMEM its count asks for,
     and the compiler's grouped matmuls of the two routed runs."""
     compiled = _lowered_cell_step(topo, "glm-4.7-flash").compile()
     names = _kernel_names(compiled)
     assert len(names) == _kernels(compiled)
-    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
-                 "flash_bwd_delta"):
+    for name in ("flash_fwd", "flash_bwd_fused"):
         assert names.count(name) == 3, names
+    assert not [n for n in names if n.startswith("flash_bwd_d")]
     assert not [n for n in names if "window" in n]
     # gate | up and down: forward, the recomputed forward, and both
     # transposes of each backward, in each of the two routed runs
